@@ -1,8 +1,8 @@
-"""Roofline extraction for the benchmark run: a reduced-mesh dry-run cell
-(per-arch smoke at 8 placeholder devices in a subprocess keeps this fast and
-keeps the main process single-device) + the analytic full-mesh terms for
-every (arch x shape) cell — the full table lives in EXPERIMENTS.md and the
-sweep JSON produced by `python -m repro.launch.dryrun --all`.
+"""Roofline rows for the benchmark run, all analytic (shape arithmetic, no
+compile and no device): the per-(arch x shape) compute / HBM / collective
+terms from ``repro.launch.analytic`` over the v5e peaks in
+``repro.launch.dryrun``.  The compiled full-mesh sweep is
+`python -m repro.launch.dryrun --all`, run as its own process.
 
 Also reports the sketch->Gram hot path's arithmetic intensity, fused
 (``kernels/sketch_gram.py``, A streams once and A_tilde stays in VMEM)

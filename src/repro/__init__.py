@@ -1,2 +1,1 @@
 """OverSketched Newton reproduction on JAX/Pallas."""
-from repro import jax_compat  # noqa: F401  (backfills newer jax APIs)

@@ -24,7 +24,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -204,13 +203,13 @@ class CodedMatvecEngine:
         # absorbed into peel-recovered cells undetectably.)
         self.paranoid = False
 
-        @partial(jax.jit, static_argnames=("tag",))
-        def _mv(tag, v, erased):
-            enc = self.enc_x if tag == "X" else self.enc_xt
-            code = self.code_x if tag == "X" else self.code_xt
-            return coded.coded_matvec(enc, v, code, self.out_rows[tag], erased)
-
-        self._mv = _mv
+    def _mv(self, tag: str, v: jax.Array, erased: Optional[jax.Array]):
+        # The codes go to the jitted coded_matvec as arguments: a jitted
+        # closure over self would bake them into the compiled program as
+        # constants (5.0 GB at n = 200k, d = 2000).
+        enc = self.enc_x if tag == "X" else self.enc_xt
+        return coded.coded_matvec(enc, v, self.code_for(tag),
+                                  self.out_rows[tag], erased)
 
     def code_for(self, tag: str) -> coded.ProductCode:
         return self.code_x if tag == "X" else self.code_xt

@@ -115,9 +115,13 @@ def apply_sketch(cs: CountSketch, a: jax.Array) -> jax.Array:
 
     The 1/sqrt(N) scale of Eq. (4) is folded into the Gram rescale (we divide
     by the survivor count there), which is what makes dropping blocks exact.
+    ``lax.map`` streams the blocks, so peak memory is one signed (n, d)
+    panel plus the (K, b, d) output — never the (K, n, d) tensor a vmap
+    over blocks would build (242 GB at epsilon's n = 200k, d = 2000).
     """
-    return jax.vmap(
-        lambda h, s: apply_block(h, s, cs.block_size, a))(cs.h, cs.sigma)
+    return jax.lax.map(
+        lambda hs: apply_block(hs[0], hs[1], cs.block_size, a),
+        (cs.h, cs.sigma))
 
 
 def apply_sketch_chunked(cs: CountSketch, a_fn: Callable[[int], jax.Array],

@@ -21,23 +21,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_TILE_S = 512
 
 
-def _kernel(er_ref, enc_ref, x_ref, out_ref):
+def _kernel(er_ref, enc_ref, x_ref, out_ref, *, s_cols: int):
+    w = pl.program_id(0)
     s = pl.program_id(1)
 
     @pl.when(s == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keep = 1.0 - er_ref[0].astype(out_ref.dtype)
+    keep = (1 - er_ref[w]).astype(out_ref.dtype)
     enc = enc_ref[0]                     # (b, ts)
-    x = x_ref[...]                       # (ts,)
-    out_ref[0, :] += keep * jnp.dot(enc, x,
-                                    preferred_element_type=out_ref.dtype)
+    x = x_ref[...]                       # (1, ts)
+    if s_cols % enc.shape[1]:
+        # The last column tile overhangs enc (not padded in HBM): zero its
+        # columns past s, whose contents are undefined (0 * NaN != 0).
+        col = jax.lax.broadcasted_iota(jnp.int32, enc.shape, 1) \
+            + s * enc.shape[1]
+        enc = jnp.where(col < s_cols, enc, 0.0)
+    # (1, ts) . (b, ts)^T -> (1, b): the block product as a lane row.
+    out_ref[0] += keep * jax.lax.dot_general(
+        x, enc, (((1,), (1,)), ((), ())),
+        preferred_element_type=out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_s", "interpret"))
@@ -49,22 +59,28 @@ def coded_block_matvec(enc: jax.Array, x: jax.Array, erased: jax.Array, *,
     ts = min(tile_s, max(128, s))
     s_pad = (-s) % ts
     if s_pad:
-        enc = jnp.pad(enc, ((0, 0), (0, 0), (0, s_pad)))
+        # Only x is padded; enc's overhanging tile is masked in-kernel, so
+        # no padded copy of the encoded matrix is made.
         x = jnp.pad(x, (0, s_pad))
     st = (s + s_pad) // ts
 
-    return pl.pallas_call(
-        _kernel,
+    # The erasure mask is one whole (W,) int32 array in SMEM; x is a
+    # (1, s) lane row and each worker writes a (1, b) row of (W, 1, b),
+    # so every VMEM block meets the TPU's (8, 128) rule.
+    out = pl.pallas_call(
+        functools.partial(_kernel, s_cols=s),
         grid=(w, st),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, b, ts), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((ts,), lambda i, j: (j,)),
+            pl.BlockSpec((1, ts), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, b), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((w, b), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, b), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((w, 1, b), jnp.float32),
         interpret=interpret,
-    )(erased, enc.astype(jnp.float32), x.astype(jnp.float32))
+    )(erased.astype(jnp.int32), enc.astype(jnp.float32),
+      x.astype(jnp.float32)[None, :])
+    return out[:, 0, :]
 
 
 @jax.jit

@@ -1,9 +1,11 @@
 """Public jitted wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; on this CPU container they run in
-``interpret=True`` mode (the Pallas interpreter executes the kernel body in
-Python), which is the validation path mandated by the target spec.  The
-backend is auto-detected; callers can force either mode.
+On a TPU the kernels are compiled by Mosaic; on any other backend they
+run under the Pallas interpreter (``interpret=True``), which executes the
+kernel body with jax ops and checks results, not speed.  The mode follows
+``jax.default_backend()``; callers can force either.  A test that compiles
+for a described chip from a CPU process must pass ``interpret=False``
+itself (see ``tests/test_tpu_compile.py``).
 
 Profiling hooks: ``set_profiler(metrics_registry)`` attaches an
 ``obs.MetricsRegistry`` to every entry point below — each call is then

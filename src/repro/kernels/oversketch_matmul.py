@@ -19,26 +19,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_TILE_D = 256
 DEFAULT_TILE_B = 256
 
 
-def _kernel(mask_ref, ai_ref, aj_ref, out_ref):
+def _kernel(mask_ref, ai_ref, aj_ref, out_ref, *, b_tiles: int):
     r = pl.program_id(2)
 
     @pl.when(r == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    m = mask_ref[0]                       # scalar mask for this sketch block
-    ai = ai_ref[0]                        # (tb, tdi)
+    m = mask_ref[r // b_tiles]            # scalar mask for this sketch block
+    ai = m * ai_ref[0]                    # (tb, tdi)
     aj = aj_ref[0]                        # (tb, tdj)
-    contrib = jax.lax.dot_general(
+    out_ref[...] += jax.lax.dot_general(
         ai, aj, (((0,), (0,)), ((), ())),
         preferred_element_type=out_ref.dtype)
-    out_ref[...] += m * contrib
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "tile_b", "interpret"))
@@ -57,10 +57,12 @@ def oversketch_gram(a_tilde: jax.Array, survivors: jax.Array, *,
     mask = survivors.astype(jnp.float32)
 
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, b_tiles=bt),
         grid=(dt, dt, k * bt),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j, r: (r // bt,)),
+            # The (K,) survivor mask is one whole SMEM array, read as a
+            # scalar per block.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, tb, td), lambda i, j, r: (r // bt, r % bt, i)),
             pl.BlockSpec((1, tb, td), lambda i, j, r: (r // bt, r % bt, j)),
         ],

@@ -55,30 +55,54 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_TILE_N = 256
-# Budget for the kernel's resident VMEM working set (headroom under the
-# ~16 MB/core ceiling).  Since the grid tiles the output, the budget is a
-# function of d_tile, not d: it bounds the TILE, never declines the call —
-# pick_d_tile shrinks the tile until the working set fits.
-MAX_FUSED_VMEM_BYTES = 12 * 1024 * 1024
+# Budget for the kernel's VMEM working set as ``fused_vmem_bytes`` counts
+# it.  Since the grid tiles the output, the budget is a function of
+# d_tile, not d: it bounds the TILE, never declines the call — pick_d_tile
+# shrinks the tile until the working set fits.  Each call asks the
+# compiler for ``vmem_limit`` of its counted working set (the v5e default
+# scope is 16 MiB of the core's 128 MiB), so every tile within the budget
+# compiles at default and at highest matmul precision.
+MAX_FUSED_VMEM_BYTES = 20 * 1024 * 1024
+VMEM_HEADROOM_BYTES = 2 * 1024 * 1024
 MIN_D_TILE = 128
 
 
 def fused_vmem_bytes(block_size: int, d_tile: int,
-                     tile_n: int = DEFAULT_TILE_N, nnz: int = 1) -> int:
-    """Working-set bytes for one (d_i, d_j) program: two double-buffered A
-    column panels, the encode matrix (nnz sign/bucket layers), two A_tilde
-    scratch accumulators, one output tile (see kernels/README.md)."""
+                     tile_n: int = DEFAULT_TILE_N, nnz: int = 1,
+                     single: bool = False) -> int:
+    """VMEM bytes one (d_i, d_j) program allocates, f32: the
+    double-buffered A column panels (one on the single-tile grid, two on
+    the tiled grid), the double-buffered output tile, the A_tilde scratch
+    accumulators, the double-buffered sign/bucket blocks (nnz layers,
+    sublane-padded to 8) and the in-kernel temporaries (three (b, tn)
+    encode-sized arrays, one (b, td) panel product).  An upper bound on
+    the smallest scoped-VMEM limit the v5e compiler accepts at default
+    precision: within 5% at epsilon's tiled width, loose on the
+    single-tile grid (table in kernels/README.md)."""
     td = d_tile + ((-d_tile) % 128)
-    return 4 * (4 * tile_n * td + tile_n * block_size
-                + 2 * nnz * tile_n + 2 * block_size * td + td * td)
+    panels = 1 if single else 2
+    sub = 8 * -(-nnz // 8)
+    return 4 * (2 * panels * tile_n * td + 2 * td * td
+                + (panels + 1) * block_size * td
+                + 4 * sub * tile_n + 3 * block_size * tile_n)
+
+
+def vmem_limit(counted: int) -> int:
+    """Scoped VMEM to ask the compiler for, given a counted working set.
+    Highest matmul precision splits each f32 matmul operand into bf16
+    parts in VMEM (19.8 MiB against 15.8 MiB counted at epsilon's tile,
+    b = 256, d_tile = 1024); the extra half covers that, the headroom
+    Mosaic's internal scratch."""
+    return counted + counted // 2 + VMEM_HEADROOM_BYTES
 
 
 def fits_fused_vmem(block_size: int, d_tile: int,
-                    tile_n: int = DEFAULT_TILE_N, nnz: int = 1) -> bool:
+                    tile_n: int = DEFAULT_TILE_N, nnz: int = 1,
+                    single: bool = False) -> bool:
     """Does a (d_tile, d_tile) output tile's working set fit the budget?
     Used only to PICK d_tile (pick_d_tile) — no caller declines on it."""
-    return fused_vmem_bytes(block_size, d_tile, tile_n,
-                            nnz) <= MAX_FUSED_VMEM_BYTES
+    return fused_vmem_bytes(block_size, d_tile, tile_n, nnz,
+                            single) <= MAX_FUSED_VMEM_BYTES
 
 
 def pick_d_tile(block_size: int, d: int, tile_n: int = DEFAULT_TILE_N,
@@ -88,7 +112,7 @@ def pick_d_tile(block_size: int, d: int, tile_n: int = DEFAULT_TILE_N,
     otherwise the largest power-of-two multiple of 128 that fits (floor
     MIN_D_TILE, the lane width — below it the MXU runs padded anyway)."""
     d_pad = d + ((-d) % 128)
-    if fits_fused_vmem(block_size, d_pad, tile_n, nnz):
+    if fits_fused_vmem(block_size, d_pad, tile_n, nnz, single=True):
         return d_pad
     td = MIN_D_TILE
     while 2 * td < d_pad and fits_fused_vmem(block_size, 2 * td, tile_n, nnz):
@@ -108,34 +132,55 @@ def fused_path(block_size: int, d: int, tile_n: int = DEFAULT_TILE_N,
 
 
 def _encode_count(meta, sigma, offset, block_size):
-    """Summed signed one-hot layers (tn, b): meta/sigma are (s, tn) slices
-    (s = 1 is plain count-sketch; s > 1 is SJLT, scaled by 1/sqrt(s))."""
+    """Summed signed one-hot layers, transposed (b, tn): meta/sigma are
+    (s, tn) slices, one lane row per layer (s = 1 is plain count-sketch;
+    s > 1 is SJLT, scaled by 1/sqrt(s))."""
     s, tn = sigma.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tn, block_size), 1)
-    enc = jnp.zeros((tn, block_size), jnp.float32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (block_size, tn), 0)
+    enc = jnp.zeros((block_size, tn), jnp.float32)
     for t in range(s):   # s is static and tiny (1..8): unrolled layers
-        enc = enc + jnp.where(meta[t][:, None] == iota,
-                              sigma[t][:, None], 0.0)
+        enc = enc + jnp.where(meta[t:t + 1, :] == iota,
+                              sigma[t:t + 1, :], 0.0)
     if s > 1:
         enc = enc * (1.0 / math.sqrt(float(s)))
     return enc
 
 
 def _encode_srht(meta, sigma, offset, block_size):
-    """Sampled Hadamard mix (tn, b): meta is the (b,) sampled-row vector,
-    sigma the (1, tn) sign slice."""
+    """Sampled Hadamard mix, transposed (b, tn): meta is the (b, 1)
+    sampled-row column, sigma the (1, tn) sign row."""
     tn = sigma.shape[-1]
-    g = jax.lax.broadcasted_iota(jnp.int32, (tn, block_size), 0) + offset
-    bits = jax.lax.population_count(jnp.bitwise_and(g, meta[None, :]))
-    had = jnp.where(bits % 2 == 0, 1.0, -1.0)
-    return sigma[0][:, None] * had * (1.0 / math.sqrt(float(block_size)))
+    g = jax.lax.broadcasted_iota(jnp.int32, (block_size, tn), 1) + offset
+    bits = jax.lax.population_count(jnp.bitwise_and(g, meta))
+    had = jnp.where(jnp.bitwise_and(bits, 1) == 0, 1.0, -1.0)
+    return sigma * had * (1.0 / math.sqrt(float(block_size)))
 
 
 _ENCODERS = {"count": _encode_count, "srht": _encode_srht}
 
 
+def _panel(a_ref, r, tile_n, n_rows):
+    """Row panel r of A.  A is not padded in HBM: when tile_n does not
+    divide n the last panel overhangs the array, and its rows past n hold
+    whatever the buffer held, so they are zeroed here (their sign is 0
+    too, but 0 * NaN is not 0).  Columns past d only reach output rows and
+    columns past d, which the caller slices off."""
+    a = a_ref[...]
+    if n_rows % tile_n:
+        row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0) + r * tile_n
+        a = jnp.where(row < n_rows, a, 0.0).astype(a.dtype)
+    return a
+
+
+def _gram_fold(at_i, at_j, m):
+    """m * at_i^T at_j for (b, td) panels -> (td, td); the survivor weight
+    scales the small panel, not the output tile."""
+    return jax.lax.dot_general(m * at_i, at_j, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel_single(mask_ref, meta_ref, sigma_ref, a_ref, out_ref, acc_ref, *,
-                   mode: str, block_size: int, tile_n: int):
+                   mode: str, block_size: int, tile_n: int, n_rows: int):
     """Single-tile specialization (d_t == 1): the whole (d_pad, d_pad)
     output is resident, A streams once per block, zero encode recompute."""
     kk = pl.program_id(2)
@@ -149,25 +194,21 @@ def _kernel_single(mask_ref, meta_ref, sigma_ref, a_ref, out_ref, acc_ref, *,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]                            # (tn, d_pad)
+    a = _panel(a_ref, r, tile_n, n_rows)      # (tn, d_pad)
     enc = _ENCODERS[mode](meta_ref[0], sigma_ref[0], r * tile_n, block_size)
     # MXU: (b, tn) @ (tn, d_pad) accumulated into the resident panel.
-    acc_ref[...] += jax.lax.dot_general(
-        enc.astype(a.dtype), a, (((0,), (0,)), ((), ())),
-        preferred_element_type=acc_ref.dtype)
+    acc_ref[...] += jnp.dot(enc.astype(a.dtype), a,
+                            preferred_element_type=acc_ref.dtype)
 
     @pl.when(r == pl.num_programs(3) - 1)
     def _fold_gram():
         at = acc_ref[...]                     # (b, d_pad) complete A_tilde_k
-        m = mask_ref[0]
-        out_ref[...] += m * jax.lax.dot_general(
-            at, at, (((0,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype)
+        out_ref[...] += _gram_fold(at, at, mask_ref[kk])
 
 
 def _kernel_tiled(mask_ref, meta_ref, sigma_ref, ai_ref, aj_ref, out_ref,
                   acc_i_ref, acc_j_ref, *, mode: str, block_size: int,
-                  tile_n: int):
+                  tile_n: int, n_rows: int):
     """General d-tiled grid: each program owns one (td, td) output tile and
     two (b, td) A_tilde column-panel accumulators.  On diagonal tiles
     (i == j) the j-panel is the i-panel, so its matmul is skipped and the
@@ -186,30 +227,24 @@ def _kernel_tiled(mask_ref, meta_ref, sigma_ref, ai_ref, aj_ref, out_ref,
         acc_i_ref[...] = jnp.zeros_like(acc_i_ref)
         acc_j_ref[...] = jnp.zeros_like(acc_j_ref)
 
-    # (tn, b) encode matrix for this (block, row-panel); padded rows carry
+    # (b, tn) encode matrix for this (block, row-panel); padded rows carry
     # sigma 0 so they contribute nothing.
     enc = _ENCODERS[mode](meta_ref[0], sigma_ref[0], r * tile_n, block_size)
-    ai = ai_ref[...]                          # (tn, td) column panel i
+    ai = _panel(ai_ref, r, tile_n, n_rows)    # (tn, td) column panel i
     enc = enc.astype(ai.dtype)
-    acc_i_ref[...] += jax.lax.dot_general(
-        enc, ai, (((0,), (0,)), ((), ())),
-        preferred_element_type=acc_i_ref.dtype)
+    acc_i_ref[...] += jnp.dot(enc, ai, preferred_element_type=acc_i_ref.dtype)
 
     @pl.when(i != j)
     def _acc_j():
-        acc_j_ref[...] += jax.lax.dot_general(
-            enc, aj_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_j_ref.dtype)
+        acc_j_ref[...] += jnp.dot(enc, _panel(aj_ref, r, tile_n, n_rows),
+                                  preferred_element_type=acc_j_ref.dtype)
 
     @pl.when(r == pl.num_programs(3) - 1)
     def _fold_gram():
         # Block k's panels are complete: fold its masked Gram tile.
-        m = mask_ref[0]
         at_i = acc_i_ref[...]
         at_j = jnp.where(i == j, at_i, acc_j_ref[...])
-        out_ref[...] += m * jax.lax.dot_general(
-            at_i, at_j, (((0,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype)
+        out_ref[...] += _gram_fold(at_i, at_j, mask_ref[kk])
 
 
 @functools.partial(jax.jit,
@@ -227,20 +262,26 @@ def _sketch_gram(mask: jax.Array, meta: jax.Array, sigma: jax.Array,
     if single:
         td = d_pad128
     n_pad, d_pad = (-n) % tn, (-d) % td
-    if n_pad or d_pad:
-        a = jnp.pad(a, ((0, n_pad), (0, d_pad)))
-        # Padded rows get sigma 0 so they contribute nothing.
+    if n_pad:
+        # Only the small (K, s, n) sign/bucket rows are padded (sign 0 =>
+        # no contribution); A's overhanging panel is masked in-kernel, so
+        # no padded copy of the (n, d) operand is ever made.
         sigma = jnp.pad(sigma, ((0, 0), (0, 0), (0, n_pad)))
         if mode == "count":
             meta = jnp.pad(meta, ((0, 0), (0, 0), (0, n_pad)))
     n_t, d_t = (n + n_pad) // tn, (d + d_pad) // td
+    # Sign/bucket rows are (1, s, tn) lane blocks of (K, s, n); the SRHT
+    # sampled rows are a (b, 1) sublane column of (K, b, 1).  The survivor
+    # mask is one whole (K,) array in SMEM, read as a scalar per block.
     meta_spec = (pl.BlockSpec((1, s, tn), lambda i, j, kk, r: (kk, 0, r))
                  if mode == "count"
-                 else pl.BlockSpec((1, block_size),
-                                   lambda i, j, kk, r: (kk, 0)))
-    common = dict(mode=mode, block_size=block_size, tile_n=tn)
+                 else pl.BlockSpec((1, block_size, 1),
+                                   lambda i, j, kk, r: (kk, 0, 0)))
+    if mode != "count":
+        meta = meta[:, :, None]
+    common = dict(mode=mode, block_size=block_size, tile_n=tn, n_rows=n)
     in_specs = [
-        pl.BlockSpec((1,), lambda i, j, kk, r: (kk,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         meta_spec,
         pl.BlockSpec((1, s, tn), lambda i, j, kk, r: (kk, 0, r)),
         pl.BlockSpec((tn, td), lambda i, j, kk, r: (r, i)),
@@ -257,6 +298,7 @@ def _sketch_gram(mask: jax.Array, meta: jax.Array, sigma: jax.Array,
         scratch = [pltpu.VMEM((block_size, td), jnp.float32),
                    pltpu.VMEM((block_size, td), jnp.float32)]
 
+    vmem = fused_vmem_bytes(block_size, td, tn, s, single)
     out = pl.pallas_call(
         kernel,
         grid=(d_t, d_t, k, n_t),
@@ -264,6 +306,8 @@ def _sketch_gram(mask: jax.Array, meta: jax.Array, sigma: jax.Array,
         out_specs=pl.BlockSpec((td, td), lambda i, j, kk, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d + d_pad, d + d_pad), jnp.float32),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(vmem)),
         interpret=interpret,
     )(*operands)
     n_avail = jnp.maximum(mask.sum(), 1.0)

@@ -43,14 +43,19 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.sketch_gram import vmem_limit
 
 
 DEFAULT_TILE_D = 256
 DEFAULT_TILE_R = 8
-# Monolithic-panel budget: double-buffered in+out (n, td) blocks must fit
-# comfortably under the ~16 MB/core VMEM ceiling next to the factor
-# matrices; beyond this the dispatcher switches to the two-pass kernel.
-MAX_PANEL_BYTES = 4 * 1024 * 1024
+# VMEM budget for one FWHT kernel, as ``panel_vmem_bytes`` /
+# ``two_pass_vmem_bytes`` count it.  The monolithic kernel runs while its
+# panel fits; past that the dispatcher switches to the two-pass kernel,
+# whose column tile shrinks until it fits.  Each call asks the compiler
+# for ``sketch_gram.vmem_limit`` of its counted bytes.
+MAX_PANEL_BYTES = 16 * 1024 * 1024
 
 
 def _split_pow2(n: int):
@@ -108,13 +113,40 @@ def _check_pow2(n: int) -> None:
         raise ValueError(f"fwht length {n} must be a power of two")
 
 
-def _pad_d(x: jax.Array, tile_d: int):
-    d = x.shape[-1]
-    td = min(tile_d, max(128, d))
-    d_pad = (-d) % td
-    if d_pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, d_pad)))
-    return x, td, (d + d_pad) // td
+def _tile_d(tile_d: int, d: int) -> int:
+    """Column tile: a multiple of the 128-lane width, at most tile_d.  The
+    last tile may overhang d; columns transform independently, so the
+    overhang only reaches output columns past d, which are never stored."""
+    return max(128, min(tile_d, d + ((-d) % 128)) // 128 * 128)
+
+
+def _params(vmem_bytes: int):
+    # Same rule as the fused Gram: counted bytes, half again for highest
+    # precision's bf16 operand splits, plus headroom.
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(vmem_bytes))
+
+
+def two_pass_vmem_bytes(n: int, tile_d: int,
+                        tile_r: int = DEFAULT_TILE_R) -> int:
+    """VMEM of the two-pass kernel's larger pass, f32: double-buffered in
+    and out blocks, three block-sized temporaries and the Hadamard factor
+    — (n2, td) blocks for the local pass, (n1, tr, td) slabs for the
+    across pass.  An upper bound on the smallest scoped-VMEM limit the
+    v5e compiler accepts (15.0 against 13.0 MiB at n = 2^18, d = 2000)."""
+    n1, n2 = _split_pow2(max(n, 1))
+    tr = min(tile_r, n2)
+    return 4 * max(7 * n2 * tile_d + n2 * n2,
+                   7 * n1 * tr * tile_d + n1 * n1)
+
+
+def _two_pass_tile_d(n: int, tile_d: int, d: int, tile_r: int,
+                     max_bytes: int) -> int:
+    """Widest column tile whose two-pass working set fits max_bytes
+    (floor 128, the lane width)."""
+    td = _tile_d(tile_d, d)
+    while td > 128 and two_pass_vmem_bytes(n, td, tile_r) > max_bytes:
+        td -= 128
+    return td
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "tile_r", "interpret"))
@@ -130,20 +162,22 @@ def fwht_two_pass(x: jax.Array, *, tile_d: int = DEFAULT_TILE_D,
     k, n, d = x.shape
     _check_pow2(n)
     n1, n2 = _split_pow2(n)
-    x, td, d_t = _pad_d(x, tile_d)
-    d_tot = td * d_t
-    x4 = x.astype(jnp.float32).reshape(k, n1, n2, d_tot)
+    tr = min(tile_r, n2)                 # both powers of two => tr | n2
+    td = _two_pass_tile_d(n, tile_d, d, tile_r, MAX_PANEL_BYTES)
+    d_t = pl.cdiv(d, td)
+    params = _params(two_pass_vmem_bytes(n, td, tile_r))
+    x4 = x.astype(jnp.float32).reshape(k, n1, n2, d)
 
     mid = pl.pallas_call(
         functools.partial(_local_kernel, n2=n2),
         grid=(k, n1, d_t),
         in_specs=[pl.BlockSpec((1, 1, n2, td), lambda kk, q, j: (kk, q, 0, j))],
         out_specs=pl.BlockSpec((1, 1, n2, td), lambda kk, q, j: (kk, q, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((k, n1, n2, d_tot), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, n1, n2, d), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
     )(x4)
 
-    tr = min(tile_r, n2)                 # both powers of two => tr | n2
     out = pl.pallas_call(
         functools.partial(_across_kernel, n1=n1,
                           scale=1.0 / math.sqrt(float(n))),
@@ -152,19 +186,21 @@ def fwht_two_pass(x: jax.Array, *, tile_d: int = DEFAULT_TILE_D,
                                lambda kk, m, j: (kk, 0, m, j))],
         out_specs=pl.BlockSpec((1, n1, tr, td),
                                lambda kk, m, j: (kk, 0, m, j)),
-        out_shape=jax.ShapeDtypeStruct((k, n1, n2, d_tot), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, n1, n2, d), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
     )(mid)
-    return out.reshape(k, n, d_tot)[:, :, :d]
+    return out.reshape(k, n, d)
 
 
 def panel_vmem_bytes(n: int, tile_d: int = DEFAULT_TILE_D,
                      d: int = DEFAULT_TILE_D) -> int:
-    """VMEM footprint of the monolithic kernel's resident panel (the
-    dispatch quantity; see kernels/README.md for the full budget)."""
-    td = min(tile_d, max(128, d))
+    """VMEM the monolithic kernel allocates, f32: double-buffered in and
+    out (n, td) panels, two panel-sized matmul temporaries and the two
+    Hadamard factors (the dispatch quantity; see kernels/README.md)."""
+    td = _tile_d(tile_d, d)
     n1, n2 = _split_pow2(max(n, 1))
-    return 2 * n * td * 4 + (n1 * n1 + n2 * n2) * 4
+    return 4 * (6 * n * td + n1 * n1 + n2 * n2)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "interpret",
@@ -176,23 +212,23 @@ def fwht(x: jax.Array, *, tile_d: int = DEFAULT_TILE_D,
 
     n must be a power of two (callers zero-pad; padded rows mix harmlessly
     since the transform is linear).  Satisfies fwht(fwht(x)) == x.
-    Dispatches to the monolithic panel kernel while the panel fits
+    Dispatches to the monolithic panel kernel while its panel fits
     ``max_panel_bytes`` of VMEM, else to the two-pass tiled kernel.
     """
     k, n, d = x.shape
     _check_pow2(n)
-    if panel_vmem_bytes(n, tile_d, d) > max_panel_bytes:
+    vmem = panel_vmem_bytes(n, tile_d, d)
+    if vmem > max_panel_bytes:
         return fwht_two_pass(x, tile_d=tile_d, interpret=interpret)
     n1, n2 = _split_pow2(n)
-    x, td, d_t = _pad_d(x, tile_d)
-    d_tot = td * d_t
+    td = _tile_d(tile_d, d)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_panel_kernel, n1=n1, n2=n2),
-        grid=(k, d_t),
+        grid=(k, pl.cdiv(d, td)),
         in_specs=[pl.BlockSpec((1, n, td), lambda kk, j: (kk, 0, j))],
         out_specs=pl.BlockSpec((1, n, td), lambda kk, j: (kk, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((k, n, d_tot), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, n, d), jnp.float32),
+        compiler_params=_params(vmem),
         interpret=interpret,
     )(x.astype(jnp.float32))
-    return out[:, :, :d]

@@ -24,6 +24,8 @@ import dataclasses
 import math
 from typing import Dict
 
+import jax
+
 from repro.models.common import ModelConfig
 from repro.models.registry import ShapeSpec
 
@@ -146,15 +148,15 @@ def cell_costs(cfg: ModelConfig, shape: ShapeSpec, chips: int,
 
     # -------------------------------------------------- HBM bytes per chip --
     from repro.launch.dryrun import sharded_param_bytes
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import production_mesh_shape
     from repro.models.registry import ModelBundle
     bundle = ModelBundle(cfg)
-    try:
-        m = mesh if mesh is not None else \
-            make_production_mesh(multi_pod=(chips == 512))
-        param_bytes_chip = sharded_param_bytes(bundle, m)
-    except Exception:   # mesh unavailable (too few devices): policy estimate
-        param_bytes_chip = bundle.param_count() * BF16 / mesh_model
+    # The count reads only the mesh's axis sizes, so an abstract mesh of
+    # the production shape stands in: it does not depend on how many
+    # devices this process has.
+    m = mesh if mesh is not None else jax.sharding.AbstractMesh(
+        *production_mesh_shape(multi_pod=(chips == 512)))
+    param_bytes_chip = sharded_param_bytes(bundle, m)
 
     if shape.kind == "train":
         # fwd+bwd read params twice, opt reads/writes moments + params

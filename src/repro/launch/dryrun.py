@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell with ShapeDtypeStruct stand-ins (no allocation), print
 memory/cost analysis and the collective schedule, and emit the roofline
 terms (EXPERIMENTS.md §Dry-run / §Roofline read from this output).
 
-Usage:
+Usage (main() gives the CPU backend 512 placeholder devices for the
+production meshes, so run it as its own process):
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] --json-out out.json
 """
@@ -14,6 +12,7 @@ Usage:
 import argparse
 import json
 import math
+import os
 import re
 import sys
 from typing import Any, Dict, Optional, Tuple
@@ -288,6 +287,12 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main(argv=None):
+    # Before the first device query: the production meshes need 512
+    # placeholder devices.  Set here, not at import, so a process that
+    # only imports this module keeps its own XLA_FLAGS.
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default=None)
     ap.add_argument("--shape", type=str, default=None,

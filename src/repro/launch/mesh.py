@@ -6,11 +6,18 @@ from __future__ import annotations
 import jax
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def production_mesh_shape(*, multi_pod: bool = False):
     """Single pod: 16x16 = 256 chips ("data", "model").
-    Multi-pod: 2x16x16 = 512 chips ("pod", "data", "model")."""
+    Multi-pod: 2x16x16 = 512 chips ("pod", "data", "model").
+    Returns (axis sizes, axis names)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return shape, axes
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    """The production mesh over real (or placeholder) devices."""
+    shape, axes = production_mesh_shape(multi_pod=multi_pod)
     return jax.make_mesh(shape, axes,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 

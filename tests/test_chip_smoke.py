@@ -1,0 +1,67 @@
+"""chip_smoke.py on CPU: its phase function at a tiny size with the same
+checks (the Pallas kernels interpreted), its compile-cache rule, and
+main()'s refusal to run without a TPU."""
+import os
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)            # chip_smoke.py lives at the repo root
+
+import chip_smoke  # noqa: E402
+from repro import sketching  # noqa: E402
+from repro.core import OverSketchConfig  # noqa: E402
+
+TINY = chip_smoke.Phase("tiny", 600, 20, 100, OverSketchConfig(256, 64, 0.25),
+                        coded_block_rows=64, path="fused", iters=6)
+
+
+def test_tiny_phase_passes_every_check_on_cpu():
+    out = chip_smoke.run_phase(TINY, seed=0)
+    assert out["kernel_path"] == "fused"
+    assert out["agree_rel"] <= chip_smoke.AGREE_RTOL
+    assert out["hessian_rel"] <= chip_smoke.HESSIAN_RTOL
+    for tag in ("jnp", "kernel"):
+        assert len(out[f"{tag}_fvals"]) == TINY.iters
+        assert out[f"{tag}_vs_ref_rel"] <= chip_smoke.REF_REL
+    # Interpreted kernels lower to plain HLO: main() would refuse this.
+    assert out["custom_call"] is False
+
+
+def test_a_failed_check_raises():
+    bad = chip_smoke.Phase("tiny", 600, 20, 100,
+                           OverSketchConfig(256, 64, 0.25), 64,
+                           path="fused_tiled", iters=1)
+    with pytest.raises(AssertionError, match="kernel path"):
+        chip_smoke.run_phase(bad, seed=0)
+
+
+@pytest.mark.parametrize("name,blocks,path", [
+    ("epsilon", 148, "fused_tiled"),
+    ("a9a", 13, "fused"),
+])
+def test_phases_at_published_widths(name, blocks, path):
+    ph = {p.name: p for p in chip_smoke.PHASES}[name]
+    assert ph.sketch.total_blocks == blocks
+    assert ph.path == path
+    assert sketching.get("oversketch", ph.sketch).fused_path(ph.d) == path
+
+
+def test_compile_cache_dir_rule(tmp_path):
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    assert chip_smoke.compile_cache_dir(env) == str(tmp_path)
+    assert chip_smoke.compile_cache_dir({}) == os.path.join(REPO,
+                                                            ".jax_cache")
+
+
+def test_main_refuses_a_non_tpu_device(capsys):
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        assert chip_smoke.main([]) != 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+    out = capsys.readouterr()
+    assert out.out == ""               # no result line without a chip
+    assert "needs a TPU" in out.err
