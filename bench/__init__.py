@@ -1,0 +1,1 @@
+"""The chip benchmark: see BENCHMARK.json at the root and PERF.md."""
