@@ -182,23 +182,4 @@ def run(quick: bool = True):
     rows.append({"name": "kernel_coded_matvec_pallas_check", "us": 0.0,
                  "path": "pallas", "derived": f"max_err={err3:.2e}"})
 
-    # Measured per-op wall-clock through the ops profiler hook — the same
-    # ``kernel.<op>.us`` table ``obs.store.run_record`` persists for the
-    # ROADMAP's measured kernel auto-router; here it lands in the BENCH
-    # trajectory so the router's data source is itself regression-gated.
-    from repro import obs
-    reg = obs.MetricsRegistry()
-    ops.set_profiler(reg)
-    try:
-        ops.oversketch_gram(a_t, surv)
-        ops.fwht(xf)
-        ops.coded_block_matvec(enc, x, er)
-    finally:
-        ops.set_profiler(None)
-    measured = {n: h.percentile(50) for n, h in sorted(reg.histograms.items())
-                if n.startswith("kernel.") and n.endswith(".us")}
-    rows.append({"name": "kernel_profiled_us",
-                 "us": sum(measured.values()), "path": "pallas",
-                 "derived": ";".join(f"{n.split('.')[1]}={v:.0f}"
-                                     for n, v in measured.items())})
     return rows

@@ -19,6 +19,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
+
+from repro.obs import wall
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,10 +179,14 @@ def verified_decode(products: jax.Array, arrived: jax.Array,
     relaunch on ``ok=False`` (the paper's straggler fallback, reused).
     """
     flagged = detect_corrupted(products, arrived, code, rtol)
-    n_flagged = int(jnp.sum(flagged))
+    n_flagged = jnp.sum(flagged)
+    with TraceAnnotation(wall.SYNC_DECODE):
+        n_flagged = int(n_flagged)
     known = arrived & ~flagged
     sys_blocks, ok = peel_decode(products, known, code)
-    if not bool(ok):
+    with TraceAnnotation(wall.SYNC_DECODE):
+        ok = bool(ok)
+    if not ok:
         return None, False, n_flagged
     # Unique codeword extension of the decoded systematic part.
     row_par = sys_blocks.sum(axis=1, keepdims=True)
@@ -188,8 +195,10 @@ def verified_decode(products: jax.Array, arrived: jax.Array,
     full = jnp.concatenate([top, col_par], axis=0)     # (g+1, g+1, b)
     resid = jnp.linalg.norm(full - products, axis=-1)
     mag = jnp.linalg.norm(full, axis=-1) + jnp.finfo(jnp.float32).tiny
-    mismatch = known & (resid > rtol * mag)
-    if bool(mismatch.any()):
+    mismatch = (known & (resid > rtol * mag)).any()
+    with TraceAnnotation(wall.SYNC_DECODE):
+        mismatch = bool(mismatch)
+    if mismatch:
         return None, False, n_flagged
     y = sys_blocks.reshape(code.padded_blocks * code.block_rows)
     return y[:out_rows], True, n_flagged

@@ -28,12 +28,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 import numpy as np
 
 from repro.core import coded, linesearch, sketch, solvers, straggler
 from repro.core.objectives import Dataset
 from repro import obs, scheduler, sketching
+from repro.obs import wall
 from repro.runtime.faults import PhaseExhaustedError
 
 
@@ -312,7 +314,8 @@ class CodedMatvecEngine:
                 _, mask = phase_safe(key, "coded_decode", kk=k_min,
                                      decodable=lambda m: _decodable(
                                          ~m.reshape(g1, g1)))
-            arrived = np.asarray(mask)
+            with TraceAnnotation(wall.SYNC_MASK):
+                arrived = np.asarray(mask)
             erased = jnp.asarray(~arrived).reshape(g1, g1)
             lc = clock.last_corruption
             if lc is not None:
@@ -369,7 +372,10 @@ class CodedMatvecEngine:
                                             self.out_rows[tag])
         else:
             y, ok = self._mv(tag, v, erased)
-        if erased is not None and not bool(ok):
+        if erased is not None:
+            with TraceAnnotation(wall.SYNC_DECODE):
+                ok = bool(ok)
+        if erased is not None and not ok:
             # Decode failure (erasure pattern beyond the code): the paper's
             # master re-launches stragglers; charge a full re-execution round.
             self.fallbacks += 1
@@ -438,7 +444,8 @@ def _jitted_sketched_hessian(objective, family: "sketching.SketchFamily",
     Python left to log from — so production path selection is auditable
     against the ``BENCH_kernels.json`` per-row ``path`` field."""
     def fn(w, data, state, survivors):
-        a = objective.hess_sqrt(w, data)
+        with jax.named_scope(wall.HESS_SQRT):
+            a = objective.hess_sqrt(w, data)
         d = a.shape[1]
         reg = objective.hess_reg * jnp.eye(d, dtype=a.dtype)
         return family.gram(state, a, survivors, use_kernels=use_kernels) + reg
@@ -468,12 +475,15 @@ def _jitted_distavg_direction(objective, family: "sketching.SketchFamily",
         raise ValueError(f"unknown distavg_solver {solver!r}")
 
     def fn(w, data, g, state, survivors):
-        a = objective.hess_sqrt(w, data)
+        with jax.named_scope(wall.HESS_SQRT):
+            a = objective.hess_sqrt(w, data)
         d = a.shape[1]
-        a_t = family.apply(state, a, use_kernels=use_kernels)  # (K, b, d)
+        with jax.named_scope(wall.SKETCH):
+            a_t = family.apply(state, a, use_kernels=use_kernels)  # (K,b,d)
         eye = jnp.eye(d, dtype=a_t.dtype)
-        grams = jnp.einsum("kbd,kbe->kde", a_t, a_t) \
-            + objective.hess_reg * eye
+        with jax.named_scope(wall.GRAM):
+            grams = jnp.einsum("kbd,kbe->kde", a_t, a_t) \
+                + objective.hess_reg * eye
         p_k = -jax.vmap(lambda hk: block_solve(hk, g))(grams)
         if debias:
             p_k = sketching.debias_direction(p_k, d, b)
@@ -603,7 +613,10 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
             tel.metrics.counter(f"kernel.path.{path}").inc()
         fn = _jitted_sketched_hessian(objective, fam, cfg.use_kernels)
         h_hat = fn(w, data, state, survivors)
-        m_eff = float(jnp.sum(survivors)) * scfg.block_size
+        # Queued behind the Hessian program, so this read waits for it.
+        n_surv = jnp.sum(survivors)
+        with TraceAnnotation(wall.SYNC_SURVIVORS):
+            m_eff = float(n_surv) * scfg.block_size
         if tel.enabled:
             tel.metrics.gauge("sketch.m_eff").set(m_eff)
             tel.metrics.gauge("sketch.mp_debias").set(
@@ -739,7 +752,17 @@ def oversketched_newton(objective, data: Dataset, w0: jax.Array,
     a custom fleet (cold starts, failures, trace record/replay; see
     ``repro.runtime``).  ``history["cost"]`` logs cumulative simulated
     dollars alongside ``history["time"]``'s simulated seconds.
+
+    The call runs inside the ``osn.solve`` profiler span and each iteration
+    inside ``osn.iter``, with its stages, fleet calls and host reads under
+    the spans ``repro.obs.wall`` names.
     """
+    with TraceAnnotation(wall.SOLVE):
+        return _oversketched_newton(objective, data, w0, cfg, model)
+
+
+def _oversketched_newton(objective, data: Dataset, w0: jax.Array,
+                         cfg: NewtonConfig, model) -> NewtonResult:
     if cfg.sketch_mode not in ("blocks", "distributed-avg"):
         raise ValueError(f"unknown sketch_mode {cfg.sketch_mode!r}")
     if cfg.distavg_solver not in ("chol", "cg"):
@@ -804,196 +827,225 @@ def oversketched_newton(objective, data: Dataset, w0: jax.Array,
         tel.metrics.gauge("newton.cg_iters").set(cfg.cg_iters)
 
     for t in range(cfg.iters):
-        cfg = live_cfg
-        key, kg, kh, kl = jax.random.split(key, 4)
-        it_span = tel.trace.begin(
-            f"iter{t}", "iteration",
-            clock.time if clock is not None else float(t))
-        # One iteration = one phase DAG: gradient matvecs chain through
-        # dependency edges, the Hessian sketch is a root node launched at
-        # the iteration start (concurrent with the gradient), the line
-        # search joins both.  schedule="sequential" keeps the historical
-        # one-phase-at-a-time dispatch; the phase keys — hence masks and
-        # iterates — are the same either way.
-        dag = (scheduler.DagRun(clock, key=key)
-               if cfg.schedule == "dag" and clock is not None else None)
+        with TraceAnnotation(wall.ITER):
+            cfg = live_cfg
+            key, kg, kh, kl = jax.random.split(key, 4)
+            it_span = tel.trace.begin(
+                f"iter{t}", "iteration",
+                clock.time if clock is not None else float(t))
+            # One iteration = one phase DAG: gradient matvecs chain through
+            # dependency edges, the Hessian sketch is a root node launched
+            # at the iteration start (concurrent with the gradient), the
+            # line search joins both.  schedule="sequential" keeps the
+            # historical one-phase-at-a-time dispatch; the phase keys —
+            # hence masks and iterates — are the same either way.
+            dag = (scheduler.DagRun(clock, key=key)
+                   if cfg.schedule == "dag" and clock is not None
+                   else None)
 
-        # --- 1. gradient (straggler-resilient coded matvecs, Alg. 1) -------
-        grad_tail = None
-        if cfg.gradient_policy == "exact" or model is None:
-            g = grad_fn(w, data)
-        else:
-            # Fixed per-tag fold constants: Python's str hash is salted
-            # per process, which would break cross-process seed
-            # reproducibility of the straggler samples.
-            mv_seq = {"n": 0}
-
-            def mv(tag, v):
-                kf = jax.random.fold_in(kg, {"X": 3, "XT": 5}[tag])
-                if dag is None:
-                    return engine.matvec(tag, v, clock, kf,
-                                         cfg.gradient_policy)
-                after = (dag.last,) if dag.last is not None else ()
-                y = engine.matvec(tag, v, clock, kf, cfg.gradient_policy,
-                                  dag=dag,
-                                  name=f"grad/{mv_seq['n']}:{tag}",
-                                  after=after)
-                mv_seq["n"] += 1
-                return y
-
-            g = objective.gradient_via(w, data, mv)
-            if dag is not None:
-                grad_tail = dag.last
-
-        # --- 2+3. sketched Hessian (Alg. 2) and direction -------------------
-        m_eff = None
-        if cfg.sketch_mode == "distributed-avg":
-            # per-worker solves + master-side direction averaging
-            p, hg = _distavg_direction_phase(objective, data, w, g, cfg,
-                                             kh, clock, dag=dag,
-                                             grad_dep=grad_tail)
-        else:
-            h_hat, m_eff = _hessian_phase(objective, data, w, cfg, kh,
-                                          clock, dag=dag)
-            if h_hat is None:
-                # Fault degradation: the sketch round (and its re-dispatch)
-                # lost too many blocks — take a plain gradient step, with
-                # hg = g (H = I) keeping the weakly-convex search coherent.
-                p, hg = -g, g
-                tel.metrics.counter("newton.gradient_fallbacks").inc()
-            else:
-                p = _solve_direction(objective, h_hat, g, cfg)
-                if cfg.debias and m_eff is not None:
-                    p = sketching.debias_direction(p, p.shape[0], m_eff)
-                hg = None
-
-        # Descent guard: whatever produced p (a starved sketch, a debias
-        # factor driven past zero by casualties, a corrupted Hessian
-        # estimate that slipped through), only a finite descent direction
-        # may reach the line search — anything else degrades to steepest
-        # descent instead of diverging.
-        gp = float(jnp.vdot(g, p))
-        if not math.isfinite(gp) or gp >= 0.0:
-            p, hg = -g, g
-            tel.metrics.counter("newton.safeguard_fallbacks").inc()
-
-        # --- 4. distributed line search (Sec. 3.2) --------------------------
-        if cfg.unit_step:
-            step = jnp.asarray(1.0)
-        elif objective.strongly_convex:
-            step = linesearch.linesearch_strongly_convex(
-                objective, data, w, p, g, cfg.beta, cfg.candidates)
-        else:
-            if hg is None:
-                hg = h_hat @ g
-            step = linesearch.linesearch_weakly_convex(
-                objective, data, w, p, g, hg, cfg.beta, cfg.candidates)
-        if clock is not None and not cfg.unit_step:
-            nb = max(1, data.x.shape[0] // max(cfg.coded_block_rows, 1))
-            ls_flops = 2.0 * cfg.coded_block_rows * data.x.shape[1] * \
-                len(cfg.candidates)
-            ls_bytes = scheduler.matvec_worker_bytes(
-                cfg.coded_block_rows, data.x.shape[1])
-            ls_mem = _phase_mem(cfg.phase_memory, ls_bytes)
-            try:
-                if dag is not None:
-                    # The line search consumes p, i.e. every phase so far;
-                    # by then the clock already sits at the DAG's frontier,
-                    # so it dispatches on the engine's exact sequential
-                    # path.  The edges are still declared (sequential
-                    # dispatch ignores them for timing) so the recorded
-                    # DAG joins here and the critical-path walk can cross
-                    # the line search.
-                    dag.dispatch(scheduler.PhaseSpec(
-                        name="linesearch", workers=nb, policy="wait_all",
-                        flops_per_worker=ls_flops, comm_units=0.5,
-                        memory_gb=ls_mem, working_set_gb=_ws_gb(ls_bytes),
-                        deps=tuple(dag.results)),
-                        key=kl, sequential=True)
+            # --- 1. gradient (straggler-resilient coded matvecs, Alg. 1) ---
+            with TraceAnnotation(wall.GRADIENT):
+                grad_tail = None
+                if cfg.gradient_policy == "exact" or model is None:
+                    g = grad_fn(w, data)
                 else:
-                    clock.phase(kl, nb, policy="wait_all",
-                                flops_per_worker=ls_flops, comm_units=0.5,
-                                memory_gb=ls_mem,
+                    # Fixed per-tag fold constants: Python's str hash is
+                    # salted per process, which would break cross-process
+                    # seed reproducibility of the straggler samples.
+                    mv_seq = {"n": 0}
+
+                    def mv(tag, v):
+                        kf = jax.random.fold_in(kg, {"X": 3, "XT": 5}[tag])
+                        if dag is None:
+                            return engine.matvec(tag, v, clock, kf,
+                                                 cfg.gradient_policy)
+                        after = (dag.last,) if dag.last is not None else ()
+                        y = engine.matvec(
+                            tag, v, clock, kf, cfg.gradient_policy, dag=dag,
+                            name=f"grad/{mv_seq['n']}:{tag}", after=after)
+                        mv_seq["n"] += 1
+                        return y
+
+                    g = objective.gradient_via(w, data, mv)
+                    if dag is not None:
+                        grad_tail = dag.last
+
+            # --- 2+3. sketched Hessian (Alg. 2) and direction ---------------
+            m_eff = None
+            with TraceAnnotation(wall.HESSIAN):
+                if cfg.sketch_mode == "distributed-avg":
+                    # per-worker solves + master-side direction averaging
+                    p, hg = _distavg_direction_phase(
+                        objective, data, w, g, cfg, kh, clock, dag=dag,
+                        grad_dep=grad_tail)
+                else:
+                    h_hat, m_eff = _hessian_phase(objective, data, w, cfg,
+                                                  kh, clock, dag=dag)
+            with TraceAnnotation(wall.DIRECTION):
+                if cfg.sketch_mode == "distributed-avg":
+                    pass            # p came with the Hessian phase
+                elif h_hat is None:
+                    # Fault degradation: the sketch round (and its
+                    # re-dispatch) lost too many blocks — take a plain
+                    # gradient step, with hg = g (H = I) keeping the
+                    # weakly-convex search coherent.
+                    p, hg = -g, g
+                    tel.metrics.counter("newton.gradient_fallbacks").inc()
+                else:
+                    p = _solve_direction(objective, h_hat, g, cfg)
+                    if cfg.debias and m_eff is not None:
+                        p = sketching.debias_direction(p, p.shape[0], m_eff)
+                    hg = None
+
+                # Descent guard: whatever produced p (a starved sketch, a
+                # debias factor driven past zero by casualties, a corrupted
+                # Hessian estimate that slipped through), only a finite
+                # descent direction may reach the line search — anything
+                # else degrades to steepest descent instead of diverging.
+                gp = jnp.vdot(g, p)
+                with TraceAnnotation(wall.SYNC_GUARD):
+                    gp = float(gp)
+                if not math.isfinite(gp) or gp >= 0.0:
+                    p, hg = -g, g
+                    tel.metrics.counter("newton.safeguard_fallbacks").inc()
+
+            # --- 4. distributed line search (Sec. 3.2) ---------------------
+            with TraceAnnotation(wall.LINESEARCH):
+                if cfg.unit_step:
+                    step = jnp.asarray(1.0)
+                elif objective.strongly_convex:
+                    step = linesearch.linesearch_strongly_convex(
+                        objective, data, w, p, g, cfg.beta, cfg.candidates)
+                else:
+                    if hg is None:
+                        hg = h_hat @ g
+                    step = linesearch.linesearch_weakly_convex(
+                        objective, data, w, p, g, hg, cfg.beta,
+                        cfg.candidates)
+                if clock is not None and not cfg.unit_step:
+                    nb = max(1, data.x.shape[0]
+                             // max(cfg.coded_block_rows, 1))
+                    ls_flops = 2.0 * cfg.coded_block_rows * \
+                        data.x.shape[1] * len(cfg.candidates)
+                    ls_bytes = scheduler.matvec_worker_bytes(
+                        cfg.coded_block_rows, data.x.shape[1])
+                    ls_mem = _phase_mem(cfg.phase_memory, ls_bytes)
+                    try:
+                        if dag is not None:
+                            # The line search consumes p, i.e. every phase
+                            # so far; by then the clock already sits at the
+                            # DAG's frontier, so it dispatches on the
+                            # engine's exact sequential path.  The edges
+                            # are still declared (sequential dispatch
+                            # ignores them for timing) so the recorded DAG
+                            # joins here and the critical-path walk can
+                            # cross the line search.
+                            dag.dispatch(scheduler.PhaseSpec(
+                                name="linesearch", workers=nb,
+                                policy="wait_all", flops_per_worker=ls_flops,
+                                comm_units=0.5, memory_gb=ls_mem,
                                 working_set_gb=_ws_gb(ls_bytes),
-                                phase_name="linesearch")
-            except PhaseExhaustedError:
-                if cfg.fault_fallback == "raise":
-                    raise
-                # Billed, lost: the search objective values are master-side
-                # math, so the chosen step survives the dead fan-out.
-                tel.metrics.counter("newton.fault_fallbacks").inc()
+                                deps=tuple(dag.results)),
+                                key=kl, sequential=True)
+                        else:
+                            clock.phase(kl, nb, policy="wait_all",
+                                        flops_per_worker=ls_flops,
+                                        comm_units=0.5, memory_gb=ls_mem,
+                                        working_set_gb=_ws_gb(ls_bytes),
+                                        phase_name="linesearch")
+                    except PhaseExhaustedError:
+                        if cfg.fault_fallback == "raise":
+                            raise
+                        # Billed, lost: the search objective values are
+                        # master-side math, so the chosen step survives
+                        # the dead fan-out.
+                        tel.metrics.counter("newton.fault_fallbacks").inc()
 
-        w = w + step * p
+                w = w + step * p
 
-        hist["iter"].append(t)
-        f_now = float(val_fn(w, data))
-        hist["fval"].append(f_now)
-        hist["gnorm"].append(float(jnp.linalg.norm(grad_fn(w, data))))
-        hist["step"].append(float(step))
-        hist["time"].append(clock.time if clock is not None else float(t + 1))
-        hist["cost"].append(clock.dollars if clock is not None else 0.0)
-        hist["sketch_dim"].append(live_cfg.sketch.sketch_dim)
+            with TraceAnnotation(wall.HISTORY):
+                hist["iter"].append(t)
+                f_now = val_fn(w, data)
+                with TraceAnnotation(wall.SYNC_HISTORY):
+                    f_now = float(f_now)
+                hist["fval"].append(f_now)
+                gnorm = jnp.linalg.norm(grad_fn(w, data))
+                with TraceAnnotation(wall.SYNC_HISTORY):
+                    hist["gnorm"].append(float(gnorm))
+                with TraceAnnotation(wall.SYNC_HISTORY):
+                    hist["step"].append(float(step))
+                hist["time"].append(clock.time if clock is not None
+                                    else float(t + 1))
+                hist["cost"].append(clock.dollars if clock is not None
+                                    else 0.0)
+                hist["sketch_dim"].append(live_cfg.sketch.sketch_dim)
 
-        if tel.enabled:
-            tel.metrics.gauge("newton.sketch_dim").set(
-                live_cfg.sketch.sketch_dim)
-            # Per-iteration seconds/dollars deltas: the cost-per-iteration
-            # streams the online health monitors watch for blowups.
-            many = len(hist["time"]) > 1
-            tel.metrics.gauge("newton.iter_seconds").set(
-                hist["time"][-1] - (hist["time"][-2] if many else 0.0))
-            tel.metrics.gauge("newton.iter_dollars").set(
-                hist["cost"][-1] - (hist["cost"][-2] if many else 0.0))
-            if cfg.solver in ("cg", "minres"):
-                tel.metrics.gauge("newton.cg_iters").set(cfg.cg_iters)
-            if dag is not None and dag.results:
-                # Per-iteration critical-path + slack report (ROADMAP's
-                # DagResult analytics item), attached to the iteration
-                # span so exporters and make_report can render it.
-                rep = dag.critical_path()
-                tel.trace.set_attrs(
-                    it_span,
-                    critical_path=list(rep.critical_path),
-                    dag_makespan=rep.makespan,
-                    slack={n: p.slack for n, p in rep.phases.items()})
-        tel.trace.end(it_span,
-                      clock.time if clock is not None else float(t + 1))
+                if tel.enabled:
+                    tel.metrics.gauge("newton.sketch_dim").set(
+                        live_cfg.sketch.sketch_dim)
+                    # Per-iteration seconds/dollars deltas: the
+                    # cost-per-iteration streams the online health monitors
+                    # watch for blowups.
+                    many = len(hist["time"]) > 1
+                    tel.metrics.gauge("newton.iter_seconds").set(
+                        hist["time"][-1] - (hist["time"][-2] if many else 0.0))
+                    tel.metrics.gauge("newton.iter_dollars").set(
+                        hist["cost"][-1] - (hist["cost"][-2] if many else 0.0))
+                    if cfg.solver in ("cg", "minres"):
+                        tel.metrics.gauge("newton.cg_iters").set(cfg.cg_iters)
+                    if dag is not None and dag.results:
+                        # Per-iteration critical-path + slack report
+                        # (ROADMAP's DagResult analytics item), attached to
+                        # the iteration span so exporters and make_report
+                        # can render it.
+                        rep = dag.critical_path()
+                        tel.trace.set_attrs(
+                            it_span,
+                            critical_path=list(rep.critical_path),
+                            dag_makespan=rep.makespan,
+                            slack={n: p.slack for n, p in rep.phases.items()})
+                tel.trace.end(it_span, clock.time if clock is not None
+                              else float(t + 1))
 
-        # --- adaptive sketch growth (paper Thm 3.2 remark) ------------------
-        if cfg.adaptive_sketch:
-            if cfg.adaptive_metric == "mp":
-                # Grow when the MEASURED Marchenko-Pastur factor of the
-                # surviving sketch rows says the sketch is too biased to
-                # trust — a leading indicator available from iteration 0,
-                # unlike the trailing f-decrease stall below.
-                stalled = m_eff is not None and sketching.mp_stalled(
-                    int(p.shape[0]), m_eff, cfg.adaptive_mp_target)
-            elif prev_f is not None:
-                decrease = prev_f - f_now
-                # Stall = progress fell off vs the last iteration; an
-                # INCREASE in f (decrease < 0, the eps-too-coarse
-                # divergence regime) is always a stall, whatever the
-                # previous decrease was.
-                stalled = decrease < 0 or (
-                    prev_decrease is not None and prev_decrease > 0
-                    and decrease < cfg.adaptive_stall_ratio * prev_decrease)
-            else:
-                stalled = False
-            grown = live_cfg.sketch.sketch_dim // init_sketch_dim
-            if stalled and grown < cfg.adaptive_max_growth:
-                new_sketch = dataclasses.replace(
-                    live_cfg.sketch,
-                    sketch_dim=live_cfg.sketch.sketch_dim * 2)
-                live_cfg = dataclasses.replace(live_cfg, sketch=new_sketch)
-                tel.metrics.counter("newton.adaptive_growth").inc()
-        if prev_f is not None:
-            prev_decrease = prev_f - f_now
-        prev_f = f_now
-        if cfg.track_test_error and data.x_test is not None:
-            hist["test_error"].append(
-                float(objective.error(w, data.x_test, data.y_test)))
-        else:
-            hist["test_error"].append(float("nan"))
+                # --- adaptive sketch growth (paper Thm 3.2 remark) ----------
+                if cfg.adaptive_sketch:
+                    if cfg.adaptive_metric == "mp":
+                        # Grow when the MEASURED Marchenko-Pastur factor of
+                        # the surviving sketch rows says the sketch is too
+                        # biased to trust — a leading indicator available
+                        # from iteration 0, unlike the trailing f-decrease
+                        # stall below.
+                        stalled = m_eff is not None and sketching.mp_stalled(
+                            int(p.shape[0]), m_eff, cfg.adaptive_mp_target)
+                    elif prev_f is not None:
+                        decrease = prev_f - f_now
+                        # Stall = progress fell off vs the last iteration;
+                        # an INCREASE in f (decrease < 0, the eps-too-coarse
+                        # divergence regime) is always a stall, whatever
+                        # the previous decrease was.
+                        stalled = decrease < 0 or (
+                            prev_decrease is not None and prev_decrease > 0
+                            and decrease
+                            < cfg.adaptive_stall_ratio * prev_decrease)
+                    else:
+                        stalled = False
+                    grown = live_cfg.sketch.sketch_dim // init_sketch_dim
+                    if stalled and grown < cfg.adaptive_max_growth:
+                        new_sketch = dataclasses.replace(
+                            live_cfg.sketch,
+                            sketch_dim=live_cfg.sketch.sketch_dim * 2)
+                        live_cfg = dataclasses.replace(live_cfg,
+                                                       sketch=new_sketch)
+                        tel.metrics.counter("newton.adaptive_growth").inc()
+                if prev_f is not None:
+                    prev_decrease = prev_f - f_now
+                prev_f = f_now
+                if cfg.track_test_error and data.x_test is not None:
+                    err = objective.error(w, data.x_test, data.y_test)
+                    with TraceAnnotation(wall.SYNC_HISTORY):
+                        hist["test_error"].append(float(err))
+                else:
+                    hist["test_error"].append(float("nan"))
 
     tel.trace.end(run_span,
                   clock.time if clock is not None else float(cfg.iters))

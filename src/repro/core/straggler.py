@@ -22,6 +22,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
+
+from repro.obs import wall
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,13 +99,18 @@ def speculative_time(times: jax.Array, key: jax.Array,
     from repro.runtime import policies as rt_policies   # lazy: imports us
     import numpy as np
     n = times.shape[0]
-    ctx = rt_policies.PhaseContext(
-        watch_fraction=watch_fraction,
-        sample_relaunch=lambda: np.asarray(
-            model.sample_times(key, n, work_per_worker, flops_per_worker),
-            dtype=np.float64))
-    out = rt_policies.get_policy("speculative")(
-        np.asarray(times, dtype=np.float64), ctx)
+
+    def sample_relaunch():
+        relaunch = model.sample_times(key, n, work_per_worker,
+                                      flops_per_worker)
+        with TraceAnnotation(wall.SYNC_STRAGGLER):
+            return np.asarray(relaunch, dtype=np.float64)
+
+    ctx = rt_policies.PhaseContext(watch_fraction=watch_fraction,
+                                   sample_relaunch=sample_relaunch)
+    with TraceAnnotation(wall.SYNC_STRAGGLER):
+        times = np.asarray(times, dtype=np.float64)
+    out = rt_policies.get_policy("speculative")(times, ctx)
     return jnp.asarray(out.elapsed)
 
 
@@ -160,7 +168,8 @@ class SimClock:
     def charge(self, elapsed: float, phase_name=None) -> None:
         """Directly add externally-computed phase time (e.g. the coded
         master's wait-until-decodable simulation)."""
-        self.engine.charge(elapsed, phase_name=phase_name)
+        with TraceAnnotation(wall.FLEET):
+            self.engine.charge(elapsed, phase_name=phase_name)
 
     def phase(self, key: jax.Array, num_workers: int, *,
               work_per_worker: float = 1.0,
@@ -180,12 +189,14 @@ class SimClock:
         bills it at its own Lambda size; ``working_set_gb`` declares the
         true per-worker working set (the fault plane's OOM threshold);
         ``phase_name``/``phase_deps`` label the phase's telemetry span —
-        see ``FleetEngine.run_phase``."""
-        elapsed, mask = self.engine.run_phase(
-            key, num_workers, work_per_worker=work_per_worker,
-            flops_per_worker=flops_per_worker, policy=policy, k=k,
-            comm_units=comm_units, decodable=decodable,
-            not_before=not_before, memory_gb=memory_gb,
-            working_set_gb=working_set_gb,
-            phase_name=phase_name, phase_deps=phase_deps)
-        return elapsed, jnp.asarray(mask)
+        see ``FleetEngine.run_phase``.  The call runs inside the profiler
+        span ``osn.fleet``."""
+        with TraceAnnotation(wall.FLEET):
+            elapsed, mask = self.engine.run_phase(
+                key, num_workers, work_per_worker=work_per_worker,
+                flops_per_worker=flops_per_worker, policy=policy, k=k,
+                comm_units=comm_units, decodable=decodable,
+                not_before=not_before, memory_gb=memory_gb,
+                working_set_gb=working_set_gb,
+                phase_name=phase_name, phase_deps=phase_deps)
+            return elapsed, jnp.asarray(mask)

@@ -7,53 +7,20 @@ kernel body with jax ops and checks results, not speed.  The mode follows
 for a described chip from a CPU process must pass ``interpret=False``
 itself (see ``tests/test_tpu_compile.py``).
 
-Profiling hooks: ``set_profiler(metrics_registry)`` attaches an
-``obs.MetricsRegistry`` to every entry point below — each call is then
-timed wall-clock (``kernel.<op>.us`` histogram + ``kernel.<op>.calls``
-counter, with ``block_until_ready`` so async dispatch does not hide the
-work).  This is the MEASURED per-backend latency table the ROADMAP's
-kernel auto-routing item consumes, replacing assumptions with data.  The
-default (no profiler) is a single ``is None`` check per call — numerics
-are never touched either way.
+Kernel time is read from the device trace: the Hessian's kernels run
+under the device scopes that ``repro.obs.wall`` names.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import coded_matvec as _cmv
 from repro.kernels import count_sketch as _cs
 from repro.kernels import oversketch_matmul as _og
 from repro.kernels import sketch_gram as _sg
 from repro.kernels import srht as _srht
-
-_PROFILER = None    # obs.MetricsRegistry while attached, else None
-
-
-def set_profiler(metrics) -> None:
-    """Attach (or with None detach) a metrics registry to all kernel entry
-    points; see the module docstring."""
-    global _PROFILER
-    _PROFILER = metrics
-
-
-def get_profiler():
-    return _PROFILER
-
-
-def _timed(op: str, fn, *args, **kwargs):
-    if _PROFILER is None:
-        return fn(*args, **kwargs)
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    out = jax.block_until_ready(out)
-    _PROFILER.histogram(f"kernel.{op}.us").observe(
-        (time.perf_counter() - t0) * 1e6)
-    _PROFILER.counter(f"kernel.{op}.calls").inc()
-    return out
 
 
 def _interpret(explicit: Optional[bool]) -> bool:
@@ -66,16 +33,15 @@ def count_sketch_apply(h: jax.Array, sigma: jax.Array, a: jax.Array,
                        block_size: int,
                        interpret: Optional[bool] = None) -> jax.Array:
     """S^T A for all K sketch blocks: (K,n),(K,n),(n,d) -> (K,b,d)."""
-    return _timed("count_sketch_apply", _cs.count_sketch_apply,
-                  h, sigma, a, block_size,
-                  interpret=_interpret(interpret))
+    return _cs.count_sketch_apply(h, sigma, a, block_size,
+                                  interpret=_interpret(interpret))
 
 
 def oversketch_gram(a_tilde: jax.Array, survivors: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Masked Gram (K,b,d),(K,) -> (d,d), rescaled by survivor count."""
-    return _timed("oversketch_gram", _og.oversketch_gram,
-                  a_tilde, survivors, interpret=_interpret(interpret))
+    return _og.oversketch_gram(a_tilde, survivors,
+                               interpret=_interpret(interpret))
 
 
 def sketch_gram_count(h: jax.Array, sigma: jax.Array, a: jax.Array,
@@ -87,10 +53,9 @@ def sketch_gram_count(h: jax.Array, sigma: jax.Array, a: jax.Array,
     never hits HBM (streaming apply + in-register masked Gram).  The
     output is d-tiled past the VMEM budget (``d_tile`` defaults to
     ``pick_d_tile``; see ``fused_path`` for which grid a shape gets)."""
-    return _timed("sketch_gram_count", _sg.sketch_gram_count,
-                  h, sigma, a, block_size, survivors,
-                  tile_n=tile_n, d_tile=d_tile,
-                  interpret=_interpret(interpret))
+    return _sg.sketch_gram_count(h, sigma, a, block_size, survivors,
+                                 tile_n=tile_n, d_tile=d_tile,
+                                 interpret=_interpret(interpret))
 
 
 def sketch_gram_sjlt(h: jax.Array, sigma: jax.Array, a: jax.Array,
@@ -100,10 +65,9 @@ def sketch_gram_sjlt(h: jax.Array, sigma: jax.Array, a: jax.Array,
                      d_tile: Optional[int] = None) -> jax.Array:
     """Fused SJLT Gram (K,s,n),(K,s,n),(n,d),(K,) -> (d,d); the s signed
     one-hot layers are summed into the encode matrix in VMEM."""
-    return _timed("sketch_gram_sjlt", _sg.sketch_gram_sjlt,
-                  h, sigma, a, block_size, survivors,
-                  tile_n=tile_n, d_tile=d_tile,
-                  interpret=_interpret(interpret))
+    return _sg.sketch_gram_sjlt(h, sigma, a, block_size, survivors,
+                                tile_n=tile_n, d_tile=d_tile,
+                                interpret=_interpret(interpret))
 
 
 def sketch_gram_srht(rows: jax.Array, sigma: jax.Array, a: jax.Array,
@@ -113,10 +77,9 @@ def sketch_gram_srht(rows: jax.Array, sigma: jax.Array, a: jax.Array,
                      d_tile: Optional[int] = None) -> jax.Array:
     """Fused SRHT Gram (K,b),(K,n),(n,d),(K,) -> (d,d); the Hadamard mix
     rows are regenerated block-locally so the mixed panel never exists."""
-    return _timed("sketch_gram_srht", _sg.sketch_gram_srht,
-                  rows, sigma, a, survivors,
-                  tile_n=tile_n, d_tile=d_tile,
-                  interpret=_interpret(interpret))
+    return _sg.sketch_gram_srht(rows, sigma, a, survivors,
+                                tile_n=tile_n, d_tile=d_tile,
+                                interpret=_interpret(interpret))
 
 
 # Grid-choice helpers, re-exported for benchmarks and tests: which fused
@@ -129,18 +92,17 @@ pick_d_tile = _sg.pick_d_tile
 def fwht(x: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     """Orthonormal Walsh-Hadamard transform along axis 1 of (K, n, d).
     Dispatches monolithic-panel vs two-pass tiled on the VMEM budget."""
-    return _timed("fwht", _srht.fwht, x, interpret=_interpret(interpret))
+    return _srht.fwht(x, interpret=_interpret(interpret))
 
 
 def fwht_two_pass(x: jax.Array,
                   interpret: Optional[bool] = None) -> jax.Array:
     """Force the two-pass tiled FWHT (local + across Kronecker passes)."""
-    return _timed("fwht_two_pass", _srht.fwht_two_pass, x,
-                  interpret=_interpret(interpret))
+    return _srht.fwht_two_pass(x, interpret=_interpret(interpret))
 
 
 def coded_block_matvec(enc: jax.Array, x: jax.Array, erased: jax.Array,
                        interpret: Optional[bool] = None) -> jax.Array:
     """Masked coded block products (W,b,s),(s,),(W,) -> (W,b)."""
-    return _timed("coded_block_matvec", _cmv.coded_block_matvec,
-                  enc, x, erased, interpret=_interpret(interpret))
+    return _cmv.coded_block_matvec(enc, x, erased,
+                                   interpret=_interpret(interpret))

@@ -15,10 +15,9 @@ one history and can be queried back out.  Two record kinds:
     the metrics snapshot, per-phase time/dollar aggregates, per-iteration
     critical-path stats, straggler completion-tail quantiles
     (p50/p95/p99, exact — the registry keeps full samples), survivor
-    counts per sketch round, health-monitor alerts, and the kernel
-    wall-clock profiler's measured per-path timings.  The last two tables
-    are exactly what the ROADMAP's kernel auto-router and analytic launch
-    planner need: measured path timings and PAST iterations' survivor
+    counts per sketch round, health-monitor alerts, and the fused-Gram
+    paths taken (``kernel.path.*``).  The survivor table is what the
+    ROADMAP's analytic launch planner needs: PAST iterations' survivor
     statistics.
   - ``kind: "bench"`` (``bench_record``) — built from a ``BENCH_*.json``
     payload (rows + meta); legacy payloads without ``git_sha`` /
@@ -136,12 +135,6 @@ def run_record(name: str, telemetry, *, backend: str = "unknown",
         if qw is not None and qw.count:
             rec["fleet_jobs"]["queue_wait"] = _tail_quantiles(qw)
 
-    # Measured kernel wall-clock per path/op (ops.set_profiler hook) —
-    # the table a data-driven fused_path() router reads.
-    kernel_us = {n: h.summary() for n, h in sorted(reg.histograms.items())
-                 if n.startswith("kernel.") and n.endswith(".us")}
-    if kernel_us:
-        rec["kernel_us"] = kernel_us
     kernel_paths = {n: c.value for n, c in sorted(reg.counters.items())
                     if n.startswith("kernel.path.")}
     if kernel_paths:

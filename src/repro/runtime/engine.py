@@ -47,8 +47,10 @@ from typing import Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import obs
+from repro.obs import wall
 from repro.runtime import policies as _policies
 from repro.runtime import trace as _trace_mod
 from repro.runtime.cost import CostLedger, CostModel, bill_phase
@@ -87,8 +89,9 @@ def _np_rng(key: jax.Array) -> np.random.Generator:
         data = jax.random.key_data(key)
     except (AttributeError, TypeError):
         data = key
-    return np.random.default_rng(
-        np.asarray(data, dtype=np.uint32).ravel().tolist())
+    with TraceAnnotation(wall.SYNC_STRAGGLER):
+        seed = np.asarray(data, dtype=np.uint32).ravel().tolist()
+    return np.random.default_rng(seed)
 
 
 class FleetEngine:
@@ -196,10 +199,12 @@ class FleetEngine:
             # failure-free case costs exactly one sample_times call.
             if attempt not in round_times:
                 k = jax.random.fold_in(key, attempt)
-                round_times[attempt] = np.asarray(
-                    self.model.sample_times(k, num_workers, work_per_worker,
-                                            flops_per_worker),
-                    dtype=np.float64)
+                times = self.model.sample_times(k, num_workers,
+                                                work_per_worker,
+                                                flops_per_worker)
+                with TraceAnnotation(wall.SYNC_STRAGGLER):
+                    round_times[attempt] = np.asarray(times,
+                                                      dtype=np.float64)
             return float(round_times[attempt][worker])
 
         done = np.full(num_workers, np.inf)
@@ -569,10 +574,11 @@ class FleetEngine:
             if "r" not in relaunch_cache:
                 fl = self.fleet
                 kr = jax.random.fold_in(key, 7777)
-                run = np.asarray(
-                    self.model.sample_times(kr, num_workers, work_per_worker,
-                                            flops_per_worker),
-                    dtype=np.float64)
+                run = self.model.sample_times(kr, num_workers,
+                                              work_per_worker,
+                                              flops_per_worker)
+                with TraceAnnotation(wall.SYNC_STRAGGLER):
+                    run = np.asarray(run, dtype=np.float64)
                 if fl.cold_start_prob > 0.0:
                     cold = rng.random(num_workers) < fl.cold_start_prob
                     run = run + cold * rng.uniform(

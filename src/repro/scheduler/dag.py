@@ -41,7 +41,9 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence
 
 import jax
+from jax.profiler import TraceAnnotation
 
+from repro.obs import wall
 from repro.scheduler.spec import PhaseSpec, canonical_order
 
 
@@ -110,35 +112,37 @@ class DagRun:
         the direct clock outside the DAG (e.g. the coded matvec's
         one-time encode phases).  Phases launching exactly at the current
         clock take the engine's ``not_before=None`` path either way,
-        keeping serialized DAGs bit-identical to sequential runs.
+        keeping serialized DAGs bit-identical to sequential runs.  The
+        call runs inside the profiler span ``osn.fleet``.
         """
-        if spec.name in self.results:
-            raise ValueError(f"phase {spec.name!r} already dispatched")
-        if key is None:
-            if self.key is None:
-                raise ValueError(
-                    f"phase {spec.name!r}: DagRun has no base key; pass one "
-                    "to DagRun(...) or dispatch(..., key=...)")
-            key = jax.random.fold_in(self.key, spec.key_fold)
-        now = float(self.clock.time)
-        nb = now if sequential else self.launch_time(spec)
-        if min_start is not None:
-            nb = max(nb, float(min_start))
-        elapsed, mask = self.clock.phase(
-            key, spec.workers, policy=spec.policy, k=spec.k,
-            work_per_worker=spec.work_per_worker,
-            flops_per_worker=spec.flops_per_worker,
-            comm_units=spec.comm_units, decodable=spec.decodable,
-            not_before=None if nb == now else nb,
-            memory_gb=spec.memory_gb,
-            working_set_gb=spec.working_set_gb,
-            phase_name=spec.name, phase_deps=spec.deps)
-        finish = float(self.clock.time) if nb == now else nb + elapsed
-        res = PhaseResult(spec=spec, start=nb, elapsed=float(elapsed),
-                          finish=finish, mask=mask)
-        self.results[spec.name] = res
-        self.last = spec.name
-        return res
+        with TraceAnnotation(wall.FLEET):
+            if spec.name in self.results:
+                raise ValueError(f"phase {spec.name!r} already dispatched")
+            if key is None:
+                if self.key is None:
+                    raise ValueError(
+                        f"phase {spec.name!r}: DagRun has no base key; pass "
+                        "one to DagRun(...) or dispatch(..., key=...)")
+                key = jax.random.fold_in(self.key, spec.key_fold)
+            now = float(self.clock.time)
+            nb = now if sequential else self.launch_time(spec)
+            if min_start is not None:
+                nb = max(nb, float(min_start))
+            elapsed, mask = self.clock.phase(
+                key, spec.workers, policy=spec.policy, k=spec.k,
+                work_per_worker=spec.work_per_worker,
+                flops_per_worker=spec.flops_per_worker,
+                comm_units=spec.comm_units, decodable=spec.decodable,
+                not_before=None if nb == now else nb,
+                memory_gb=spec.memory_gb,
+                working_set_gb=spec.working_set_gb,
+                phase_name=spec.name, phase_deps=spec.deps)
+            finish = float(self.clock.time) if nb == now else nb + elapsed
+            res = PhaseResult(spec=spec, start=nb, elapsed=float(elapsed),
+                              finish=finish, mask=mask)
+            self.results[spec.name] = res
+            self.last = spec.name
+            return res
 
     @property
     def makespan(self) -> float:

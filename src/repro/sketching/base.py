@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 import repro.core.sketch as core_sketch
 from repro.core.sketch import OverSketchConfig
+from repro.obs import wall
 
 SketchState = Any  # pytree of arrays; structure is family-specific
 
@@ -108,18 +109,23 @@ class SketchFamily(abc.ABC):
         makes dropping blocks + rescaling exact for every family.  On the
         kernel path the fused single-pass pipeline is preferred whenever
         the family provides one.
+
+        The sketch runs under the device scope ``osn_sketch`` and the Gram
+        under ``osn_gram`` (``repro.obs.wall``); the fused kernel does both
+        in one pass, so all of it falls under ``osn_sketch``.
         """
         if use_kernels:
             if survivors is None:
                 survivors = jnp.ones((self.cfg.total_blocks,), bool)
-            fused = self.gram_fused(state, a, survivors)
+            with jax.named_scope(wall.SKETCH):
+                fused = self.gram_fused(state, a, survivors)
             if fused is not None:
                 return fused
-            a_t = self.apply(state, a, use_kernels=True)
+        with jax.named_scope(wall.SKETCH):
+            a_t = self.apply(state, a, use_kernels=use_kernels)
+        with jax.named_scope(wall.GRAM):
             return core_sketch.sketched_gram(a_t, survivors,
-                                             use_kernels=True)
-        a_t = self.apply(state, a)
-        return core_sketch.sketched_gram(a_t, survivors)
+                                             use_kernels=use_kernels)
 
     # ------------------------------------------------------------------ cost
     # Hooks for the straggler SimClock: per-worker flops and master-I/O for

@@ -418,24 +418,6 @@ def test_giant_telemetry_is_observation_only():
     assert {"grad", "local-newton"} <= names
 
 
-# ------------------------------------------------------- kernel profiling
-def test_ops_profiler_hook():
-    from repro.kernels import ops
-    x = jnp.ones((1, 8, 4), jnp.float32)
-    assert ops.get_profiler() is None
-    baseline = ops.fwht(x)                      # unprofiled path
-    reg = obs.MetricsRegistry()
-    ops.set_profiler(reg)
-    try:
-        profiled = ops.fwht(x)
-        snap = reg.snapshot()
-        assert snap["counters"]["kernel.fwht.calls"] == 1.0
-        assert snap["histograms"]["kernel.fwht.us"]["count"] == 1
-        assert snap["histograms"]["kernel.fwht.us"]["max"] > 0
-    finally:
-        ops.set_profiler(None)
-    assert ops.get_profiler() is None
-    assert jnp.array_equal(baseline, profiled)
 
 
 def _regen():
