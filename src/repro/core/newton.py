@@ -439,10 +439,12 @@ def _jitted_sketched_hessian(objective, family: "sketching.SketchFamily",
     the two-kernel apply+gram chain ("unfused").
 
     The path actually taken is logged as a telemetry metric
-    (``kernel.path.<fused|fused_tiled|unfused>``) at this function's call
-    site in ``_hessian_phase`` — inside the jitted closure there is no
-    Python left to log from — so production path selection is auditable
-    against the ``BENCH_kernels.json`` per-row ``path`` field."""
+    (``kernel.path.<path>``: ``fused``/``fused_tiled`` with
+    ``use_kernels``, else ``SketchFamily.apply_path`` of the data's
+    platform: ``mxu_count_sketch`` or ``segment_sum`` for the OverSketch
+    family, ``unfused`` for the others) at this function's call site in
+    ``_hessian_phase`` — inside the jitted closure there is no Python
+    left to log from."""
     def fn(w, data, state, survivors):
         with jax.named_scope(wall.HESS_SQRT):
             a = objective.hess_sqrt(w, data)
@@ -502,6 +504,14 @@ def _jitted_exact_hessian(objective):
         d = a.shape[1]
         return a.T @ a + objective.hess_reg * jnp.eye(d, dtype=a.dtype)
     return jax.jit(fn)
+
+
+def _platform(x) -> str:
+    """The platform a program on ``x`` runs on: its device's, or the
+    default backend's for a host array."""
+    if isinstance(x, jax.Array):
+        return next(iter(x.devices())).platform
+    return jax.default_backend()
 
 
 def _hess_rows(objective, data: Dataset, w: jax.Array) -> Tuple[int, int]:
@@ -605,11 +615,12 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
         state = fam.sample(jax.random.fold_in(key, 7), n_rows)
         tel = _telemetry(clock)
         if tel.enabled:
-            # Audit trail for kernel auto-routing: the path the fused
-            # sketch->Gram dispatch ACTUALLY takes for this (family, d),
-            # comparable against BENCH_kernels.json rows instead of
-            # assumed from the config.
-            path = fam.fused_path(d) if cfg.use_kernels else "unfused"
+            # Audit trail for kernel auto-routing: the path the sketch
+            # ACTUALLY takes for this (family, d) — the fused sketch->Gram
+            # grid, or the apply that the platform of the data selects —
+            # instead of assumed from the config.
+            path = (fam.fused_path(d) if cfg.use_kernels
+                    else fam.apply_path(_platform(data.x)))
             tel.metrics.counter(f"kernel.path.{path}").inc()
         fn = _jitted_sketched_hessian(objective, fam, cfg.use_kernels)
         h_hat = fn(w, data, state, survivors)

@@ -7,10 +7,11 @@ The paper's Eq. (4) sketch is ``S = (1/sqrt(N)) [S_1, ..., S_{N+e}]`` where each
 estimate after rescaling by the survivor count (``E[S_i S_i^T] = I``).
 
 We never materialize S.  A Count-Sketch block is two integer/sign vectors
-``(h, sigma)``; ``S_i^T A`` is a signed segment-sum of A's rows into b buckets.
-The TPU-native formulation (one-hot MXU matmul) lives in ``repro.kernels``;
-this module is the distribution-agnostic reference path used by the optimizer
-and the kernels' oracle.
+``(h, sigma)``; ``S_i^T A`` is a signed segment-sum of A's rows into b buckets
+(``apply_block``).  ``apply_sketch``, the optimizer's path, lowers to the
+segment sums, or on a TPU at b <= ``MXU_MAX_BLOCK_SIZE`` to the one-hot MXU
+matmul of ``repro.kernels.count_sketch``; the kernels' oracle is
+``repro.kernels.ref``.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import count_sketch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,18 +113,50 @@ def apply_block(h: jax.Array, sigma: jax.Array, block_size: int,
     return jax.ops.segment_sum(signed, h, num_segments=block_size)
 
 
+# The widest sketch block ``apply_sketch`` gives the MXU kernel on a TPU.
+# The kernel's work per block grows with b (6 b n d flop for its three
+# bfloat16 passes) and the segment sums' barely does: on a v5e the two
+# cross between b = 1024 and 2048 (PERF.md section 6 has the readings).
+MXU_MAX_BLOCK_SIZE = 1024
+
+
+def sketch_impl(platform: str, block_size: int) -> str:
+    """Which implementation ``apply_sketch`` lowers to on ``platform`` (a
+    JAX platform name such as "tpu" or "cpu") for blocks of ``block_size``:
+    "mxu_count_sketch" (the Pallas MXU kernel, ``kernels/count_sketch.py``)
+    or "segment_sum"."""
+    if platform == "tpu" and block_size <= MXU_MAX_BLOCK_SIZE:
+        return "mxu_count_sketch"
+    return "segment_sum"
+
+
+def _apply_segment_sum(h: jax.Array, sigma: jax.Array, a: jax.Array,
+                       block_size: int) -> jax.Array:
+    """``lax.map`` streams the blocks, so peak memory is one signed (n, d)
+    panel plus the (K, b, d) output — never the (K, n, d) tensor a vmap
+    over blocks would build (242 GB at epsilon's n = 200k, d = 2000)."""
+    return jax.lax.map(
+        lambda hs: apply_block(hs[0], hs[1], block_size, a), (h, sigma))
+
+
 def apply_sketch(cs: CountSketch, a: jax.Array) -> jax.Array:
     """All blocks: A (n, d) -> A_tilde (total_blocks, b, d).  Unscaled.
 
     The 1/sqrt(N) scale of Eq. (4) is folded into the Gram rescale (we divide
     by the survivor count there), which is what makes dropping blocks exact.
-    ``lax.map`` streams the blocks, so peak memory is one signed (n, d)
-    panel plus the (K, b, d) output — never the (K, n, d) tensor a vmap
-    over blocks would build (242 GB at epsilon's n = 200k, d = 2000).
+    The implementation follows the platform the program is lowered for
+    and the block size (``sketch_impl``): the MXU kernel on a TPU, which
+    has no fast scatter, and the segment sums elsewhere or for wide blocks.
+    Both give the f32 segment sum up to the order of its additions.
     """
-    return jax.lax.map(
-        lambda hs: apply_block(hs[0], hs[1], cs.block_size, a),
-        (cs.h, cs.sigma))
+    segment_sums = partial(_apply_segment_sum, block_size=cs.block_size)
+    if sketch_impl("tpu", cs.block_size) == "segment_sum":
+        return segment_sums(cs.h, cs.sigma, a)
+    return jax.lax.platform_dependent(
+        cs.h, cs.sigma, a,
+        tpu=partial(count_sketch.count_sketch_apply,
+                    block_size=cs.block_size, interpret=False),
+        default=segment_sums)
 
 
 def apply_sketch_chunked(cs: CountSketch, a_fn: Callable[[int], jax.Array],

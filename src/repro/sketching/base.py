@@ -20,6 +20,9 @@ This module defines the protocol every family implements:
       falls back to apply+gram
   fused_path(d)         -> str       which gram path use_kernels takes:
       "fused" | "fused_tiled" | "unfused" (benchmark/bookkeeping hook)
+  apply_path(platform)  -> str       which apply use_kernels=False lowers
+      to on a platform (the OverSketch family's selects by platform and
+      block size)
   block_flops(num_rows, d) -> float  per-worker cost for the straggler clock
   comm_units(d)         -> float     per-worker master-I/O units
 
@@ -99,6 +102,12 @@ class SketchFamily(abc.ABC):
             return "unfused"
         from repro.kernels.sketch_gram import fused_path as _fused_path
         return _fused_path(self.cfg.block_size, d)
+
+    def apply_path(self, platform: str) -> str:
+        """Which implementation ``apply(use_kernels=False)`` takes when
+        lowered for ``platform``: ``"unfused"`` (the family's jnp form)
+        unless the family selects by platform and block size."""
+        return "unfused"
 
     def gram(self, state: SketchState, a: jax.Array,
              survivors: Optional[jax.Array] = None,

@@ -1,8 +1,9 @@
 """OverSketch family: the paper's stacked Count-Sketch blocks (Eq. 4).
 
 This is the seed implementation from ``repro.core.sketch`` migrated behind
-the ``SketchFamily`` protocol; ``repro.core`` re-exports are untouched and
-the reference functions there remain the kernels' oracle.  Per-block
+the ``SketchFamily`` protocol; ``repro.core`` re-exports are untouched, and
+``apply`` without kernels is ``core.sketch.apply_sketch``, which picks its
+implementation by platform and block size (``apply_path``).  Per-block
 unbiasedness E[S_i S_i^T] = I is the Count-Sketch property the paper's
 Lemma 6.1 builds on.
 
@@ -37,6 +38,9 @@ class OverSketchFamily(SketchFamily):
             return kops.count_sketch_apply(state.h, state.sigma, a,
                                            self.cfg.block_size)
         return core_sketch.apply_sketch(state, a)
+
+    def apply_path(self, platform: str) -> str:
+        return core_sketch.sketch_impl(platform, self.cfg.block_size)
 
     def gram_fused(self, state: core_sketch.CountSketch, a: jax.Array,
                    survivors: jax.Array):

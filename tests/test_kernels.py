@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels import ops, ref
+from repro.kernels import count_sketch, ops, ref
 
 
 # ---------------------------------------------------------------- count sketch
@@ -59,6 +59,92 @@ def test_count_sketch_property(seed, n, d):
     expect = ref.count_sketch_apply(h, sigma, a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-4, atol=1e-4)
+
+
+# The MXU kernel's grid at the shapes it has to handle: K not a multiple of
+# the block group (13, 148), n not a multiple of the row panel, ragged d,
+# and each block size the configurations use.
+@pytest.mark.parametrize("k,n,d,b", [
+    (13, 1100, 123, 128),
+    (13, 300, 130, 256),
+    (148, 1100, 130, 64),
+    (148, 2500, 123, 256),
+    (148, 1100, 123, 128),
+    (13, 2500, 130, 64),
+])
+def test_count_sketch_grid_matches_segment_sum(k, n, d, b):
+    kh, ks, ka = jax.random.split(jax.random.PRNGKey(k + n + d + b), 3)
+    h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
+    sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
+    a = jax.random.normal(ka, (n, d))
+    out = ops.count_sketch_apply(h, sigma, a, b, interpret=True)
+    expect = ref.count_sketch_apply(h, sigma, a, b)
+    assert out.shape == (k, b, d) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_count_sketch_keeps_all_24_bits():
+    """Every entry needs the whole f32 significand.  One bfloat16 pass
+    drops its last 2^-12 (2.4e-4 relative); the three-part split keeps
+    it, so the three passes agree with the segment sum to 1e-6."""
+    k, n, d, b = 13, 1100, 130, 128
+    kh, ks = jax.random.split(jax.random.PRNGKey(24))
+    h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
+    sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
+    a = jnp.full((n, d), 1 + 2 ** -12 + 2 ** -20, jnp.float32)
+    expect = np.asarray(ref.count_sketch_apply(h, sigma, a, b))
+
+    def rel(x):
+        return np.abs(np.asarray(x) - expect).max() / np.abs(expect).max()
+
+    assert rel(ops.count_sketch_apply(h, sigma, a, b, interpret=True)) <= 1e-6
+    one_pass = ops.count_sketch_apply(h, sigma, a.astype(jnp.bfloat16), b,
+                                      interpret=True)
+    assert rel(one_pass) > 1e-4
+
+
+# The tiles at epsilon's and a9a's widths, at the distributed-average mode's
+# b = 2048 > d, and at the widest block that fits: always within the VMEM
+# budget, aligned to the (8, 128) rule.
+@pytest.mark.parametrize("k,b,n,d,tiles", [
+    (148, 256, 200_000, 2000, (16, 512, 512)),
+    (13, 128, 32_000, 123, (16, 512, 128)),
+    (19, 2048, 200_000, 2000, (8, 512, 128)),
+    (8, 4096, 200_000, 2000, (8, 256, 128)),
+    (2, 64, 300, 13, (8, 384, 128)),
+])
+def test_count_sketch_tiles_fit_the_vmem_budget(k, b, n, d, tiles):
+    group, tn, td = count_sketch.pick_tiles(k, b, n, d)
+    assert (group, tn, td) == tiles
+    assert count_sketch.vmem_bytes(group, b, tn, td) \
+        <= count_sketch.VMEM_BUDGET_BYTES
+    assert group % 8 == 0 and tn % 128 == 0 and td % 128 == 0
+
+
+def test_count_sketch_refuses_a_block_too_wide_for_vmem():
+    with pytest.raises(ValueError, match="does not fit"):
+        count_sketch.pick_tiles(8, 8192, 200_000, 2000)
+
+
+def test_count_sketch_chip_script_checks_the_kernel():
+    """benchmarks/count_sketch_chip.py at a small size on the CPU: the
+    segment sums, the kernel at the picked tiles and at a given setting,
+    both agreeing with the sums, and a setting over the budget skipped."""
+    from benchmarks import count_sketch_chip
+    rows = count_sketch_chip.measure(
+        300, 130, 13, 64, count_sketch_chip.parse_tiles(
+            "8/128/128,16/4096/4096"), 1, seed=2 ** 31 + 7)
+    assert [r["variant"] for r in rows] == [
+        "segment_sum", "mxu 16/384/256", "mxu 8/128/128",
+        "mxu 16/4096/4096"]
+    assert [r.get("picked") for r in rows[1:3]] == [True, False]
+    for r in rows[1:3]:
+        assert r["rel_max"] <= 1e-5 and r["rel_fro"] <= 1e-5
+        assert len(r["s"]) == 1 and r["ms_per_block"] > 0
+    assert "over the budget" in rows[3]["skipped"]
+    assert count_sketch_chip.parse_cases("148x256,32x1024") == [
+        (148, 256), (32, 1024)]
 
 
 # ------------------------------------------------------------ oversketch gram

@@ -118,6 +118,47 @@ def test_streamed_apply_matches_vmap_and_holds_no_full_tensor():
     assert full not in streamed     # ...and the streamed path has none
 
 
+def test_apply_sketch_takes_segment_sums_off_tpu():
+    """Lowered for the CPU, apply_sketch is the lax.map of segment sums,
+    bit for bit, jitted or not; the path helper names it so, and names
+    the MXU kernel for a TPU."""
+    key = jax.random.PRNGKey(11)
+    n, d = 300, 13
+    a = jax.random.normal(key, (n, d))
+    cfg = sk.OverSketchConfig(256, 64, 0.25)
+    cs = sk.sample_countsketch(jax.random.fold_in(key, 1), n, cfg)
+    segment_sums = jax.jit(lambda h, s, x: jax.lax.map(
+        lambda hs: sk.apply_block(hs[0], hs[1], cs.block_size, x), (h, s)))
+    expect = np.asarray(segment_sums(cs.h, cs.sigma, a))
+    np.testing.assert_array_equal(np.asarray(jax.jit(sk.apply_sketch)(cs, a)),
+                                  expect)
+    np.testing.assert_array_equal(np.asarray(sk.apply_sketch(cs, a)), expect)
+    assert jax.default_backend() == "cpu"
+    assert sk.sketch_impl("cpu", cs.block_size) == "segment_sum"
+    assert sk.sketch_impl("tpu", cs.block_size) == "mxu_count_sketch"
+
+
+@pytest.mark.parametrize("block_size", [2048, 4096])
+def test_apply_sketch_keeps_segment_sums_for_wide_blocks(block_size):
+    """Past MXU_MAX_BLOCK_SIZE the kernel's work per block (which grows
+    with b) outruns the segment sums' on a TPU too: apply_sketch is then
+    the segment sums on every platform, with no platform switch."""
+    assert block_size > sk.MXU_MAX_BLOCK_SIZE
+    assert sk.sketch_impl("tpu", block_size) == "segment_sum"
+    assert sk.sketch_impl("tpu", sk.MXU_MAX_BLOCK_SIZE) == "mxu_count_sketch"
+    key = jax.random.PRNGKey(12)
+    n, d = 300, 13
+    a = jax.random.normal(key, (n, d))
+    cs = sk.sample_countsketch(jax.random.fold_in(key, 1), n,
+                               sk.OverSketchConfig(2 * block_size,
+                                                   block_size, 0.25))
+    text = jax.jit(sk.apply_sketch).lower(cs, a).as_text()
+    assert "stablehlo.case" not in text
+    np.testing.assert_array_equal(
+        np.asarray(sk.apply_sketch(cs, a)),
+        np.asarray(sk._apply_segment_sum(cs.h, cs.sigma, a, block_size)))
+
+
 def test_distributed_gram_matches_local():
     """shard_map masked-psum path == single-device masked gram."""
     mesh = jax.make_mesh((1,), ("model",),

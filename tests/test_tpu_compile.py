@@ -12,21 +12,29 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker that runs this file
 keeps it until it exits.  Keep every such compile in this one file.
 """
+import functools
 import os
 import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)            # chip_smoke.py lives at the repo root
 
 from chip_smoke import PHASES  # noqa: E402
+from repro import sketching  # noqa: E402
 from repro.core.coded import make_code  # noqa: E402
-from repro.core.sketch import CountSketch, apply_sketch  # noqa: E402
+from repro.core.newton import _jitted_distavg_direction  # noqa: E402
+from repro.core.objectives import Dataset, LogisticRegression  # noqa: E402
+from repro.core.sketch import (MXU_MAX_BLOCK_SIZE, CountSketch,  # noqa: E402
+                               OverSketchConfig, apply_sketch)
 from repro.kernels import ops  # noqa: E402
+from repro.kernels.count_sketch import (VMEM_BUDGET_BYTES,  # noqa: E402
+                                        pick_tiles, vmem_bytes)
 from repro.sketching.base import next_pow2  # noqa: E402
 
 HBM_BYTES = 16 * 2 ** 30
@@ -130,10 +138,11 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, width, precision):
     assert _hbm_bytes(compiled) <= HBM_BYTES
 
 
-@pytest.mark.parametrize("width", list(WIDTHS))
-def test_streamed_apply_sketch_fits_one_chip(one_chip, width):
-    """The default (use_kernels=False) sketch path: blocks stream through
-    lax.map, so it holds one signed (n, d) panel, never (K, n, d)."""
+def _apply_sketch_compiled(sharding, width):
+    """apply_sketch compiled for the chip at a width, with A and the output
+    in the row-major layout the program hands them over in (left to
+    itself, the compiler may pick a transposed layout for a bare parameter
+    and add a copy of A to meet the kernel's)."""
     ph = WIDTHS[width]
     n, d = ph.n, ph.d
     k, b = ph.sketch.total_blocks, ph.sketch.block_size
@@ -141,8 +150,79 @@ def test_streamed_apply_sketch_fits_one_chip(one_chip, width):
     def fn(h, s, a):
         return apply_sketch(CountSketch(h=h, sigma=s, block_size=b), a)
 
-    compiled = _compile(fn, one_chip, ((k, n), jnp.int32),
-                        ((k, n), jnp.float32), ((n, d), jnp.float32))
+    rows = Format(Layout(major_to_minor=(0, 1)), sharding)
+    args = [jax.ShapeDtypeStruct((k, n), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=rows)]
+    out = Format(Layout(major_to_minor=(0, 1, 2)), sharding)
+    return jax.jit(fn, out_shardings=out).lower(*args).compile()
+
+
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_apply_sketch_lowers_to_the_mxu_kernel(one_chip, width):
+    """Lowered for a TPU, the platform branch of apply_sketch is the MXU
+    count-sketch kernel (a Mosaic custom call), not the segment sums."""
+    compiled = _apply_sketch_compiled(one_chip, width)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_streamed_apply_sketch_fits_one_chip(one_chip, width):
+    """The default (use_kernels=False) sketch path on the chip: the MXU
+    kernel streams panels of A through VMEM, so besides its (K, b, d)
+    output it holds no more than the (K, n) bucket and sign rows rounded
+    up to the kernel's tiles: never an (n, d) panel, never (K, n, d)."""
+    ph = WIDTHS[width]
+    n, d = ph.n, ph.d
+    k, b = ph.sketch.total_blocks, ph.sketch.block_size
+    compiled = _apply_sketch_compiled(one_chip, width)
     assert _hbm_bytes(compiled) <= HBM_BYTES
-    # Temporaries stay within a few (n, d) panels, not K of them.
-    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * n * d * 4
+    group, tn, td = pick_tiles(k, b, n, d)
+    k_pad, n_pad = k + (-k) % group, n + (-n) % tn
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 4 * 2 * k_pad * n_pad
+
+
+# sketch_mode="distributed-avg" needs b > d, so at epsilon's d = 2000 it
+# sketches with b = 2048: K = 19 blocks for 15 of them.
+DISTAVG_SKETCH = OverSketchConfig(15 * 2048, 2048, 0.25)
+
+
+def _arg(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_distavg_direction_compiles_at_epsilon_width(one_chip):
+    """Past MXU_MAX_BLOCK_SIZE apply_sketch keeps the segment sums on a
+    TPU: the distributed-average direction at b = 2048 holds no Mosaic
+    call, and fits the chip."""
+    ph, cfg = WIDTHS["epsilon"], DISTAVG_SKETCH
+    n, d, k, b = ph.n, ph.d, cfg.total_blocks, cfg.block_size
+    assert b > MXU_MAX_BLOCK_SIZE
+    fn = _jitted_distavg_direction(LogisticRegression(),
+                                   sketching.get("oversketch", cfg), True,
+                                   False, "chol", 64)
+    arg = functools.partial(_arg, one_chip)
+    compiled = fn.lower(
+        arg((d,)), Dataset(arg((n, d)), arg((n,))), arg((d,)),
+        CountSketch(h=arg((k, n), jnp.int32), sigma=arg((k, n)),
+                    block_size=b),
+        arg((k,), jnp.bool_)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert _hbm_bytes(compiled) <= HBM_BYTES
+
+
+def test_count_sketch_apply_compiles_at_distavg_width(one_chip):
+    """What use_kernels runs in that direction: the kernel at b = 2048,
+    whose tiles pick_tiles shrinks until its working set fits the VMEM
+    budget, compiles for the chip."""
+    ph, cfg = WIDTHS["epsilon"], DISTAVG_SKETCH
+    n, d, k, b = ph.n, ph.d, cfg.total_blocks, cfg.block_size
+    group, tn, td = pick_tiles(k, b, n, d)
+    assert vmem_bytes(group, b, tn, td) <= VMEM_BUDGET_BYTES
+    compiled = _compile(
+        lambda h, s, a: ops.count_sketch_apply(h, s, a, b, interpret=False),
+        one_chip, ((k, n), jnp.int32), ((k, n), jnp.float32),
+        ((n, d), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _hbm_bytes(compiled) <= HBM_BYTES

@@ -3,6 +3,7 @@
 from the ``.xplane.pb``, and the scopes in the lowered Hessian programs."""
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,8 +125,10 @@ def test_lowered_hessian_carries_the_three_device_scopes():
     text = _hessian_text(False)
     for scope in wall.SCOPES:
         assert f"jit(fn)/{scope}/" in text, scope
-    # The count sketch's streamed blocks run inside the sketch scope.
-    assert f"jit(fn)/{wall.SKETCH}/while/body/" in text
+    # The count sketch's streamed blocks run inside the sketch scope, in
+    # the branch apply_sketch's platform switch takes on the CPU.
+    assert re.search(
+        rf"jit\(fn\)/{wall.SKETCH}/cond/branch_\d+_fun/while/body/", text)
 
 
 def test_fused_kernel_falls_under_the_sketch_scope_alone():
