@@ -7,6 +7,13 @@ column of the extended grid satisfies a single-parity-check constraint, so a
 *peeling decoder* recovers any erasure pattern with at most one missing cell
 per row xor column per round (and most patterns with up to 2g+1 erasures).
 
+The systematic blocks are the data itself, zero-padded, so the code keeps
+only its 2g+1 parity blocks (``encode_2d``): the products of the systematic
+cells are read from A v, and those of the parity cells from the parity
+blocks.  The code of A^T is encoded from A's column blocks, with no
+transpose of A.  ``encode_full`` builds the whole grid, which the
+distributed path takes and the tests compare against.
+
 Encoding happens once (the paper amortizes it across iterations since the data
 matrix is fixed); decode is a cheap `lax.fori_loop` of vectorized peel rounds.
 """
@@ -47,15 +54,74 @@ def make_code(num_rows: int, block_rows: int) -> ProductCode:
     return ProductCode(num_blocks=t, block_rows=block_rows, grid=g)
 
 
-@functools.partial(jax.jit, static_argnames=("code",))
-def encode_2d(a: jax.Array, code: ProductCode) -> jax.Array:
-    """A (rows, s) -> encoded blocks ((g+1), (g+1), b, s).
+def _row_block_parity(a: jax.Array, code: ProductCode) -> jax.Array:
+    """The parity of the grid of A's row blocks, A zero-padded to g^2 b
+    rows: (2g+1, b, s).  The whole grid rows are read in place, one per
+    step of a scan; only the last, ragged one is padded, as a copy of
+    fewer than g b rows."""
+    g, b = code.grid, code.block_rows
+    rows, s = a.shape
+    q = rows // (g * b)                   # grid rows of whole blocks
 
-    Row padding with zeros up to g^2 * b rows; parities are sums of blocks.
-    Jitted, so the padded copy and the partial sums are fused temporaries
-    rather than live eager arrays (at n = 200k, d = 2000 the X^T code is
-    3.3 GB of output on a 16 GiB chip).
+    def grid_row(col_sums, r):
+        blocks = jax.lax.dynamic_slice_in_dim(a, r * g * b, g * b)
+        blocks = blocks.reshape(g, b, s)
+        return col_sums + blocks, blocks.sum(axis=0)
+
+    col_sums, row_sums = jax.lax.scan(
+        grid_row, jnp.zeros((g, b, s), a.dtype), jnp.arange(q))
+    if q < g:
+        rest = a[q * g * b:]
+        last = jnp.pad(rest, ((0, g * b - rest.shape[0]), (0, 0)))
+        last = last.reshape(g, b, s)
+        row_sums = jnp.concatenate([
+            row_sums, last.sum(axis=0, keepdims=True),
+            jnp.zeros((g - q - 1, b, s), a.dtype)])
+        col_sums = col_sums + last
+    return jnp.concatenate([row_sums, col_sums,
+                            row_sums.sum(axis=0, keepdims=True)])
+
+
+def _column_block_parity(a: jax.Array, code: ProductCode) -> jax.Array:
+    """The parity of the grid of A's column blocks, each parity block an
+    (n, b) block in A's layout: (2g+1, n, b), each summed straight from
+    A's columns."""
+    g, b, t = code.grid, code.block_rows, code.num_blocks
+    n = a.shape[0]
+    blocks = [a[:, k * b:(k + 1) * b] for k in range(t)]
+    blocks[-1] = jnp.pad(blocks[-1], ((0, 0), (0, b - blocks[-1].shape[1])))
+
+    def total(ks):
+        return functools.reduce(jnp.add, [blocks[k] for k in ks],
+                                jnp.zeros((n, b), a.dtype))
+
+    return jnp.stack([total(range(r * g, min(r * g + g, t)))
+                      for r in range(g)]
+                     + [total(range(c, t, g)) for c in range(g)]
+                     + [total(range(t))])
+
+
+@functools.partial(jax.jit, static_argnames=("code", "transpose"))
+def encode_2d(a: jax.Array, code: ProductCode,
+              transpose: bool = False) -> jax.Array:
+    """The 2g+1 parity blocks of the code of A's rows: (rows, s) ->
+    (2g+1, b, s), the g row parities, the g column parities, the corner.
+
+    With ``transpose`` the code is of A^T, whose row blocks are A's column
+    blocks: (n, cols) -> (2g+1, n, b), each parity kept in A's layout
+    (block p of the code of A^T is ``parity[p].T``), summed from A's
+    columns with no transpose of A.
     """
+    if transpose:
+        return _column_block_parity(a, code)
+    return _row_block_parity(a, code)
+
+
+@functools.partial(jax.jit, static_argnames=("code",))
+def encode_full(a: jax.Array, code: ProductCode) -> jax.Array:
+    """A (rows, s) -> the whole code ((g+1), (g+1), b, s): the systematic
+    blocks (A zero-padded to g^2 * b rows) and their parities, one block
+    per worker, as ``distributed_coded_matvec`` takes it."""
     g, b = code.grid, code.block_rows
     rows, s = a.shape
     pad = code.padded_blocks * b - rows
@@ -68,8 +134,28 @@ def encode_2d(a: jax.Array, code: ProductCode) -> jax.Array:
 
 
 def coded_block_products(enc: jax.Array, x: jax.Array) -> jax.Array:
-    """Every worker's task: its block times x.  ((g+1),(g+1),b,s) -> (...,b)."""
+    """Every worker's task on the whole code: its block times x.
+    ((g+1),(g+1),b,s) -> (...,b)."""
     return jnp.einsum("rcbs,s->rcb", enc, x)
+
+
+def block_products(a: jax.Array, parity: jax.Array, v: jax.Array,
+                   code: ProductCode, transpose: bool = False) -> jax.Array:
+    """Every worker's task, ((g+1), (g+1), b), from A and its parity blocks
+    (``encode_2d``): the systematic cells are A v (A^T v with
+    ``transpose``) zero-padded to g^2 b and cut into blocks, the parity
+    cells the parity blocks times v."""
+    g, b = code.grid, code.block_rows
+    if transpose:
+        sys = v @ a
+        par = jnp.einsum("pnb,n->pb", parity, v)
+    else:
+        sys = a @ v
+        par = jnp.einsum("pbs,s->pb", parity, v)
+    sys = jnp.pad(sys, (0, code.padded_blocks * b - sys.shape[0]))
+    top = jnp.concatenate([sys.reshape(g, g, b), par[:g, None]], axis=1)
+    # The last grid row: the column parities, then the corner.
+    return jnp.concatenate([top, par[None, g:]], axis=0)
 
 
 def _peel_axis(vals: jax.Array, known: jax.Array, axis: int) -> Tuple[jax.Array, jax.Array]:
@@ -204,20 +290,22 @@ def verified_decode(products: jax.Array, arrived: jax.Array,
     return y[:out_rows], True, n_flagged
 
 
-@functools.partial(jax.jit, static_argnames=("code", "out_rows"))
-def coded_matvec(enc: jax.Array, x: jax.Array, code: ProductCode,
-                 out_rows: int,
-                 erased: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
-    """End-to-end straggler-resilient matvec given pre-encoded blocks.
+@functools.partial(jax.jit, static_argnames=("code", "transpose"))
+def coded_matvec(a: jax.Array, parity: jax.Array, v: jax.Array,
+                 code: ProductCode, erased: Optional[jax.Array] = None,
+                 transpose: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """End-to-end straggler-resilient A v (A^T v with ``transpose``) from A
+    and its parity blocks (``encode_2d``).
 
     erased: bool ((g+1),(g+1)) straggler mask (True = missing).  None = none.
     """
-    prods = coded_block_products(enc, x)
+    prods = block_products(a, parity, v, code, transpose)
     if erased is None:
         known = jnp.ones(prods.shape[:2], dtype=bool)
     else:
         known = ~erased
-    return decode_matvec(prods, known, code, out_rows)
+    return decode_matvec(prods, known, code,
+                         a.shape[1] if transpose else a.shape[0])
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +318,9 @@ def distributed_coded_matvec(enc_flat: jax.Array, x: jax.Array,
                              worker_axis: str) -> Tuple[jax.Array, jax.Array]:
     """Coded matvec with worker tasks sharded over ``worker_axis``.
 
-    enc_flat: (W_pad, b, s) encoded blocks flattened row-major and zero-padded
-       to a multiple of the axis size (W_pad >= (g+1)^2).
+    enc_flat: (W_pad, b, s) blocks of the whole code (``encode_full``)
+       flattened row-major and zero-padded to a multiple of the axis size
+       (W_pad >= (g+1)^2).
     erased_flat: (W_pad,) straggler erasures.  Erased workers' products are
        masked before the gather — simulating "the master never saw them".
     """

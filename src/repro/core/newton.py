@@ -166,7 +166,9 @@ def _ws_gb(working_set_bytes: float) -> float:
 class CodedMatvecEngine:
     """Holds the one-time 2-D product-code encodings of X and X^T (the paper
     amortizes encoding across iterations, Sec. 4.1) and serves straggler-
-    resilient matvecs.
+    resilient matvecs.  A code's systematic blocks are X itself, so the
+    engine keeps X by reference and only the two parity stacks
+    (``coded.encode_2d``), both summed from X with no transpose of it.
 
     Each operand's encode is billed as a real fleet phase on first use.
     With ``overlap_encode`` (the default, the paper's pipeline) both
@@ -190,9 +192,13 @@ class CodedMatvecEngine:
         br_d = max(1, min(block_rows, d))
         self.code_x = coded.make_code(n, br_n)      # for X @ v    (n rows)
         self.code_xt = coded.make_code(d, br_d)     # for X^T @ v  (d rows)
-        self.enc_x = coded.encode_2d(data.x, self.code_x)
-        self.enc_xt = coded.encode_2d(data.x.T, self.code_xt)
+        self.x = data.x
+        with TraceAnnotation(wall.ENCODE):
+            self.parity = {
+                "X": coded.encode_2d(data.x, self.code_x),
+                "XT": coded.encode_2d(data.x, self.code_xt, transpose=True)}
         self.out_rows = {"X": n, "XT": d}
+        self.cols = {"X": d, "XT": n}    # the width of one coded block
         self.fallbacks = 0
         # Degraded-mode latch: flips on the first *observed* corruption
         # (a parity flag or a codeword-verification reject).  From then
@@ -206,12 +212,11 @@ class CodedMatvecEngine:
         self.paranoid = False
 
     def _mv(self, tag: str, v: jax.Array, erased: Optional[jax.Array]):
-        # The codes go to the jitted coded_matvec as arguments: a jitted
-        # closure over self would bake them into the compiled program as
-        # constants (5.0 GB at n = 200k, d = 2000).
-        enc = self.enc_x if tag == "X" else self.enc_xt
-        return coded.coded_matvec(enc, v, self.code_for(tag),
-                                  self.out_rows[tag], erased)
+        # X and the parity go to the jitted coded_matvec as arguments: a
+        # jitted closure over self would bake them into the compiled
+        # program as constants.
+        return coded.coded_matvec(self.x, self.parity[tag], v,
+                                  self.code_for(tag), erased, tag == "XT")
 
     def code_for(self, tag: str) -> coded.ProductCode:
         return self.code_x if tag == "X" else self.code_xt
@@ -231,10 +236,9 @@ class CodedMatvecEngine:
         ``not_before`` overlap machinery either way."""
         code = self.code_for(tag)
         w = code.num_workers
-        enc = self.enc_x if tag == "X" else self.enc_xt
-        flops = 2.0 * code.block_rows * enc.shape[-1]   # one block matvec
-        mem_bytes = scheduler.matvec_worker_bytes(code.block_rows,
-                                                  enc.shape[-1])
+        cols = self.cols[tag]
+        flops = 2.0 * code.block_rows * cols   # one block matvec
+        mem_bytes = scheduler.matvec_worker_bytes(code.block_rows, cols)
         mem = _phase_mem(self.phase_memory, mem_bytes)
         ws = _ws_gb(mem_bytes)
         enc_floor = {"t": None}   # set if this call bills an encode phase
@@ -277,7 +281,7 @@ class CodedMatvecEngine:
             self._encode_pending.discard(tag)
             if self._encode_t0 is None:
                 self._encode_t0 = clock.time
-            enc_flops = float(code.block_rows * enc.shape[-1])  # parity adds
+            enc_flops = float(code.block_rows * cols)  # parity adds
             nb = self._encode_t0 if self.overlap_encode else None
             if nb is not None and nb == clock.time:
                 # Launching "now" overlaps nothing: take the sequential
@@ -341,7 +345,8 @@ class CodedMatvecEngine:
             # Reconstruct what the master actually received: clean block
             # products plus seeded garbage at the corrupted cells.
             g1 = code.grid + 1
-            prods = coded.coded_block_products(enc, v)
+            prods = coded.block_products(self.x, self.parity[tag], v, code,
+                                         tag == "XT")
             noise = (jnp.sqrt(jnp.mean(prods ** 2)) + 1e-30) * \
                 jax.random.normal(jax.random.fold_in(key, 777), prods.shape)
             cgrid = jnp.asarray(corrupt.reshape(g1, g1))
@@ -815,6 +820,11 @@ def _oversketched_newton(objective, data: Dataset, w0: jax.Array,
                                overlap_encode=cfg.overlap_encode,
                                phase_memory=cfg.phase_memory,
                                corruption_detection=cfg.corruption_detection)
+    tel = _telemetry(clock)
+    if tel.enabled:
+        # The bytes each code keeps on the device beside X: its parity.
+        for tag, parity in engine.parity.items():
+            tel.metrics.gauge(f"coded.held_bytes.{tag}").set(parity.nbytes)
 
     w = jnp.asarray(w0, jnp.float32)
     hist: Dict[str, List[float]] = {k: [] for k in (
@@ -829,7 +839,6 @@ def _oversketched_newton(objective, data: Dataset, w0: jax.Array,
     prev_f = None
     prev_decrease = None
 
-    tel = _telemetry(clock)
     run_span = tel.trace.begin(
         "newton", "run", clock.time if clock is not None else 0.0,
         sketch_family=cfg.sketch_family, schedule=cfg.schedule,

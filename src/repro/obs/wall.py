@@ -13,6 +13,7 @@ Host spans (``oversketched_newton`` and the fleet it calls):
 
 ==========================  ==================================================
 ``osn.solve``               one ``oversketched_newton`` call
+``osn.encode``              the dispatch of the product codes' parity encodes
 ``osn.iter``                one iteration; its start is the iteration's stamp
 ``osn.gradient``            step 1, the coded gradient and its matvecs
 ``osn.hessian``             steps 2+3, the sketch draw and the Hessian dispatch
@@ -43,6 +44,7 @@ framework op name, ``jit(fn)/osn_sketch/while/...``):
 """
 
 SOLVE = "osn.solve"
+ENCODE = "osn.encode"
 ITER = "osn.iter"
 GRADIENT = "osn.gradient"
 HESSIAN = "osn.hessian"

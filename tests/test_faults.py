@@ -275,7 +275,7 @@ def _coded_setup(key=3, rows=32, cols=12, block=8):
     a = jax.random.normal(k, (rows, cols))
     v = jax.random.normal(jax.random.fold_in(k, 1), (cols,))
     code = coded.make_code(rows, block)
-    prods = coded.coded_block_products(coded.encode_2d(a, code), v)
+    prods = coded.coded_block_products(coded.encode_full(a, code), v)
     return a @ v, prods, code, rows
 
 

@@ -6,7 +6,9 @@ kernel compiled with ``interpret=False`` (its block tiling, its scoped-VMEM
 request) and that the program fits one chip's 16 GiB of HBM.  The widths
 are epsilon's published d = 2000 with n cut to 200,000 rows (the fig7
 sketch: K = 148 blocks of b = 256) and a9a at its full 32,000 x 123 (the
-fig8 sketch: K = 13 blocks of b = 128).
+fig8 sketch: K = 13 blocks of b = 128).  The paper's synthetic problem
+is compiled at its full 300,000 x 3000 (its sketch: K = 148 blocks of
+b = 256), with the product codes held as parity only.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker that runs this file
@@ -27,8 +29,10 @@ sys.path.insert(0, REPO)            # chip_smoke.py lives at the repo root
 
 from chip_smoke import PHASES  # noqa: E402
 from repro import sketching  # noqa: E402
+from repro.core import coded  # noqa: E402
 from repro.core.coded import make_code  # noqa: E402
-from repro.core.newton import _jitted_distavg_direction  # noqa: E402
+from repro.core.newton import (_jitted_distavg_direction,  # noqa: E402
+                               _jitted_sketched_hessian)
 from repro.core.objectives import Dataset, LogisticRegression  # noqa: E402
 from repro.core.sketch import (MXU_MAX_BLOCK_SIZE, CountSketch,  # noqa: E402
                                OverSketchConfig, apply_sketch)
@@ -226,3 +230,84 @@ def test_count_sketch_apply_compiles_at_distavg_width(one_chip):
         ((n, d), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
     assert _hbm_bytes(compiled) <= HBM_BYTES
+
+
+# The paper's synthetic logistic problem (arXiv:1903.08857, Sec. 5) at its
+# published size, as bench/configs/synthetic.json runs it.
+SYNTHETIC = dict(n=300_000, d=3000, coded_block_rows=256,
+                 sketch=OverSketchConfig(30208, 256, 0.25))
+
+
+def _row_major(sharding, ndim):
+    return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
+
+
+def test_synthetic_solve_programs_fit_one_chip_beside_x_and_parity(one_chip):
+    """At 300,000 x 3000 the two parity encodes, both coded matvecs and the
+    Hessian program compile for the chip, and each program's arguments,
+    output and temporaries, with X and both parity stacks held beside
+    them, fit its 16 GiB.  Arrays are row-major, as the program hands
+    them over.  The whole codes would not fit: they alone take 11.7 GB
+    beside X's 3.6."""
+    n, d, br = SYNTHETIC["n"], SYNTHETIC["d"], SYNTHETIC["coded_block_rows"]
+    cfg = SYNTHETIC["sketch"]
+    k, b = cfg.total_blocks, cfg.block_size
+    code_x, code_xt = make_code(n, br), make_code(d, br)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_row_major(one_chip, len(shape)))
+
+    def program(fn, *out_ndims):
+        out = tuple(_row_major(one_chip, k) for k in out_ndims)
+        return jax.jit(fn, out_shardings=out[0] if len(out) == 1 else out)
+
+    x, v_d, v_n = arg((n, d)), arg((d,)), arg((n,))
+    par_x = jax.eval_shape(lambda a: coded.encode_2d(a, code_x), x)
+    par_xt = jax.eval_shape(
+        lambda a: coded.encode_2d(a, code_xt, transpose=True), x)
+    assert par_x.shape == (2 * code_x.grid + 1, br, d)
+    assert par_xt.shape == (2 * code_xt.grid + 1, n, br)
+    fam = sketching.get("oversketch", cfg)
+    hessian = _jitted_sketched_hessian(LogisticRegression(lam=1e-5), fam,
+                                       False)
+    compiled = {
+        "encode_x": program(lambda a: coded.encode_2d(a, code_x), 3
+                            ).lower(x),
+        "encode_xt": program(lambda a: coded.encode_2d(
+            a, code_xt, transpose=True), 3).lower(x),
+        "coded_matvec_x": program(
+            lambda a, p, v, e: coded.coded_matvec(a, p, v, code_x, e),
+            1, 0).lower(x, arg(par_x.shape), v_d,
+                        arg((code_x.grid + 1,) * 2, jnp.bool_)),
+        "coded_matvec_xt": program(
+            lambda a, p, v, e: coded.coded_matvec(a, p, v, code_xt, e, True),
+            1, 0).lower(x, arg(par_xt.shape), v_n,
+                        arg((code_xt.grid + 1,) * 2, jnp.bool_)),
+        "hessian": program(hessian, 2).lower(
+            v_d, Dataset(x, v_n),
+            CountSketch(h=arg((k, n), jnp.int32), sigma=arg((k, n)),
+                        block_size=b), arg((k,), jnp.bool_)),
+    }
+    compiled = {name: low.compile() for name, low in compiled.items()}
+    assert "tpu_custom_call" in compiled["hessian"].as_text()
+    # Bytes on the device, row-major tiles included, of what the solve
+    # holds, and of each held array that is among a program's arguments.
+    held = {"x": compiled["encode_x"].memory_analysis()
+            .argument_size_in_bytes,
+            "par_x": compiled["encode_x"].memory_analysis()
+            .output_size_in_bytes,
+            "par_xt": compiled["encode_xt"].memory_analysis()
+            .output_size_in_bytes}
+    assert held["par_x"] + held["par_xt"] < 3.1e9
+    among_args = {"encode_x": ("x",), "encode_xt": ("x",),
+                  "coded_matvec_x": ("x", "par_x"),
+                  "coded_matvec_xt": ("x", "par_xt"), "hessian": ("x",)}
+    for name, c in compiled.items():
+        total = sum(held.values()) + _hbm_bytes(c) - sum(
+            held[a] for a in among_args[name])
+        assert total <= HBM_BYTES, (name, total)
+    # The encodes copy no X: their temporaries are under a fifth of it.
+    for name in ("encode_x", "encode_xt"):
+        assert compiled[name].memory_analysis().temp_size_in_bytes \
+            < held["x"] / 5, name

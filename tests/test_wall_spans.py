@@ -9,9 +9,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import sketching
+from repro import obs, sketching
 from repro.core import (Dataset, LogisticRegression, NewtonConfig,
-                        OverSketchConfig, newton, oversketched_newton)
+                        OverSketchConfig, SimClock, StragglerModel,
+                        make_code, newton, oversketched_newton)
 from repro.obs import wall
 
 D = 16
@@ -104,6 +105,28 @@ def test_sync_spans_per_iteration_are_the_default_paths_host_reads(traced):
     assert per_site == {wall.SYNC_STRAGGLER: 8, wall.SYNC_MASK: 2,
                         wall.SYNC_DECODE: 2, wall.SYNC_SURVIVORS: 1,
                         wall.SYNC_GUARD: 1, wall.SYNC_HISTORY: 3}
+
+
+def test_one_solve_opens_the_encode_span_and_sets_the_held_bytes(traced):
+    """The parity encodes are dispatched once, under ``osn.encode`` inside
+    the solve and before its first iteration, and each code's bytes kept
+    on the device are its 2g+1 parity blocks of b rows by the operand's
+    width."""
+    spans, _, _ = traced
+    solve, = [s for s in spans if s[2] == wall.SOLVE]
+    encode, = [s for s in spans if s[2] == wall.ENCODE]
+    assert _inside(encode, solve)
+    assert encode[1] <= min(s[0] for s in spans if s[2] == wall.ITER)
+    tel = obs.Telemetry()
+    data = _data()
+    oversketched_newton(LogisticRegression(lam=1e-5), data, jnp.zeros(D),
+                        CFG, model=SimClock(StragglerModel(), telemetry=tel))
+    n, d = data.x.shape
+    gauges = tel.metrics.snapshot()["gauges"]
+    for tag, rows, width in (("X", n, d), ("XT", d, n)):
+        code = make_code(rows, min(CFG.coded_block_rows, rows))
+        want = 4 * (2 * code.grid + 1) * code.block_rows * width
+        assert gauges[f"coded.held_bytes.{tag}"] == {"value": want, "n": 1}
 
 
 def test_tracing_leaves_the_iterate_unchanged(traced):
