@@ -13,13 +13,22 @@ all K blocks of:
   mxu          ``kernels/count_sketch.py`` at the tiles ``pick_tiles``
                gives (``"picked": true``), then at each ``--tiles``
                setting ``G/tn/td`` (sketch blocks per group, panel rows,
-               panel columns) whose working set fits the kernel's budget.
+               panel columns) whose working set fits the kernel's budget;
+  mxu ... live N/K  the kernel at the picked tiles with only N of the K
+               blocks live, as the straggler mask leaves them: N is the
+               most survivors whose ``OverSketchConfig`` provisions at
+               most K blocks (118 of 148), the dead ones drawn from
+               ``--seed``.  Its time against the
+               all-live row's shows whether the cost follows the live
+               blocks.
 
 Each row is one JSON line: the case, the variant, the first call's
 seconds (compile included), the seconds of each of ``--repeats`` timed
-calls, their median in ms per sketch block, and for the kernel its error
-against the segment sums, ``rel_max`` = max |diff| / max |segment sums|
-and ``rel_fro`` (relative Frobenius).  ``--out`` writes the rows again as
+calls, their median in ms per sketch block of K, and for the kernel its
+error against the segment sums, ``rel_max`` = max |diff| / max |segment
+sums| and ``rel_fro`` (relative Frobenius), over its live blocks; the
+masked row also gives ``dead_max``, the largest |entry| of a dead block,
+which the kernel's contract makes 0.  ``--out`` writes the rows again as
 one JSON list.  The defaults are epsilon's width and sketch (K = 148
 blocks of b = 256).  Off a TPU the kernel runs in the Pallas interpreter,
 which checks the rows at a small size and times nothing of interest.
@@ -69,9 +78,10 @@ def _timed(fn, repeats: int):
 def measure(n: int, d: int, k: int, b: int, tiles, repeats: int,
             seed: int):
     """The rows of one case: the segment sums, then the kernel at the
-    picked tiles and at each of ``tiles``."""
+    picked tiles, at them with the straggler mask's survivors live, and
+    at each of ``tiles``."""
     interpret = jax.default_backend() != "tpu"
-    kh, ks, ka = jax.random.split(jax.random.PRNGKey(seed), 3)
+    kh, ks, ka, kl = jax.random.split(jax.random.PRNGKey(seed), 4)
     h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
     sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
     a = jax.random.normal(ka, (n, d), dtype=jnp.float32)
@@ -85,27 +95,47 @@ def measure(n: int, d: int, k: int, b: int, tiles, repeats: int,
                                              block_size=b))
     expect, first, times = _timed(lambda: segment_sums(h, sigma, a), repeats)
     rows = [row("segment_sum", first, times)]
-    ref_max = jnp.abs(expect).max()
-    ref_fro = jnp.linalg.norm(expect)
     picked = count_sketch.pick_tiles(k, b, n, d)
-    for tile in [picked] + [t for t in tiles if t != picked]:
+    n_live = max(n for n in range(1, k + 1)
+                 if sketch.OverSketchConfig(n * b, b).total_blocks <= k)
+    some = jnp.zeros((k,), bool).at[
+        jax.random.permutation(kl, k)[:n_live]].set(True)
+    runs = [(picked, None)]
+    if n_live < k:
+        runs.append((picked, some))
+    runs += [(t, None) for t in tiles if t != picked]
+    for tile, live in runs:
         group, tn, td = tile
         variant = f"mxu {group}/{tn}/{td}"
+        extra = {}
+        if live is not None:
+            variant += f" live {n_live}/{k}"
+            extra["live_blocks"] = n_live
         vmem = count_sketch.vmem_bytes(group, b, tn, td)
         if vmem > count_sketch.VMEM_BUDGET_BYTES:
             rows.append({**case, "variant": variant, "skipped":
                          f"{vmem} bytes of VMEM, over the budget"})
             continue
+        mask = jnp.ones((k,), bool) if live is None else live
         apply = functools.partial(
             count_sketch._count_sketch_apply, block_size=b, group=group,
             tile_n=tn, tile_d=td, interpret=interpret)
-        out, first, times = _timed(lambda: apply(h, sigma, a), repeats)
-        diff = out - expect
+        out, first, times = _timed(lambda: apply(h, sigma, a, mask),
+                                   repeats)
+        # The error over the live blocks; a dead block must read 0.
+        on = mask[:, None, None]
+        ref = jnp.where(on, expect, 0.0)
+        diff = jnp.where(on, out, 0.0) - ref
+        if live is not None:
+            extra["dead_max"] = float(jnp.abs(jnp.where(on, 0.0, out)).max())
         rows.append(row(variant, first, times, picked=tile == picked,
                         vmem_bytes=vmem,
-                        rel_max=float(jnp.abs(diff).max() / ref_max),
-                        rel_fro=float(jnp.linalg.norm(diff) / ref_fro)))
-        del out, diff
+                        rel_max=float(jnp.abs(diff).max()
+                                      / jnp.abs(ref).max()),
+                        rel_fro=float(jnp.linalg.norm(diff)
+                                      / jnp.linalg.norm(ref)),
+                        **extra))
+        del out, diff, ref
     return rows
 
 

@@ -449,7 +449,9 @@ def _jitted_sketched_hessian(objective, family: "sketching.SketchFamily",
     platform: ``mxu_count_sketch`` or ``segment_sum`` for the OverSketch
     family, ``unfused`` for the others) at this function's call site in
     ``_hessian_phase`` — inside the jitted closure there is no Python
-    left to log from."""
+    left to log from.  On ``mxu_count_sketch`` the same site counts the
+    dropped blocks the kernel skips
+    (``kernel.count_sketch.blocks_skipped``)."""
     def fn(w, data, state, survivors):
         with jax.named_scope(wall.HESS_SQRT):
             a = objective.hess_sqrt(w, data)
@@ -632,8 +634,13 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
         # Queued behind the Hessian program, so this read waits for it.
         n_surv = jnp.sum(survivors)
         with TraceAnnotation(wall.SYNC_SURVIVORS):
-            m_eff = float(n_surv) * scfg.block_size
+            n_surv = float(n_surv)
+        m_eff = n_surv * scfg.block_size
         if tel.enabled:
+            if path == "mxu_count_sketch":
+                # The MXU kernel does no matmul for a dropped block.
+                tel.metrics.counter("kernel.count_sketch.blocks_skipped"
+                                    ).inc(scfg.total_blocks - n_surv)
             tel.metrics.gauge("sketch.m_eff").set(m_eff)
             tel.metrics.gauge("sketch.mp_debias").set(
                 max(0.0, 1.0 - d / m_eff) if m_eff > 0 else 0.0)
@@ -641,8 +648,7 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
             # provisioning statistic the launch planner reads back out of
             # the cross-run store (obs.store run records keep the full
             # per-round series).
-            tel.metrics.histogram("sketch.survivors").observe(
-                float(jnp.sum(survivors)))
+            tel.metrics.histogram("sketch.survivors").observe(n_surv)
         return h_hat, m_eff
     # exact Hessian (paper's "exact Newton" baseline)
     block_flops = 2.0 * b * min(d, b) ** 2    # one (b x d_tile) gram block

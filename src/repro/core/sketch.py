@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -131,15 +130,21 @@ def sketch_impl(platform: str, block_size: int) -> str:
 
 
 def _apply_segment_sum(h: jax.Array, sigma: jax.Array, a: jax.Array,
-                       block_size: int) -> jax.Array:
+                       block_size: int,
+                       live: Optional[jax.Array] = None) -> jax.Array:
     """``lax.map`` streams the blocks, so peak memory is one signed (n, d)
     panel plus the (K, b, d) output — never the (K, n, d) tensor a vmap
-    over blocks would build (242 GB at epsilon's n = 200k, d = 2000)."""
-    return jax.lax.map(
+    over blocks would build (242 GB at epsilon's n = 200k, d = 2000).
+    Blocks that ``live`` marks dead are sketched, then zeroed."""
+    out = jax.lax.map(
         lambda hs: apply_block(hs[0], hs[1], block_size, a), (h, sigma))
+    if live is None:
+        return out
+    return jnp.where(live.astype(bool)[:, None, None], out, 0.0)
 
 
-def apply_sketch(cs: CountSketch, a: jax.Array) -> jax.Array:
+def apply_sketch(cs: CountSketch, a: jax.Array,
+                 live: Optional[jax.Array] = None) -> jax.Array:
     """All blocks: A (n, d) -> A_tilde (total_blocks, b, d).  Unscaled.
 
     The 1/sqrt(N) scale of Eq. (4) is folded into the Gram rescale (we divide
@@ -148,15 +153,21 @@ def apply_sketch(cs: CountSketch, a: jax.Array) -> jax.Array:
     and the block size (``sketch_impl``): the MXU kernel on a TPU, which
     has no fast scatter, and the segment sums elsewhere or for wide blocks.
     Both give the f32 segment sum up to the order of its additions.
+
+    ``live``, a (total_blocks,) mask such as the straggler survivors, marks
+    the blocks whose sketch is wanted: the others read exactly 0 on every
+    platform (the MXU kernel does no matmul for them), and the live ones
+    are what ``live=None`` gives, bit for bit.
     """
-    segment_sums = partial(_apply_segment_sum, block_size=cs.block_size)
-    if sketch_impl("tpu", cs.block_size) == "segment_sum":
-        return segment_sums(cs.h, cs.sigma, a)
+    b = cs.block_size
+    if sketch_impl("tpu", b) == "segment_sum":
+        return _apply_segment_sum(cs.h, cs.sigma, a, b, live)
     return jax.lax.platform_dependent(
-        cs.h, cs.sigma, a,
-        tpu=partial(count_sketch.count_sketch_apply,
-                    block_size=cs.block_size, interpret=False),
-        default=segment_sums)
+        cs.h, cs.sigma, a, live,
+        tpu=lambda h, sigma, a, live: count_sketch.count_sketch_apply(
+            h, sigma, a, b, live=live, interpret=False),
+        default=lambda h, sigma, a, live: _apply_segment_sum(
+            h, sigma, a, b, live))
 
 
 def apply_sketch_chunked(cs: CountSketch, a_fn: Callable[[int], jax.Array],

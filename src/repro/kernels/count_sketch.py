@@ -18,14 +18,24 @@ parts ``hi + mid + lo == a`` (8 significand bits each, 24 in all), so three
 passes ``O @ hi + O @ mid + O @ lo`` accumulated in f32 give the f32
 segment sum up to the order of its additions.  A bfloat16 A takes one pass.
 
-No operand is padded in HBM.  The last group overhangs K, and its blocks
-past K are skipped; the last row panel overhangs A and the bucket/sign
-rows, and its rows past n are zeroed in the kernel; columns past d reach
-only output columns past d, whose writes are dropped.
+Live blocks.  ``live`` (K,) marks the blocks whose sketch is wanted; it
+rides in SMEM, padded with zeros to whole groups, and a block it marks
+dead builds no one-hot and runs no pass, so its output reads exactly 0.
+The Hessian passes the straggler survivors: the Gram weighs a dropped
+block by 0, so the MXU skips work the master would discard.  Tiles and
+pass order do not depend on the mask, so a live block's sketch is the
+same either way.
+
+No operand but the (K,) mask is padded in HBM.  The last group overhangs
+K, and its blocks past K read dead; the last row panel overhangs A and
+the bucket/sign rows, and its rows past n are zeroed in the kernel;
+columns past d reach only output columns past d, whose writes are
+dropped.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -97,8 +107,8 @@ def _split(a: jax.Array):
     return [hi, mid, lo]
 
 
-def _kernel(h_ref, sigma_ref, a_ref, out_ref, *, block_size: int,
-            num_blocks: int, n_rows: int):
+def _kernel(live_ref, h_ref, sigma_ref, a_ref, out_ref, *,
+            block_size: int, n_rows: int):
     g = pl.program_id(0)
     r = pl.program_id(2)   # innermost: reduction over row panels
     group, tn = h_ref.shape
@@ -120,8 +130,9 @@ def _kernel(h_ref, sigma_ref, a_ref, out_ref, *, block_size: int,
     parts = _split(a)
     iota = jax.lax.broadcasted_iota(jnp.int32, (block_size, tn), 0)
     for i in range(group):
-        # Blocks past K (the last group's overhang) are skipped.
-        @pl.when(g * group + i < num_blocks)
+        # Dead blocks, and those past K (the last group's overhang, padded
+        # dead), are skipped: their output keeps the zeros of _init.
+        @pl.when(live_ref[g * group + i] != 0)
         def _apply(i=i):
             # Block i's signed one-hot bucket matrix, (b, tn).
             onehot = jnp.where(h_ref[i:i + 1, :] == iota, sigma[i:i + 1, :],
@@ -137,20 +148,23 @@ def _kernel(h_ref, sigma_ref, a_ref, out_ref, *, block_size: int,
 
 @functools.partial(jax.jit, static_argnames=("block_size", "group", "tile_n",
                                              "tile_d", "interpret"))
-def _count_sketch_apply(h, sigma, a, *, block_size: int, group: int,
+def _count_sketch_apply(h, sigma, a, live, *, block_size: int, group: int,
                         tile_n: int, tile_d: int, interpret: bool):
     k, n = h.shape
     d = a.shape[1]
     if a.dtype != jnp.bfloat16:
         a = a.astype(jnp.float32)
+    num_groups = pl.cdiv(k, group)
+    live = jnp.pad(live.astype(jnp.int32), (0, num_groups * group - k))
     vmem = vmem_bytes(group, block_size, tile_n, tile_d)
-    # Every edge block overhangs its array (no operand is padded): reads
-    # past an edge are masked above or skipped, writes past it dropped.
+    # Every edge block overhangs its array (only the mask is padded):
+    # reads past an edge are masked above or skipped, writes past it
+    # dropped.  The mask is one whole array in SMEM, read per block.
     return pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, num_blocks=k,
-                          n_rows=n),
-        grid=(pl.cdiv(k, group), pl.cdiv(d, tile_d), pl.cdiv(n, tile_n)),
+        functools.partial(_kernel, block_size=block_size, n_rows=n),
+        grid=(num_groups, pl.cdiv(d, tile_d), pl.cdiv(n, tile_n)),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((group, tile_n), lambda g, j, r: (g, r)),
             pl.BlockSpec((group, tile_n), lambda g, j, r: (g, r)),
             pl.BlockSpec((tile_n, tile_d), lambda g, j, r: (r, j)),
@@ -163,18 +177,24 @@ def _count_sketch_apply(h, sigma, a, *, block_size: int, group: int,
             vmem_limit_bytes=vmem + vmem // 2 + VMEM_HEADROOM_BYTES),
         interpret=interpret,
         name="mxu_count_sketch",
-    )(h.astype(jnp.int32), sigma.astype(jnp.float32), a)
+    )(live, h.astype(jnp.int32), sigma.astype(jnp.float32), a)
 
 
 def count_sketch_apply(h: jax.Array, sigma: jax.Array, a: jax.Array,
-                       block_size: int, *,
+                       block_size: int, *, live: Optional[jax.Array] = None,
                        interpret: bool = False) -> jax.Array:
     """(K, n) x (K, n) x (n, d) -> (K, block_size, d) float32.
 
-    Tiles come from ``pick_tiles``: groups of up to 16 sketch blocks share
-    each loaded panel of A, so A is read ceil(K / group) times."""
+    ``live`` is a (K,) bool or int mask of the blocks to sketch (default
+    every block).  A block with ``live`` false reads exactly 0 and costs
+    no matmul; a live block's sketch is bit for bit the one ``live=None``
+    gives.  Tiles come from ``pick_tiles``: groups of up to 16 sketch
+    blocks share each loaded panel of A, so A is read ceil(K / group)
+    times."""
     k, n = h.shape
+    if live is None:
+        live = jnp.ones((k,), jnp.int32)
     group, tn, td = pick_tiles(k, block_size, n, a.shape[1])
-    return _count_sketch_apply(h, sigma, a, block_size=block_size,
+    return _count_sketch_apply(h, sigma, a, live, block_size=block_size,
                                group=group, tile_n=tn, tile_d=td,
                                interpret=interpret)

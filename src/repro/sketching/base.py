@@ -12,6 +12,8 @@ This module defines the protocol every family implements:
 
   sample(key, num_rows) -> state     pytree of arrays (jit-transparent)
   apply(state, a)       -> (total_blocks, b, d) per-block  S_i^T A
+  apply_live(state, a, survivors) -> the same, what ``gram`` calls; a
+      family may zero (and skip) the blocks the mask drops
   gram(state, a, survivors) -> (d, d) masked, rescaled Gram estimate
   gram_fused(state, a, survivors) -> (d, d) or None — optional fused
       sketch->Gram Pallas path (A_tilde never materialized); the kernel's
@@ -76,6 +78,14 @@ class SketchFamily(abc.ABC):
         """Per-block application A (n, d) -> (total_blocks, b, d), unscaled
         by 1/sqrt(N) (the survivor rescale in ``gram`` absorbs it)."""
 
+    def apply_live(self, state: SketchState, a: jax.Array,
+                   survivors: Optional[jax.Array],
+                   use_kernels: bool = False) -> jax.Array:
+        """``apply`` for a Gram over ``survivors`` (None = every block).  A
+        family may leave the blocks the mask drops unsketched, reading 0,
+        since the Gram weighs them by 0; the default sketches them all."""
+        return self.apply(state, a, use_kernels=use_kernels)
+
     # Families with a block-local encode-matrix form set this True (and
     # override gram_fused); it drives fused_path reporting.
     has_fused_gram = False
@@ -131,7 +141,8 @@ class SketchFamily(abc.ABC):
             if fused is not None:
                 return fused
         with jax.named_scope(wall.SKETCH):
-            a_t = self.apply(state, a, use_kernels=use_kernels)
+            a_t = self.apply_live(state, a, survivors,
+                                  use_kernels=use_kernels)
         with jax.named_scope(wall.GRAM):
             return core_sketch.sketched_gram(a_t, survivors,
                                              use_kernels=use_kernels)

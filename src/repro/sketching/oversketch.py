@@ -3,9 +3,10 @@
 This is the seed implementation from ``repro.core.sketch`` migrated behind
 the ``SketchFamily`` protocol; ``repro.core`` re-exports are untouched, and
 ``apply`` without kernels is ``core.sketch.apply_sketch``, which picks its
-implementation by platform and block size (``apply_path``).  Per-block
-unbiasedness E[S_i S_i^T] = I is the Count-Sketch property the paper's
-Lemma 6.1 builds on.
+implementation by platform and block size (``apply_path``); ``gram``
+passes it the survivor mask (``apply_live``), so dropped blocks are not
+sketched.  Per-block unbiasedness E[S_i S_i^T] = I is the Count-Sketch
+property the paper's Lemma 6.1 builds on.
 
 Cost model: sketching is folded into the coded matmul workers (paper
 Sec. 4.1 amortizes encoding), so ``apply_flops`` stays 0 and a block worker
@@ -38,6 +39,14 @@ class OverSketchFamily(SketchFamily):
             return kops.count_sketch_apply(state.h, state.sigma, a,
                                            self.cfg.block_size)
         return core_sketch.apply_sketch(state, a)
+
+    def apply_live(self, state: core_sketch.CountSketch, a: jax.Array,
+                   survivors, use_kernels: bool = False) -> jax.Array:
+        # Reached only without kernels (gram_fused answers with them).  The
+        # apply skips the blocks the straggler mask drops (on a TPU the MXU
+        # kernel does no matmul for them): the Gram weighs them by 0, so
+        # H_hat is what every block's sketch gives.
+        return core_sketch.apply_sketch(state, a, survivors)
 
     def apply_path(self, platform: str) -> str:
         return core_sketch.sketch_impl(platform, self.cfg.block_size)

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core.sketch import OverSketchConfig
 from repro.kernels import count_sketch, ops, ref
 
 
@@ -104,6 +105,60 @@ def test_count_sketch_keeps_all_24_bits():
     assert rel(one_pass) > 1e-4
 
 
+# The blocks the straggler mask drops at each K = N + e: e of OverSketch's
+# provisioning for N = 10, 118 and 16 survivors (3 of 13, 30 of 148, 4 of 20).
+_DROPPED = {c.total_blocks: c.num_redundant
+            for c in (OverSketchConfig(n, 1) for n in (10, 118, 16))}
+
+
+def _live_mask(kind, k, key):
+    """all: every block; none: no block; k_of_n: the straggler mask's
+    count of blocks dropped at random."""
+    if kind == "all":
+        return jnp.ones((k,), bool)
+    if kind == "none":
+        return jnp.zeros((k,), bool)
+    return jnp.ones((k,), bool).at[
+        jax.random.permutation(key, k)[:_DROPPED[k]]].set(False)
+
+
+# K = 20 leaves the last group of 16 overhanging K; n = 1100 and 700 leave
+# the last row panel overhanging n.
+@pytest.mark.parametrize("k,n,d,b,dtype,kind", [
+    (13, 1100, 123, 128, jnp.float32, "k_of_n"),
+    (13, 1100, 123, 128, jnp.bfloat16, "all"),
+    (148, 1100, 130, 64, jnp.float32, "k_of_n"),
+    (148, 700, 70, 64, jnp.bfloat16, "k_of_n"),
+    (148, 700, 70, 64, jnp.float32, "none"),
+    (20, 700, 130, 64, jnp.float32, "all"),
+    (20, 700, 130, 64, jnp.bfloat16, "k_of_n"),
+    (20, 1100, 70, 128, jnp.float32, "none"),
+])
+def test_count_sketch_live_mask_skips_dead_blocks(k, n, d, b, dtype, kind):
+    """A dead block reads exactly 0; a live one is bit for bit what
+    ``live=None`` gives, and ``live=None`` is the segment sum."""
+    kh, ks, ka, kl = jax.random.split(jax.random.PRNGKey(k + n + d + b), 4)
+    h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
+    sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
+    a = jax.random.normal(ka, (n, d)).astype(dtype)
+    live = _live_mask(kind, k, kl)
+    every = np.asarray(count_sketch.count_sketch_apply(
+        h, sigma, a, b, interpret=True))
+    if kind == "all":
+        live = live.astype(jnp.int32)       # an int mask works as well
+    out = np.asarray(count_sketch.count_sketch_apply(
+        h, sigma, a, b, live=live, interpret=True))
+    on = np.asarray(live) != 0
+    assert out.shape == (k, b, d) and out.dtype == np.float32
+    np.testing.assert_array_equal(out[on], every[on])
+    assert not out[~on].any()
+    assert (~on).sum() == {"all": 0, "none": k,
+                           "k_of_n": _DROPPED.get(k)}[kind]
+    expect = ref.count_sketch_apply(h, sigma, a.astype(jnp.float32), b)
+    np.testing.assert_allclose(every, np.asarray(expect),
+                               rtol=1e-5, atol=1e-5)
+
+
 # The tiles at epsilon's and a9a's widths, at the distributed-average mode's
 # b = 2048 > d, and at the widest block that fits: always within the VMEM
 # budget, aligned to the (8, 128) rule.
@@ -129,20 +184,22 @@ def test_count_sketch_refuses_a_block_too_wide_for_vmem():
 
 def test_count_sketch_chip_script_checks_the_kernel():
     """benchmarks/count_sketch_chip.py at a small size on the CPU: the
-    segment sums, the kernel at the picked tiles and at a given setting,
-    both agreeing with the sums, and a setting over the budget skipped."""
+    segment sums, the kernel at the picked tiles (all blocks live, then
+    the straggler mask's 10 of 13) and at a given setting, each agreeing
+    with the sums, and a setting over the budget skipped."""
     from benchmarks import count_sketch_chip
     rows = count_sketch_chip.measure(
         300, 130, 13, 64, count_sketch_chip.parse_tiles(
             "8/128/128,16/4096/4096"), 1, seed=2 ** 31 + 7)
     assert [r["variant"] for r in rows] == [
-        "segment_sum", "mxu 16/384/256", "mxu 8/128/128",
-        "mxu 16/4096/4096"]
-    assert [r.get("picked") for r in rows[1:3]] == [True, False]
-    for r in rows[1:3]:
+        "segment_sum", "mxu 16/384/256", "mxu 16/384/256 live 10/13",
+        "mxu 8/128/128", "mxu 16/4096/4096"]
+    assert [r.get("picked") for r in rows[1:4]] == [True, True, False]
+    for r in rows[1:4]:
         assert r["rel_max"] <= 1e-5 and r["rel_fro"] <= 1e-5
         assert len(r["s"]) == 1 and r["ms_per_block"] > 0
-    assert "over the budget" in rows[3]["skipped"]
+    assert rows[2]["live_blocks"] == 10 and rows[2]["dead_max"] == 0.0
+    assert "over the budget" in rows[4]["skipped"]
     assert count_sketch_chip.parse_cases("148x256,32x1024") == [
         (148, 256), (32, 1024)]
 

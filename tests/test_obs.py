@@ -226,6 +226,31 @@ def test_perfetto_layout():
     obs.validate_trace(trace, require_phases=("hessian", "linesearch"))
 
 
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_blocks_skipped_counts_the_dropped_blocks_per_round(monkeypatch,
+                                                            platform):
+    """Where the data's platform selects the MXU kernel, each sketch round
+    adds total_blocks - survivors to kernel.count_sketch.blocks_skipped;
+    the segment sums skip nothing, so the CPU path has no such counter.
+    The program itself runs on the CPU either way: the counter follows
+    the path the platform selects."""
+    from repro.core import newton
+    monkeypatch.setattr(newton, "_platform", lambda x: platform)
+    tel = obs.Telemetry()
+    _tiny_newton(tel)
+    snap = tel.metrics.snapshot()
+    survivors = tel.metrics.histogram("sketch.survivors").values
+    assert len(survivors) == 2          # one sketch round per iteration
+    total = 5                           # N = 64 / 16 = 4, e = ceil(0.25 N)
+    assert all(s < total for s in survivors)
+    if platform == "tpu":
+        assert snap["counters"]["kernel.path.mxu_count_sketch"] == 2.0
+        assert snap["counters"]["kernel.count_sketch.blocks_skipped"] \
+            == sum(total - s for s in survivors) == 2.0
+    else:
+        assert "kernel.count_sketch.blocks_skipped" not in snap["counters"]
+
+
 def test_perfetto_golden_bytes():
     got = obs.dumps_stable(obs.to_perfetto(_synthetic_spans()))
     assert PERFETTO_GOLDEN.exists(), \

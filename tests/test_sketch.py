@@ -174,3 +174,85 @@ def test_distributed_gram_matches_local():
                                         block_axis="model")
     np.testing.assert_allclose(np.asarray(local), np.asarray(dist),
                                rtol=1e-5, atol=1e-5)
+
+
+def _dropped(cfg, key):
+    """A survivor mask that drops e = total - N blocks, as k-of-n does."""
+    keep = jax.random.permutation(key, cfg.total_blocks)[:cfg.num_blocks]
+    return jnp.zeros((cfg.total_blocks,), bool).at[keep].set(True)
+
+
+def test_apply_sketch_zeroes_exactly_the_dead_blocks():
+    """Off a TPU a live mask leaves the segment sums of the live blocks as
+    they are, bit for bit, jitted or not, and zeroes the dead ones."""
+    key = jax.random.PRNGKey(21)
+    n, d = 300, 13
+    a = jax.random.normal(key, (n, d))
+    cfg = sk.OverSketchConfig(8 * 64, 64, 0.25)
+    cs = sk.sample_countsketch(jax.random.fold_in(key, 1), n, cfg)
+    live = _dropped(cfg, jax.random.fold_in(key, 2))
+    on = np.asarray(live)
+    assert (~on).sum() == cfg.num_redundant == 2
+    every = np.asarray(sk.apply_sketch(cs, a))
+    for out in (sk.apply_sketch(cs, a, live),
+                jax.jit(sk.apply_sketch)(cs, a, live)):
+        out = np.asarray(out)
+        np.testing.assert_array_equal(out[on], every[on])
+        assert not out[~on].any()
+    assert every[~on].any()
+
+
+def test_oversketch_gram_with_survivors_is_the_all_block_formula():
+    """The OverSketch family hands the survivor mask to its apply, so the
+    dropped blocks are never sketched, and H_hat is still, bit for bit,
+    the masked Gram of every block's sketch."""
+    from repro import sketching
+    key = jax.random.PRNGKey(22)
+    n, d = 400, 17
+    a = jax.random.normal(key, (n, d)) / np.sqrt(n)
+    cfg = sk.OverSketchConfig(10 * 32, 32, 0.25)
+    fam = sketching.get("oversketch", cfg)
+    cs = fam.sample(jax.random.fold_in(key, 1), n)
+    surv = _dropped(cfg, jax.random.fold_in(key, 2))
+    on = np.asarray(surv)
+    assert (~on).sum() == 3
+    assert not np.asarray(fam.apply_live(cs, a, surv))[~on].any()
+    formula = jax.jit(lambda c, x, m: sk.sketched_gram(sk.apply_sketch(c, x),
+                                                       m))
+    for gram, expect in ((fam.gram(cs, a, surv), sk.sketched_gram(
+            sk.apply_sketch(cs, a), surv)),
+            (jax.jit(fam.gram)(cs, a, surv), formula(cs, a, surv))):
+        np.testing.assert_array_equal(np.asarray(gram), np.asarray(expect))
+
+
+def test_newton_iterates_do_not_depend_on_the_mask_reaching_the_apply(
+        monkeypatch):
+    """A short solve on the straggler clock: the iterates, and the
+    simulated seconds and dollars, are the same bit for bit whether the
+    sketch apply skips the dropped blocks or sketches all of them."""
+    from repro.core import newton
+    from repro.core.objectives import Dataset, LogisticRegression
+    from repro.core.straggler import StragglerModel
+    from repro.sketching.base import SketchFamily
+    from repro.sketching.oversketch import OverSketchFamily
+    kx, kw = jax.random.split(jax.random.PRNGKey(23))
+    x = jax.random.normal(kx, (600, 12))
+    y = jnp.sign(x @ jax.random.normal(kw, (12,)))
+    cfg = newton.NewtonConfig(iters=3, coded_block_rows=128,
+                              sketch=sk.OverSketchConfig(8 * 32, 32, 0.25))
+
+    def solve():
+        newton._jitted_sketched_hessian.cache_clear()
+        return newton.oversketched_newton(
+            LogisticRegression(lam=1e-3), Dataset(x=x, y=y), jnp.zeros(12),
+            cfg, model=StragglerModel(p_tail=0.05, tail_hi=3.0))
+
+    skipped = solve()
+    with monkeypatch.context() as mp:
+        mp.setattr(OverSketchFamily, "apply_live", SketchFamily.apply_live)
+        every = solve()
+    newton._jitted_sketched_hessian.cache_clear()
+    np.testing.assert_array_equal(np.asarray(skipped.w), np.asarray(every.w))
+    for k in ("fval", "gnorm", "time", "cost"):
+        assert skipped.history[k] == every.history[k], k
+    assert skipped.history["fval"][-1] < skipped.history["fval"][0]

@@ -14,8 +14,10 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker that runs this file
 keeps it until it exits.  Keep every such compile in this one file.
 """
+import base64
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -38,7 +40,8 @@ from repro.core.sketch import (MXU_MAX_BLOCK_SIZE, CountSketch,  # noqa: E402
                                OverSketchConfig, apply_sketch)
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.count_sketch import (VMEM_BUDGET_BYTES,  # noqa: E402
-                                        pick_tiles, vmem_bytes)
+                                        VMEM_HEADROOM_BYTES, pick_tiles,
+                                        vmem_bytes)
 from repro.sketching.base import next_pow2  # noqa: E402
 
 HBM_BYTES = 16 * 2 ** 30
@@ -162,12 +165,50 @@ def _apply_sketch_compiled(sharding, width):
     return jax.jit(fn, out_shardings=out).lower(*args).compile()
 
 
+def _mxu_count_sketch_call(text):
+    """The compiled ``mxu_count_sketch`` custom call in an HLO text: its
+    operands' shapes, its scoped-VMEM request in bytes, and its Mosaic
+    body (MLIR bytecode, whose string table names the memory spaces)."""
+    line = next(ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln
+                and "mxu_count_sketch" in ln.split("=", 1)[0])
+    operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
+                         line).group(1)
+    vmem = re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                     line).group(1)
+    body = re.search(r'"body":"([^"]+)"', line).group(1)
+    return (re.findall(r"[a-z0-9]+\[[0-9,]*\]", operands), int(vmem),
+            base64.b64decode(body))
+
+
 @pytest.mark.parametrize("width", list(WIDTHS))
 def test_apply_sketch_lowers_to_the_mxu_kernel(one_chip, width):
     """Lowered for a TPU, the platform branch of apply_sketch is the MXU
-    count-sketch kernel (a Mosaic custom call), not the segment sums."""
+    count-sketch kernel (a Mosaic custom call), not the segment sums.  In
+    the Hessian program it takes the survivor mask, padded to whole block
+    groups, as an SMEM operand, and asks for the VMEM it asks for with
+    every block live."""
     compiled = _apply_sketch_compiled(one_chip, width)
     assert "tpu_custom_call" in compiled.as_text()
+    ph = WIDTHS[width]
+    n, d = ph.n, ph.d
+    k, b = ph.sketch.total_blocks, ph.sketch.block_size
+    group, tn, td = pick_tiles(k, b, n, d)
+    vmem = vmem_bytes(group, b, tn, td)
+    k_pad = k + (-k) % group
+    hessian = _jitted_sketched_hessian(
+        LogisticRegression(), sketching.get("oversketch", ph.sketch), False)
+    arg = functools.partial(_arg, one_chip)
+    text = hessian.lower(
+        arg((d,)), Dataset(arg((n, d)), arg((n,))),
+        CountSketch(h=arg((k, n), jnp.int32), sigma=arg((k, n)),
+                    block_size=b), arg((k,), jnp.bool_)).compile().as_text()
+    operands, request, body = _mxu_count_sketch_call(text)
+    assert operands == [f"s32[{k_pad}]", f"s32[{k},{n}]", f"f32[{k},{n}]",
+                        f"f32[{n},{d}]"]
+    assert b"#tpu.memory_space<smem>" in body
+    every = _mxu_count_sketch_call(compiled.as_text())
+    assert request == every[1] == vmem + vmem // 2 + VMEM_HEADROOM_BYTES
 
 
 @pytest.mark.parametrize("width", list(WIDTHS))
