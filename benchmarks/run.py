@@ -128,8 +128,8 @@ def main(argv=None) -> int:
             from repro.obs import store as obs_store
 
             # Every persisted row carries a ``path`` field naming what
-            # actually executed (fused | fused_tiled | unfused | ref |
-            # pallas) so the perf trajectory is attributable; backfill
+            # actually executed (such as ref | pallas) so the perf
+            # trajectory is attributable; backfill
             # rows from modules that predate the field.
             for r in rows:
                 r.setdefault("path", "unknown")

@@ -8,32 +8,28 @@ device from ``--seed`` (``make_logistic_dataset``, cond = 10, rows in
 sorted-margin layout, as ``data.profile_dataset`` makes paper profiles),
 and ``oversketched_newton`` solves ``LogisticRegression(lam=1e-5)`` through
 its normal path: coded gradient matvecs under the default straggler
-clock, the sketched Hessian, the Cholesky direction and the line search.
-Each phase solves twice, with the jnp sketch (``use_kernels=False``) and
-with the Pallas kernels (``use_kernels=True``), and once more with a plain
-exact-Newton reference.
+clock, the sketched Hessian (the MXU count-sketch kernel on a TPU), the
+Cholesky direction and the line search.  Each phase solves once that
+way, and once more with a plain exact-Newton reference.
 
   epsilon  d = 2000 (epsilon's published width), n = 200,000 (cut from
            400,000 to fit 16 GiB of HBM beside the product codes of X and
            X^T), n_test = 50,000; the fig7 sketch (K = 148 blocks of
-           b = 256), coded_block_rows = 256.  The kernels take the d-tiled
-           ``fused_tiled`` grid.
+           b = 256), coded_block_rows = 256.
   a9a      32,000 x 123, n_test = 16,000 (the published size); the fig8
-           sketch (K = 13 blocks of b = 128), coded_block_rows = 128.  The
-           kernels take the single-tile ``fused`` grid.
+           sketch (K = 13 blocks of b = 128), coded_block_rows = 128.
 
 Checks, each a hard failure: finite objectives ending below f(w0) = log 2;
-the kernel and jnp solves agree on the final objective (AGREE_RTOL); at
-the first iterate the kernel-path Hessian matches the ``kernels/ref.py``
+at the first iterate the solve's Hessian matches the ``kernels/ref.py``
 oracle under highest matmul precision (HESSIAN_RTOL, relative Frobenius);
-both solves end within 1% (``benchmarks.common.best_f``) of the exact-Newton
-reference; the compiled kernel-path Hessian holds a ``tpu_custom_call``
-(the kernels were compiled for the chip, not interpreted).
+the solve ends within 1% (``benchmarks.common.best_f``) of the exact-Newton
+reference; the compiled Hessian holds a ``tpu_custom_call`` (the
+count-sketch kernel was compiled for the chip, not interpreted).
 
 Every line but the last is a JSON record for information: compile and
-per-iteration wall seconds, peak device memory, the kernel path taken.
-The last line is ``{"ok": true, "device": {...}}``.  Without a TPU the
-script exits nonzero before any phase runs.
+per-iteration wall seconds, peak device memory, the sketch's
+implementation.  The last line is ``{"ok": true, "device": {...}}``.
+Without a TPU the script exits nonzero before any phase runs.
 """
 from __future__ import annotations
 
@@ -61,12 +57,7 @@ from repro.core.straggler import StragglerModel  # noqa: E402
 from repro.data.synthetic import make_logistic_dataset  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 
-# The two solves share the sketch draws and survivor masks; they differ
-# only in how the Gram is summed (fused kernel vs segment-sum + einsum).
-# After the solve has converged that moves the final objective by float32
-# rounding, far below this bound.
-AGREE_RTOL = 1e-4
-# Kernel vs oracle Hessian, both at highest precision: float32 sums of
+# Solve vs oracle Hessian, both at highest precision: float32 sums of
 # n rows in two different orders, expected near 1e-6.
 HESSIAN_RTOL = 1e-4
 # OSN's final objective against the exact-Newton reference (best_f).
@@ -81,17 +72,16 @@ class Phase:
     n_test: int
     sketch: OverSketchConfig
     coded_block_rows: int
-    path: str            # the fused grid the kernel solve must take
     iters: int = 8
 
 
 PHASES = (
     Phase("epsilon", 200_000, 2000, 50_000,
           OverSketchConfig(((15 * 2000) // 256 + 1) * 256, 256, 0.25),
-          256, "fused_tiled"),
+          256),
     Phase("a9a", 32_000, 123, 16_000,
           OverSketchConfig(((10 * 123) // 128 + 1) * 128, 128, 0.25),
-          128, "fused"),
+          128),
 )
 
 
@@ -137,10 +127,10 @@ def _ref_hessian(obj, w, data, state, survivors):
 
 
 def run_phase(ph: Phase, seed: int) -> dict:
-    """Solve one phase on the default device with both sketch paths and the
-    reference; raise AssertionError on a failed check.  Returns the
-    readings, ``custom_call`` among them (whether the compiled kernel-path
-    Hessian holds a Mosaic kernel)."""
+    """Solve one phase on the default device and with the reference; raise
+    AssertionError on a failed check.  Returns the readings,
+    ``custom_call`` among them (whether the compiled Hessian holds a
+    Mosaic kernel)."""
     key = jax.random.PRNGKey(seed)
     t0 = time.perf_counter()
     data = make_logistic_dataset(key, ph.n, ph.d, ph.n_test, cond=10.0,
@@ -152,57 +142,46 @@ def run_phase(ph: Phase, seed: int) -> dict:
            "data_s": time.perf_counter() - t0}
     obj = LogisticRegression(lam=1e-5)
     fam = sketching.get("oversketch", ph.sketch)
-    out["kernel_path"] = fam.fused_path(ph.d)
-    _check(out["kernel_path"] == ph.path,
-           f"{ph.name}: kernel path {out['kernel_path']} != {ph.path}")
+    out["sketch_impl"] = fam.apply_path(jax.devices()[0].platform)
 
-    finals = {}
-    w1 = None
-    for use_kernels, tag in ((False, "jnp"), (True, "kernel")):
-        cfg = NewtonConfig(iters=ph.iters, sketch=ph.sketch,
-                           coded_block_rows=ph.coded_block_rows,
-                           use_kernels=use_kernels, seed=seed)
-        # One iteration first: it compiles every program the solve runs
-        # and gives the first iterate; the full solve then runs warm.
-        first, warm_s = _solve(obj, data, dataclasses.replace(cfg, iters=1),
-                               StragglerModel())
-        res, wall_s = _solve(obj, data, cfg, StragglerModel())
-        w1 = first.w if w1 is None else w1
-        f = np.asarray(res.history["fval"], np.float64)
-        out[f"{tag}_fvals"] = f.tolist()
-        out[f"{tag}_compile_s"] = warm_s - wall_s / ph.iters
-        out[f"{tag}_s_per_iter"] = wall_s / ph.iters
-        out[f"{tag}_peak_bytes"] = _peak_bytes()
-        _check(bool(np.isfinite(f).all()), f"{ph.name}/{tag}: f not finite")
-        _check(f[-1] < math.log(2.0),
-               f"{ph.name}/{tag}: final f {f[-1]} >= f(w0) = log 2")
-        finals[tag] = res.history
-        del first, res
+    cfg = NewtonConfig(iters=ph.iters, sketch=ph.sketch,
+                       coded_block_rows=ph.coded_block_rows, seed=seed)
+    # One iteration first: it compiles every program the solve runs and
+    # gives the first iterate; the full solve then runs warm.
+    first, warm_s = _solve(obj, data, dataclasses.replace(cfg, iters=1),
+                           StragglerModel())
+    res, wall_s = _solve(obj, data, cfg, StragglerModel())
+    w1 = first.w
+    f = np.asarray(res.history["fval"], np.float64)
+    out["fvals"] = f.tolist()
+    out["compile_s"] = warm_s - wall_s / ph.iters
+    out["s_per_iter"] = wall_s / ph.iters
+    out["solve_peak_bytes"] = _peak_bytes()
+    _check(bool(np.isfinite(f).all()), f"{ph.name}: f not finite")
+    _check(f[-1] < math.log(2.0),
+           f"{ph.name}: final f {f[-1]} >= f(w0) = log 2")
+    history = res.history
+    del first, res
 
-    fj, fk = finals["jnp"]["fval"][-1], finals["kernel"]["fval"][-1]
-    out["agree_rel"] = abs(fk - fj) / abs(fj)
-    _check(out["agree_rel"] <= AGREE_RTOL,
-           f"{ph.name}: kernel vs jnp final f {fk} vs {fj}")
-
-    # Kernel-path Hessian at the first iterate against the oracle, with
+    # The solve's Hessian at the first iterate against the oracle, with
     # the redundant blocks dropped as stragglers.
     state = fam.sample(jax.random.fold_in(key, 7), ph.n)
     survivors = jnp.arange(ph.sketch.total_blocks) < ph.sketch.num_blocks
-    hess = _jitted_sketched_hessian(obj, fam, True)
+    hess = _jitted_sketched_hessian(obj, fam)
     out["custom_call"] = "tpu_custom_call" in hess.lower(
         w1, data, state, survivors).compile().as_text()
     with jax.default_matmul_precision("highest"):
-        h_ker = hess(w1, data, state, survivors)
+        h_osn = hess(w1, data, state, survivors)
         h_ref = jax.jit(_ref_hessian, static_argnums=0)(obj, w1, data, state,
                                                         survivors)
     h_def = hess(w1, data, state, survivors)       # default precision
     ref_norm = jnp.linalg.norm(h_ref)
-    out["hessian_rel"] = float(jnp.linalg.norm(h_ker - h_ref) / ref_norm)
+    out["hessian_rel"] = float(jnp.linalg.norm(h_osn - h_ref) / ref_norm)
     out["hessian_rel_default_precision"] = float(
         jnp.linalg.norm(h_def - h_ref) / ref_norm)
     _check(out["hessian_rel"] <= HESSIAN_RTOL,
-           f"{ph.name}: kernel Hessian rel err {out['hessian_rel']}")
-    del h_ker, h_ref, h_def
+           f"{ph.name}: Hessian rel err {out['hessian_rel']}")
+    del h_osn, h_ref, h_def
 
     # Plain exact Newton (full Hessian, exact gradient, no straggler
     # clock) at highest precision, for the same iterations.
@@ -212,13 +191,11 @@ def run_phase(ph: Phase, seed: int) -> dict:
     f_ref = ref_res.history["fval"]
     out["ref_fvals"] = list(f_ref)
     out["ref_wall_s"] = ref_s
-    for tag in ("jnp", "kernel"):
-        target = best_f(ref_res.history, finals[tag], rel=REF_REL)
-        f_osn = finals[tag]["fval"][-1]
-        out[f"{tag}_vs_ref_rel"] = (f_osn - f_ref[-1]) / abs(f_ref[-1])
-        _check(f_osn <= target,
-               f"{ph.name}/{tag}: final f {f_osn} not within 1% of the "
-               f"exact-Newton reference {f_ref[-1]}")
+    target = best_f(ref_res.history, history, rel=REF_REL)
+    out["vs_ref_rel"] = (f[-1] - f_ref[-1]) / abs(f_ref[-1])
+    _check(f[-1] <= target,
+           f"{ph.name}: final f {f[-1]} not within 1% of the exact-Newton "
+           f"reference {f_ref[-1]}")
     out["peak_bytes"] = _peak_bytes()
     return out
 
@@ -240,7 +217,7 @@ def main(argv=None) -> int:
     for ph in PHASES:
         out = run_phase(ph, args.seed)
         _check(out["custom_call"],
-               f"{ph.name}: no tpu_custom_call in the kernel-path Hessian")
+               f"{ph.name}: no tpu_custom_call in the Hessian")
         emit(out)
     emit({"ok": True, "device": device})
     return 0

@@ -199,6 +199,31 @@ def peel_decode(products: jax.Array, known: jax.Array,
     return vals[:g, :g], success
 
 
+@jax.jit
+def parity_residuals(products: jax.Array, known: jax.Array):
+    """Per-line parity-check residuals of a coded product grid.
+
+    products: ((g+1), (g+1), b) block products (erased cells arbitrary);
+    known: ((g+1), (g+1)) bool arrival mask.  Every row and column of the
+    extended grid satisfies sum(systematic) - parity = 0, so over known
+    cells the signed line sums are exact-zero residual vectors unless a
+    known cell's value is corrupted.  Returns ``(row_res, row_mag,
+    col_res, col_mag)``: the L2 residual of each line's constraint and
+    the L2 magnitude of the line's known values (the relative-tolerance
+    scale).  Unknown cells contribute zero to both, so the caller must
+    gate on line completeness — a line with a missing cell has no
+    checkable constraint.
+    """
+    n = products.shape[0]
+    sgn = jnp.where(jnp.arange(n) == n - 1, -1.0, 1.0)
+    vals = jnp.where(known[..., None], products, 0.0).astype(jnp.float32)
+    row_res = jnp.linalg.norm(jnp.einsum("c,rcb->rb", sgn, vals), axis=-1)
+    col_res = jnp.linalg.norm(jnp.einsum("r,rcb->cb", sgn, vals), axis=-1)
+    row_mag = jnp.sqrt((vals ** 2).sum(axis=(1, 2)))
+    col_mag = jnp.sqrt((vals ** 2).sum(axis=(0, 2)))
+    return row_res, row_mag, col_res, col_mag
+
+
 def decode_matvec(products: jax.Array, known: jax.Array, code: ProductCode,
                   out_rows: int) -> Tuple[jax.Array, jax.Array]:
     """Full decode back to y = A @ x of length out_rows."""
@@ -225,7 +250,6 @@ def detect_corrupted(products: jax.Array, known: jax.Array,
     Returns a ((g+1), (g+1)) bool grid of cells to demote to erasures,
     feeding the existing ``peel_decode`` path unchanged.
     """
-    from repro.kernels.coded_matvec import parity_residuals  # lazy: layering
     del code  # geometry is carried by the grid shape itself
     row_res, row_mag, col_res, col_mag = parity_residuals(products, known)
     full_rows = known.all(axis=1)

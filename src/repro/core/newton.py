@@ -112,7 +112,6 @@ class NewtonConfig:
     # fleet-wide 3 GB.  Off by default to keep historical dollar totals.
     phase_memory: bool = False
     seed: int = 0
-    use_kernels: bool = False       # route sketch through repro.kernels ops
     track_test_error: bool = False
     # Paper Thm 3.2 remark: "the sketch dimension can be increased to reduce
     # eps ... and improve the convergence rate in practice" — when iteration
@@ -430,40 +429,31 @@ def _solve_direction(objective, h_hat: jax.Array, g: jax.Array,
 
 
 @functools.lru_cache(maxsize=64)
-def _jitted_sketched_hessian(objective, family: "sketching.SketchFamily",
-                             use_kernels: bool):
+def _jitted_sketched_hessian(objective, family: "sketching.SketchFamily"):
     """Hashable frozen-dataclass objectives AND families => cacheable
     jitted closures.  ``state`` is the family's sketch realization pytree.
 
-    With ``use_kernels`` the Hessian build prefers the family's fused
-    streaming sketch->Gram kernel (``SketchFamily.gram_fused``: one pass
-    over hess_sqrt rows, A_tilde never materialized in HBM).  The kernel
-    d-tiles its output grid, so oversketch/srht/sjlt take the fused path
-    for EVERY d (``SketchFamily.fused_path(d)`` reports "fused" vs
-    "fused_tiled"); families without an encode-matrix form fall back to
-    the two-kernel apply+gram chain ("unfused").
-
-    The path actually taken is logged as a telemetry metric
-    (``kernel.path.<path>``: ``fused``/``fused_tiled`` with
-    ``use_kernels``, else ``SketchFamily.apply_path`` of the data's
-    platform: ``mxu_count_sketch`` or ``segment_sum`` for the OverSketch
-    family, ``unfused`` for the others) at this function's call site in
-    ``_hessian_phase`` — inside the jitted closure there is no Python
-    left to log from.  On ``mxu_count_sketch`` the same site counts the
-    dropped blocks the kernel skips
-    (``kernel.count_sketch.blocks_skipped``)."""
+    The Hessian is ``SketchFamily.gram`` of the objective's square root:
+    the family's apply, then the masked Gram.  The implementation the
+    apply lowers to is ``SketchFamily.apply_path`` of the data's platform
+    (``mxu_count_sketch`` or ``segment_sum`` for the OverSketch family,
+    ``unfused`` for the others); ``_hessian_phase`` counts it as the
+    telemetry metric ``kernel.path.<path>`` at this function's call site,
+    since inside the jitted closure there is no Python left to log from.
+    On ``mxu_count_sketch`` the same site counts the dropped blocks the
+    kernel skips (``kernel.count_sketch.blocks_skipped``)."""
     def fn(w, data, state, survivors):
         with jax.named_scope(wall.HESS_SQRT):
             a = objective.hess_sqrt(w, data)
         d = a.shape[1]
         reg = objective.hess_reg * jnp.eye(d, dtype=a.dtype)
-        return family.gram(state, a, survivors, use_kernels=use_kernels) + reg
+        return family.gram(state, a, survivors) + reg
     return jax.jit(fn)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_distavg_direction(objective, family: "sketching.SketchFamily",
-                              debias: bool, use_kernels: bool,
+                              debias: bool,
                               solver: str = "chol", cg_iters: int = 64):
     """distributed-avg mode (Bartan-Pilanci 2020): every surviving block-
     worker solves its own per-block sketched system, the master averages
@@ -488,7 +478,7 @@ def _jitted_distavg_direction(objective, family: "sketching.SketchFamily",
             a = objective.hess_sqrt(w, data)
         d = a.shape[1]
         with jax.named_scope(wall.SKETCH):
-            a_t = family.apply(state, a, use_kernels=use_kernels)  # (K,b,d)
+            a_t = family.apply(state, a)  # (K,b,d)
         eye = jnp.eye(d, dtype=a_t.dtype)
         with jax.named_scope(wall.GRAM):
             grams = jnp.einsum("kbd,kbe->kde", a_t, a_t) \
@@ -622,14 +612,11 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
         state = fam.sample(jax.random.fold_in(key, 7), n_rows)
         tel = _telemetry(clock)
         if tel.enabled:
-            # Audit trail for kernel auto-routing: the path the sketch
-            # ACTUALLY takes for this (family, d) — the fused sketch->Gram
-            # grid, or the apply that the platform of the data selects —
-            # instead of assumed from the config.
-            path = (fam.fused_path(d) if cfg.use_kernels
-                    else fam.apply_path(_platform(data.x)))
+            # Audit trail for the sketch's routing: the apply that the
+            # platform of the data selects, not one assumed from the config.
+            path = fam.apply_path(_platform(data.x))
             tel.metrics.counter(f"kernel.path.{path}").inc()
-        fn = _jitted_sketched_hessian(objective, fam, cfg.use_kernels)
+        fn = _jitted_sketched_hessian(objective, fam)
         h_hat = fn(w, data, state, survivors)
         # Queued behind the Hessian program, so this read waits for it.
         n_surv = jnp.sum(survivors)
@@ -758,8 +745,7 @@ def _distavg_direction_phase(objective, data: Dataset, w: jax.Array,
                 survivors = jnp.asarray(e.mask)
     state = fam.sample(jax.random.fold_in(key, 7), n_rows)
     fn = _jitted_distavg_direction(objective, fam, cfg.debias,
-                                   cfg.use_kernels, cfg.distavg_solver,
-                                   cfg.cg_iters)
+                                   cfg.distavg_solver, cfg.cg_iters)
     return fn(w, data, g, state, survivors)
 
 

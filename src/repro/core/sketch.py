@@ -192,8 +192,7 @@ def apply_sketch_chunked(cs: CountSketch, a_fn: Callable[[int], jax.Array],
 
 
 def sketched_gram(a_tilde: jax.Array,
-                  survivors: Optional[jax.Array] = None, *,
-                  use_kernels: bool = False) -> jax.Array:
+                  survivors: Optional[jax.Array] = None) -> jax.Array:
     """H_hat = (1/N_avail) sum_{i in survivors} A_tilde_i^T A_tilde_i.
 
     a_tilde:   (total_blocks, b, d) sketched square root blocks.
@@ -201,14 +200,9 @@ def sketched_gram(a_tilde: jax.Array,
 
     Dropping a block and rescaling keeps the estimator unbiased — this is the
     paper's "over"-sketching straggler resiliency, done as a masked reduction.
-    ``use_kernels`` routes the reduction through the Pallas masked-Gram
-    kernel (MXU tiles, straggler mask applied inside the accumulation).
     """
     if survivors is None:
         survivors = jnp.ones((a_tilde.shape[0],), dtype=bool)
-    if use_kernels:
-        from repro.kernels import ops as kops
-        return kops.oversketch_gram(a_tilde, survivors)
     m = survivors.astype(a_tilde.dtype)
     n_avail = jnp.maximum(m.sum(), 1.0)
     grams = jnp.einsum("kbd,kbe->kde", a_tilde, a_tilde)
@@ -216,23 +210,9 @@ def sketched_gram(a_tilde: jax.Array,
 
 
 def oversketched_gram(key: jax.Array, a: jax.Array, cfg: OverSketchConfig,
-                      survivors: Optional[jax.Array] = None, *,
-                      use_kernels: bool = False) -> jax.Array:
-    """One-shot H_hat ~= A^T A with straggler resiliency (single device).
-
-    ``use_kernels`` takes the fused streaming pipeline
-    (``kernels.sketch_gram``): row-panels of A are sketched block-locally
-    and the masked Gram accumulates in VMEM — A_tilde never hits HBM.
-    The kernel's output grid is d-tiled, so the fused path runs for every
-    d (``pick_d_tile`` sizes the resident tile to the VMEM budget).
-    """
+                      survivors: Optional[jax.Array] = None) -> jax.Array:
+    """One-shot H_hat ~= A^T A with straggler resiliency (single device)."""
     cs = sample_countsketch(key, a.shape[0], cfg)
-    if use_kernels:
-        from repro.kernels import ops as kops
-        if survivors is None:
-            survivors = jnp.ones((cs.total_blocks,), dtype=bool)
-        return kops.sketch_gram_count(cs.h, cs.sigma, a,
-                                      cfg.block_size, survivors)
     return sketched_gram(apply_sketch(cs, a), survivors)
 
 

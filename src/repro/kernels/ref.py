@@ -1,4 +1,6 @@
-"""Pure-jnp oracles for the Pallas kernels (the correctness ground truth)."""
+"""Pure-jnp oracles (the correctness ground truth) for the count-sketch
+kernel and the sketch families' Grams; ``fwht`` is also the Hadamard mix
+the SRHT family runs."""
 from __future__ import annotations
 
 import jax
@@ -54,8 +56,8 @@ def oversketch_gram(a_tilde: jax.Array, survivors: jax.Array) -> jax.Array:
 def fwht(x: jax.Array) -> jax.Array:
     """Orthonormal Walsh-Hadamard transform along axis 1 of (K, n, d).
 
-    Radix-2 butterfly (Sylvester / natural ordering): the oracle for the
-    blocked Kronecker-matmul Pallas kernel.  n must be a power of two.
+    Radix-2 butterfly (Sylvester / natural ordering).  n must be a power
+    of two.
     """
     k, n, d = x.shape
     if n & (n - 1):
@@ -74,9 +76,9 @@ def fwht(x: jax.Array) -> jax.Array:
 
 
 def srht_apply(rows: jax.Array, sigma: jax.Array, a: jax.Array) -> jax.Array:
-    """Blocked SRHT apply, the unfused oracle: sign, zero-pad to n_pad =
-    next power of two, orthonormal FWHT, gather the b sampled rows, scale
-    by sqrt(n_pad/b).
+    """Blocked SRHT apply, the oracle: sign, zero-pad to n_pad = next
+    power of two, orthonormal FWHT, gather the b sampled rows, scale by
+    sqrt(n_pad/b).
 
     rows: (K, b) int32 sampled Hadamard-row indices in [0, n_pad)
     sigma: (K, n) Rademacher signs
@@ -99,28 +101,19 @@ def srht_apply(rows: jax.Array, sigma: jax.Array, a: jax.Array) -> jax.Array:
 
 def sketch_gram_count(h: jax.Array, sigma: jax.Array, a: jax.Array,
                       block_size: int, survivors: jax.Array) -> jax.Array:
-    """Unfused apply+gram composition: the fused count-sketch oracle."""
+    """Apply then masked Gram: the count-sketch family's Gram oracle."""
     return oversketch_gram(count_sketch_apply(h, sigma, a, block_size),
                            survivors)
 
 
 def sketch_gram_srht(rows: jax.Array, sigma: jax.Array, a: jax.Array,
                      survivors: jax.Array) -> jax.Array:
-    """Unfused apply+gram composition: the fused SRHT oracle."""
+    """Apply then masked Gram: the SRHT family's Gram oracle."""
     return oversketch_gram(srht_apply(rows, sigma, a), survivors)
 
 
 def sketch_gram_sjlt(h: jax.Array, sigma: jax.Array, a: jax.Array,
                      block_size: int, survivors: jax.Array) -> jax.Array:
-    """Unfused apply+gram composition: the fused SJLT oracle."""
+    """Apply then masked Gram: the SJLT family's Gram oracle."""
     return oversketch_gram(sjlt_apply(h, sigma, a, block_size), survivors)
 
-
-def coded_block_matvec(enc: jax.Array, x: jax.Array,
-                       erased: jax.Array) -> jax.Array:
-    """Per-worker block products with straggler masking.
-
-    enc: (W, b, s) coded row-blocks; x: (s,); erased: (W,) bool -> (W, b)
-    """
-    prods = jnp.einsum("wbs,s->wb", enc, x)
-    return jnp.where(erased[:, None], 0.0, prods)

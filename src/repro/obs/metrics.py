@@ -19,7 +19,7 @@ returns one shared no-op instance.  Like the tracer, the registry draws no
 randomness and reads no clock, so attaching it never perturbs a run.
 
 Metric names are dotted paths (``fleet.cold_starts``, ``phase.dollars``,
-``kernel.path.fused_tiled``); ``snapshot()`` returns them sorted, so the
+``kernel.path.mxu_count_sketch``); ``snapshot()`` returns them sorted, so the
 JSONL export is deterministic.
 
 A registry optionally carries a ``listener`` — anything with an

@@ -15,7 +15,7 @@ one history and can be queried back out.  Two record kinds:
     the metrics snapshot, per-phase time/dollar aggregates, per-iteration
     critical-path stats, straggler completion-tail quantiles
     (p50/p95/p99, exact — the registry keeps full samples), survivor
-    counts per sketch round, health-monitor alerts, and the fused-Gram
+    counts per sketch round, health-monitor alerts, and the sketch
     paths taken (``kernel.path.*``).  The survivor table is what the
     ROADMAP's analytic launch planner needs: PAST iterations' survivor
     statistics.
@@ -30,7 +30,7 @@ CLI (used by CI to maintain the bench history artifact)::
         --store artifacts/bench_history.jsonl
     python -m repro.obs.store show --store artifacts/bench_history.jsonl
     python -m repro.obs.store history --store ... --name kernels_bench \\
-        --row fused_gram_oversketch
+        --row kernel_count_sketch_ref
 
 ``repro.obs.diff`` consumes the same store for history-aware regression
 gating.
@@ -244,10 +244,8 @@ class Store:
 
     def kernel_path_table(self, name: str = "kernels_bench", **filters
                           ) -> Dict[str, dict]:
-        """Latest measured per-row timings ``{row: {us, path}}`` — the
-        persisted table the kernel auto-router consults instead of
-        assuming the fused path always wins (ROADMAP: measured kernel
-        auto-routing)."""
+        """Latest measured per-row timings ``{row: {us, path}}`` of a
+        bench module's rows."""
         rec = self.latest(kind="bench", name=name, **filters)
         if rec is None:
             return {}

@@ -38,7 +38,7 @@ framework op name, ``jit(fn)/osn_sketch/while/...``):
 
 ==================  ==========================================================
 ``osn_hess_sqrt``   ``objective.hess_sqrt`` inside the Hessian programs
-``osn_sketch``      the sketch apply, or the fused sketch->Gram kernel
+``osn_sketch``      the sketch apply
 ``osn_gram``        the survivors' Gram of the sketched blocks
 ==================  ==========================================================
 """

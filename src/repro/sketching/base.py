@@ -14,17 +14,11 @@ This module defines the protocol every family implements:
   apply(state, a)       -> (total_blocks, b, d) per-block  S_i^T A
   apply_live(state, a, survivors) -> the same, what ``gram`` calls; a
       family may zero (and skip) the blocks the mask drops
-  gram(state, a, survivors) -> (d, d) masked, rescaled Gram estimate
-  gram_fused(state, a, survivors) -> (d, d) or None — optional fused
-      sketch->Gram Pallas path (A_tilde never materialized); the kernel's
-      d-tiled output grid means a family that has one takes it for ANY d.
-      Families without an encode-matrix form return None and ``gram``
-      falls back to apply+gram
-  fused_path(d)         -> str       which gram path use_kernels takes:
-      "fused" | "fused_tiled" | "unfused" (benchmark/bookkeeping hook)
-  apply_path(platform)  -> str       which apply use_kernels=False lowers
+  gram(state, a, survivors) -> (d, d) masked, rescaled Gram estimate:
+      ``apply_live`` then ``core.sketch.sketched_gram``
+  apply_path(platform)  -> str       which implementation ``apply`` lowers
       to on a platform (the OverSketch family's selects by platform and
-      block size)
+      block size; the others have one jnp form, "unfused")
   block_flops(num_rows, d) -> float  per-worker cost for the straggler clock
   comm_units(d)         -> float     per-worker master-I/O units
 
@@ -44,7 +38,6 @@ import dataclasses
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 
 import repro.core.sketch as core_sketch
 from repro.core.sketch import OverSketchConfig
@@ -73,79 +66,37 @@ class SketchFamily(abc.ABC):
         Newton iteration, like the paper's per-iteration sketch)."""
 
     @abc.abstractmethod
-    def apply(self, state: SketchState, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: SketchState, a: jax.Array) -> jax.Array:
         """Per-block application A (n, d) -> (total_blocks, b, d), unscaled
         by 1/sqrt(N) (the survivor rescale in ``gram`` absorbs it)."""
 
     def apply_live(self, state: SketchState, a: jax.Array,
-                   survivors: Optional[jax.Array],
-                   use_kernels: bool = False) -> jax.Array:
+                   survivors: Optional[jax.Array]) -> jax.Array:
         """``apply`` for a Gram over ``survivors`` (None = every block).  A
         family may leave the blocks the mask drops unsketched, reading 0,
         since the Gram weighs them by 0; the default sketches them all."""
-        return self.apply(state, a, use_kernels=use_kernels)
-
-    # Families with a block-local encode-matrix form set this True (and
-    # override gram_fused); it drives fused_path reporting.
-    has_fused_gram = False
-
-    def gram_fused(self, state: SketchState, a: jax.Array,
-                   survivors: jax.Array) -> Optional[jax.Array]:
-        """Fused streaming sketch->Gram (``kernels/sketch_gram.py``): the
-        per-block panels ``A_tilde_i`` stay in VMEM and never round-trip
-        through HBM.  The kernel tiles its output grid on d, so there is
-        no VMEM decline path — a family that overrides this takes the
-        fused kernel for every d.  Families without a block-local
-        encode-matrix form (count-sketch scatter, SJLT layers, SRHT mix)
-        keep the default None and ``gram`` routes through the two-kernel
-        apply+gram fallback."""
-        return None
-
-    def fused_path(self, d: int) -> str:
-        """Which path ``gram(use_kernels=True)`` takes for width d:
-        ``"fused"`` (single resident output tile), ``"fused_tiled"``
-        (d-tiled (d_i, d_j) grid) or ``"unfused"`` (apply+gram pair).
-        Pure bookkeeping — benchmarks record it so perf rows are
-        attributable to the grid that actually ran."""
-        if not self.has_fused_gram:
-            return "unfused"
-        from repro.kernels.sketch_gram import fused_path as _fused_path
-        return _fused_path(self.cfg.block_size, d)
+        return self.apply(state, a)
 
     def apply_path(self, platform: str) -> str:
-        """Which implementation ``apply(use_kernels=False)`` takes when
-        lowered for ``platform``: ``"unfused"`` (the family's jnp form)
-        unless the family selects by platform and block size."""
+        """Which implementation ``apply`` takes when lowered for
+        ``platform``: ``"unfused"`` (the family's jnp form) unless the
+        family selects by platform and block size."""
         return "unfused"
 
     def gram(self, state: SketchState, a: jax.Array,
-             survivors: Optional[jax.Array] = None,
-             use_kernels: bool = False) -> jax.Array:
+             survivors: Optional[jax.Array] = None) -> jax.Array:
         """Masked H_hat = (1/N_avail) sum_i A_tilde_i^T A_tilde_i.
 
         Shared across families: per-block unbiasedness (E[S_i S_i^T] = I)
-        makes dropping blocks + rescaling exact for every family.  On the
-        kernel path the fused single-pass pipeline is preferred whenever
-        the family provides one.
+        makes dropping blocks + rescaling exact for every family.
 
         The sketch runs under the device scope ``osn_sketch`` and the Gram
-        under ``osn_gram`` (``repro.obs.wall``); the fused kernel does both
-        in one pass, so all of it falls under ``osn_sketch``.
+        under ``osn_gram`` (``repro.obs.wall``).
         """
-        if use_kernels:
-            if survivors is None:
-                survivors = jnp.ones((self.cfg.total_blocks,), bool)
-            with jax.named_scope(wall.SKETCH):
-                fused = self.gram_fused(state, a, survivors)
-            if fused is not None:
-                return fused
         with jax.named_scope(wall.SKETCH):
-            a_t = self.apply_live(state, a, survivors,
-                                  use_kernels=use_kernels)
+            a_t = self.apply_live(state, a, survivors)
         with jax.named_scope(wall.GRAM):
-            return core_sketch.sketched_gram(a_t, survivors,
-                                             use_kernels=use_kernels)
+            return core_sketch.sketched_gram(a_t, survivors)
 
     # ------------------------------------------------------------------ cost
     # Hooks for the straggler SimClock: per-worker flops and master-I/O for

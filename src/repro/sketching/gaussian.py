@@ -31,8 +31,7 @@ class GaussianFamily(SketchFamily):
     def sample(self, key: jax.Array, num_rows: int) -> dict:
         return {"keys": jax.random.split(key, self.cfg.total_blocks)}
 
-    def apply(self, state: dict, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: dict, a: jax.Array) -> jax.Array:
         n = a.shape[0]
         b = self.cfg.block_size
         inv_sqrt_b = 1.0 / jnp.sqrt(jnp.asarray(float(b), a.dtype))

@@ -41,8 +41,7 @@ class LeverageFamily(SketchFamily):
         # defer the draw, keep the key (one realization per iteration).
         return {"key": key}
 
-    def apply(self, state: dict, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: dict, a: jax.Array) -> jax.Array:
         n, d = a.shape
         q, _ = jnp.linalg.qr(a)                      # thin QR, (n, d)
         lev = jnp.sum(q * q, axis=1)                 # leverage scores, sum=d

@@ -29,8 +29,7 @@ class NystromFamily(SketchFamily):
             dtype=jnp.int32)
         return {"rows": rows}
 
-    def apply(self, state: dict, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: dict, a: jax.Array) -> jax.Array:
         n = a.shape[0]
         scale = jnp.sqrt(jnp.asarray(n / self.cfg.block_size, a.dtype))
         return jax.vmap(lambda r: a[r])(state["rows"]) * scale

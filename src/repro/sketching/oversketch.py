@@ -2,10 +2,9 @@
 
 This is the seed implementation from ``repro.core.sketch`` migrated behind
 the ``SketchFamily`` protocol; ``repro.core`` re-exports are untouched, and
-``apply`` without kernels is ``core.sketch.apply_sketch``, which picks its
-implementation by platform and block size (``apply_path``); ``gram``
-passes it the survivor mask (``apply_live``), so dropped blocks are not
-sketched.  Per-block unbiasedness E[S_i S_i^T] = I is the Count-Sketch
+``apply`` is ``core.sketch.apply_sketch``, which picks its implementation
+by platform and block size (``apply_path``); ``gram`` passes it the
+survivor mask (``apply_live``), so dropped blocks are not sketched.  Per-block unbiasedness E[S_i S_i^T] = I is the Count-Sketch
 property the paper's Lemma 6.1 builds on.
 
 Cost model: sketching is folded into the coded matmul workers (paper
@@ -27,34 +26,19 @@ from repro.sketching.registry import register
 @dataclasses.dataclass(frozen=True)
 class OverSketchFamily(SketchFamily):
 
-    has_fused_gram = True
-
     def sample(self, key: jax.Array, num_rows: int) -> core_sketch.CountSketch:
         return core_sketch.sample_countsketch(key, num_rows, self.cfg)
 
-    def apply(self, state: core_sketch.CountSketch, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
-        if use_kernels:
-            from repro.kernels import ops as kops
-            return kops.count_sketch_apply(state.h, state.sigma, a,
-                                           self.cfg.block_size)
+    def apply(self, state: core_sketch.CountSketch,
+              a: jax.Array) -> jax.Array:
         return core_sketch.apply_sketch(state, a)
 
     def apply_live(self, state: core_sketch.CountSketch, a: jax.Array,
-                   survivors, use_kernels: bool = False) -> jax.Array:
-        # Reached only without kernels (gram_fused answers with them).  The
-        # apply skips the blocks the straggler mask drops (on a TPU the MXU
-        # kernel does no matmul for them): the Gram weighs them by 0, so
-        # H_hat is what every block's sketch gives.
+                   survivors) -> jax.Array:
+        # The apply skips the blocks the straggler mask drops (on a TPU the
+        # MXU kernel does no matmul for them): the Gram weighs them by 0,
+        # so H_hat is what every block's sketch gives.
         return core_sketch.apply_sketch(state, a, survivors)
 
     def apply_path(self, platform: str) -> str:
         return core_sketch.sketch_impl(platform, self.cfg.block_size)
-
-    def gram_fused(self, state: core_sketch.CountSketch, a: jax.Array,
-                   survivors: jax.Array):
-        # The kernel d-tiles its output grid, so the fused path runs for
-        # every d (pick_d_tile sizes the tile to the VMEM budget).
-        from repro.kernels import ops as kops
-        return kops.sketch_gram_count(state.h, state.sigma, a,
-                                      self.cfg.block_size, survivors)

@@ -25,7 +25,6 @@ from repro.sketching.registry import register
 class SJLTFamily(SketchFamily):
 
     nnz_per_row: int = 4
-    has_fused_gram = True
 
     def sample(self, key: jax.Array, num_rows: int) -> dict:
         kh, ks = jax.random.split(key)
@@ -35,39 +34,16 @@ class SJLTFamily(SketchFamily):
         sigma = jax.random.rademacher(ks, shape, dtype=jnp.float32)
         return {"h": h, "sigma": sigma}
 
-    def apply(self, state: dict, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: dict, a: jax.Array) -> jax.Array:
         b = self.cfg.block_size
-        if use_kernels:
-            # Flatten the slot axis into extra blocks for the count-sketch
-            # MXU kernel, then reduce the s slot outputs per block.
-            from repro.kernels import ops as kops
-            k, s, n = state["h"].shape
-            flat = kops.count_sketch_apply(state["h"].reshape(k * s, n),
-                                           state["sigma"].reshape(k * s, n),
-                                           a, b)
-            out = flat.reshape(k, s, b, -1).sum(axis=1)
-        else:
-            def one_block(h_b, s_b):
-                slots = jax.vmap(
-                    lambda h, s: core_sketch.apply_block(h, s, b, a))(h_b, s_b)
-                return slots.sum(axis=0)
-            out = jax.vmap(one_block)(state["h"], state["sigma"])
+
+        def one_block(h_b, s_b):
+            slots = jax.vmap(
+                lambda h, s: core_sketch.apply_block(h, s, b, a))(h_b, s_b)
+            return slots.sum(axis=0)
+
+        out = jax.vmap(one_block)(state["h"], state["sigma"])
         return out / jnp.sqrt(jnp.asarray(float(self.nnz_per_row), out.dtype))
-
-    def gram_fused(self, state: dict, a: jax.Array,
-                   survivors: jax.Array):
-        # Encode-matrix form: the s signed one-hot layers are summed into
-        # a (tile_n, b) matrix in VMEM (count-sketch is the s = 1 slice of
-        # the same encoder), so SJLT rides the same fused streaming kernel
-        # as oversketch/srht — A_tilde never reaches HBM.
-        from repro.kernels import ops as kops
-        return kops.sketch_gram_sjlt(state["h"], state["sigma"], a,
-                                     self.cfg.block_size, survivors)
-
-    def fused_path(self, d: int) -> str:
-        from repro.kernels.sketch_gram import fused_path as _fused_path
-        return _fused_path(self.cfg.block_size, d, nnz=self.nnz_per_row)
 
     def apply_flops(self, num_rows: int, d: int) -> float:
         return 2.0 * self.nnz_per_row * num_rows * d

@@ -9,9 +9,7 @@ zero-padded embedding, and E[P_i^T P_i] = (b/n_pad) I, so
 needs.  Tighter embedding constants than Count-Sketch at equal m (Tropp
 2011), at an O(n log n) mixing cost per block.
 
-The Hadamard mix routes through the blocked Kronecker MXU kernel in
-``repro.kernels.srht`` when ``use_kernels=True``; the pure-jnp butterfly in
-``repro.kernels.ref`` is the oracle path.
+The Hadamard mix is the radix-2 butterfly ``repro.kernels.ref.fwht``.
 """
 from __future__ import annotations
 
@@ -21,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import fwht
 from repro.sketching.base import SketchFamily, next_pow2
 from repro.sketching.registry import register
 
@@ -28,8 +27,6 @@ from repro.sketching.registry import register
 @register("srht")
 @dataclasses.dataclass(frozen=True)
 class SRHTFamily(SketchFamily):
-
-    has_fused_gram = True
 
     def sample(self, key: jax.Array, num_rows: int) -> dict:
         ks, kp = jax.random.split(key)
@@ -41,16 +38,9 @@ class SRHTFamily(SketchFamily):
                                   dtype=jnp.int32)
         return {"sigma": sigma, "rows": rows}
 
-    def apply(self, state: dict, a: jax.Array,
-              use_kernels: bool = False) -> jax.Array:
+    def apply(self, state: dict, a: jax.Array) -> jax.Array:
         n, d = a.shape
         n_pad = next_pow2(n)
-        if use_kernels:
-            from repro.kernels import ops as kops
-            fwht = kops.fwht
-        else:
-            from repro.kernels import ref
-            fwht = ref.fwht
         scale = jnp.sqrt(jnp.asarray(n_pad / self.cfg.block_size, a.dtype))
 
         # lax.map streams blocks so peak memory is ONE (n_pad, d) panel
@@ -64,16 +54,6 @@ class SRHTFamily(SketchFamily):
             return fwht(x[None])[0][rows] * scale
 
         return jax.lax.map(one, (state["sigma"], state["rows"]))
-
-    def gram_fused(self, state: dict, a: jax.Array,
-                   survivors: jax.Array):
-        # Streaming mix: the b sampled Hadamard rows are regenerated per
-        # row-panel inside the kernel, so neither the (n_pad, d) mixed
-        # panel nor A_tilde ever reaches HBM; the d-tiled output grid
-        # keeps the fused path live for every d.
-        from repro.kernels import ops as kops
-        return kops.sketch_gram_srht(state["rows"], state["sigma"], a,
-                                     survivors)
 
     def apply_flops(self, num_rows: int, d: int) -> float:
         n_pad = next_pow2(num_rows)

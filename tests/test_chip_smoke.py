@@ -1,6 +1,6 @@
 """chip_smoke.py on CPU: its phase function at a tiny size with the same
-checks (the Pallas kernels interpreted), its compile-cache rule, and
-main()'s refusal to run without a TPU."""
+checks (the segment sums in place of the chip's kernel), its compile-cache
+rule, and main()'s refusal to run without a TPU."""
 import os
 import sys
 
@@ -15,38 +15,35 @@ from repro import sketching  # noqa: E402
 from repro.core import OverSketchConfig  # noqa: E402
 
 TINY = chip_smoke.Phase("tiny", 600, 20, 100, OverSketchConfig(256, 64, 0.25),
-                        coded_block_rows=64, path="fused", iters=6)
+                        coded_block_rows=64, iters=6)
 
 
 def test_tiny_phase_passes_every_check_on_cpu():
     out = chip_smoke.run_phase(TINY, seed=0)
-    assert out["kernel_path"] == "fused"
-    assert out["agree_rel"] <= chip_smoke.AGREE_RTOL
+    assert out["sketch_impl"] == "segment_sum"
     assert out["hessian_rel"] <= chip_smoke.HESSIAN_RTOL
-    for tag in ("jnp", "kernel"):
-        assert len(out[f"{tag}_fvals"]) == TINY.iters
-        assert out[f"{tag}_vs_ref_rel"] <= chip_smoke.REF_REL
-    # Interpreted kernels lower to plain HLO: main() would refuse this.
+    assert len(out["fvals"]) == TINY.iters
+    assert out["vs_ref_rel"] <= chip_smoke.REF_REL
+    # On the CPU the Hessian lowers to plain HLO: main() would refuse this.
     assert out["custom_call"] is False
 
 
-def test_a_failed_check_raises():
-    bad = chip_smoke.Phase("tiny", 600, 20, 100,
-                           OverSketchConfig(256, 64, 0.25), 64,
-                           path="fused_tiled", iters=1)
-    with pytest.raises(AssertionError, match="kernel path"):
-        chip_smoke.run_phase(bad, seed=0)
+def test_a_failed_check_raises(monkeypatch):
+    monkeypatch.setattr(chip_smoke, "HESSIAN_RTOL", -1.0)
+    short = chip_smoke.Phase("tiny", 600, 20, 100,
+                             OverSketchConfig(256, 64, 0.25), 64, iters=1)
+    with pytest.raises(AssertionError, match="Hessian rel err"):
+        chip_smoke.run_phase(short, seed=0)
 
 
-@pytest.mark.parametrize("name,blocks,path", [
-    ("epsilon", 148, "fused_tiled"),
-    ("a9a", 13, "fused"),
-])
-def test_phases_at_published_widths(name, blocks, path):
+@pytest.mark.parametrize("name,blocks", [("epsilon", 148), ("a9a", 13)])
+def test_phases_at_published_widths(name, blocks):
+    """The published sketches, whose blocks the MXU kernel sketches on a
+    TPU."""
     ph = {p.name: p for p in chip_smoke.PHASES}[name]
     assert ph.sketch.total_blocks == blocks
-    assert ph.path == path
-    assert sketching.get("oversketch", ph.sketch).fused_path(ph.d) == path
+    fam = sketching.get("oversketch", ph.sketch)
+    assert fam.apply_path("tpu") == "mxu_count_sketch"
 
 
 def test_compile_cache_dir_rule(tmp_path):

@@ -124,6 +124,38 @@ def test_ragged_rows_padding(operand):
                                rtol=1e-4, atol=1e-4)
 
 
+# Grids of 2x2, 3x3 and 5x5 workers, narrow and wide blocks, a width that is
+# no multiple of 128, and a bfloat16 operand: one worker erased each time.
+@pytest.mark.parametrize("rows,cols,block,dtype", [
+    (64, 128, 64, jnp.float32),
+    (64, 333, 32, jnp.float32),
+    (1000, 512, 64, jnp.float32),
+    (32, 200, 32, jnp.float32),
+    (32, 200, 32, jnp.bfloat16),
+])
+@pytest.mark.parametrize("operand", OPERANDS)
+def test_coded_matvec_block_shapes_and_dtypes(operand, rows, cols, block,
+                                              dtype):
+    """The solve's coded_matvec against X v (X^T v) computed in float32 from
+    the same values."""
+    key = jax.random.PRNGKey(rows + cols)
+    m = (jax.random.normal(key, (rows, cols)) / 14.0).astype(dtype)
+    t = operand == "XT"
+    v = jax.random.normal(jax.random.fold_in(key, 1),
+                          (rows if t else cols,)).astype(dtype)
+    code = cd.make_code(cols if t else rows, block)
+    parity = cd.encode_2d(m, code, transpose=t)
+    g1 = code.grid + 1
+    erased = jnp.zeros((g1, g1), bool).at[g1 - 1, 0].set(True)
+    y, ok = _matvec(m, parity, v, code, operand, erased)
+    m32, v32 = np.asarray(m, np.float32), np.asarray(v, np.float32)
+    exact = m32.T @ v32 if t else m32 @ v32
+    assert bool(ok) and y.shape == exact.shape
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(y, np.float32), exact, rtol=tol,
+                               atol=tol)
+
+
 @pytest.mark.parametrize("cell", [(1, 1), (2, 4), (4, 2), (4, 4)],
                          ids=["systematic", "row_parity", "col_parity",
                               "corner"])

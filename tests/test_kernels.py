@@ -1,14 +1,18 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + hypothesis.
+"""The count-sketch Pallas kernel and the Hessian's Gram path against the
+pure-jnp oracles of ``kernels/ref.py``: shape/dtype sweeps + hypothesis.
 
-All kernels run in interpret mode on CPU (the TPU-target validation path)."""
+The kernel runs in interpret mode on CPU (the TPU-target validation
+path); the families' Grams run the jnp code the solve runs on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.sketch import OverSketchConfig
-from repro.kernels import count_sketch, ops, ref
+from repro import sketching
+from repro.core import sketch as core_sketch
+from repro.core.sketch import OverSketchConfig, sketched_gram
+from repro.kernels import count_sketch, ref
 
 
 # ---------------------------------------------------------------- count sketch
@@ -24,7 +28,7 @@ def test_count_sketch_shapes(k, n, d, b):
     h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
     sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
     a = jax.random.normal(ka, (n, d))
-    out = ops.count_sketch_apply(h, sigma, a, b)
+    out = count_sketch.count_sketch_apply(h, sigma, a, b, interpret=True)
     expect = ref.count_sketch_apply(h, sigma, a, b)
     assert out.shape == (k, b, d)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -39,7 +43,7 @@ def test_count_sketch_dtypes(dtype):
     h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
     sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
     a = jax.random.normal(ka, (n, d)).astype(dtype)
-    out = ops.count_sketch_apply(h, sigma, a, b)
+    out = count_sketch.count_sketch_apply(h, sigma, a, b, interpret=True)
     expect = ref.count_sketch_apply(h, sigma, a.astype(jnp.float32), b)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -56,7 +60,7 @@ def test_count_sketch_property(seed, n, d):
     h = jax.random.randint(kh, (2, n), 0, b, dtype=jnp.int32)
     sigma = jax.random.rademacher(ks, (2, n), dtype=jnp.float32)
     a = jax.random.normal(ka, (n, d))
-    out = ops.count_sketch_apply(h, sigma, a, b)
+    out = count_sketch.count_sketch_apply(h, sigma, a, b, interpret=True)
     expect = ref.count_sketch_apply(h, sigma, a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-4, atol=1e-4)
@@ -64,7 +68,7 @@ def test_count_sketch_property(seed, n, d):
 
 # The MXU kernel's grid at the shapes it has to handle: K not a multiple of
 # the block group (13, 148), n not a multiple of the row panel, ragged d,
-# and each block size the configurations use.
+# each block size the configurations use, and d = 8192 (16 column tiles).
 @pytest.mark.parametrize("k,n,d,b", [
     (13, 1100, 123, 128),
     (13, 300, 130, 256),
@@ -72,13 +76,14 @@ def test_count_sketch_property(seed, n, d):
     (148, 2500, 123, 256),
     (148, 1100, 123, 128),
     (13, 2500, 130, 64),
+    (1, 64, 8192, 64),
 ])
 def test_count_sketch_grid_matches_segment_sum(k, n, d, b):
     kh, ks, ka = jax.random.split(jax.random.PRNGKey(k + n + d + b), 3)
     h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
     sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
     a = jax.random.normal(ka, (n, d))
-    out = ops.count_sketch_apply(h, sigma, a, b, interpret=True)
+    out = count_sketch.count_sketch_apply(h, sigma, a, b, interpret=True)
     expect = ref.count_sketch_apply(h, sigma, a, b)
     assert out.shape == (k, b, d) and out.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -99,9 +104,10 @@ def test_count_sketch_keeps_all_24_bits():
     def rel(x):
         return np.abs(np.asarray(x) - expect).max() / np.abs(expect).max()
 
-    assert rel(ops.count_sketch_apply(h, sigma, a, b, interpret=True)) <= 1e-6
-    one_pass = ops.count_sketch_apply(h, sigma, a.astype(jnp.bfloat16), b,
-                                      interpret=True)
+    assert rel(count_sketch.count_sketch_apply(h, sigma, a, b,
+                                               interpret=True)) <= 1e-6
+    one_pass = count_sketch.count_sketch_apply(
+        h, sigma, a.astype(jnp.bfloat16), b, interpret=True)
     assert rel(one_pass) > 1e-4
 
 
@@ -204,306 +210,180 @@ def test_count_sketch_chip_script_checks_the_kernel():
         (148, 256), (32, 1024)]
 
 
-# ------------------------------------------------------------ oversketch gram
-@pytest.mark.parametrize("k,b,d", [
-    (4, 64, 32),
-    (6, 128, 100),   # ragged d
-    (10, 256, 256),
-    (3, 65, 33),     # ragged b and d
+
+
+# ---------------------------------------------- the masked Gram, sketched_gram
+# Tolerances per dtype: float32 sums in another order, and bfloat16's 8-bit
+# significand (2^-8 = 3.9e-3 per rounding, a few roundings deep).
+TOL = {jnp.float32: 1e-4, jnp.bfloat16: 2e-2}
+
+
+def _mask(kind, k, key):
+    """random: each block kept with p = 0.8, block 0 always; one_dropped:
+    every block but block 1; single: block 2 alone; none: no block."""
+    if kind == "random":
+        return jax.random.bernoulli(key, 0.8, (k,)).at[0].set(True)
+    if kind == "one_dropped":
+        return jnp.ones((k,), bool).at[1].set(False)
+    if kind == "single":
+        return jnp.zeros((k,), bool).at[2].set(True)
+    return jnp.zeros((k,), bool)
+
+
+@pytest.mark.parametrize("k,b,d,dtype,kind", [
+    (4, 64, 32, jnp.float32, "random"),
+    (6, 128, 100, jnp.float32, "random"),     # ragged d
+    (10, 256, 256, jnp.float32, "random"),
+    (3, 65, 33, jnp.float32, "random"),       # ragged b and d
+    (3, 64, 40, jnp.float32, "one_dropped"),
+    (3, 64, 40, jnp.bfloat16, "one_dropped"),
+    (3, 64, 16, jnp.float32, "none"),
 ])
-def test_oversketch_gram_shapes(k, b, d):
+def test_sketched_gram_matches_the_oracle(k, b, d, dtype, kind):
+    """The solve's masked Gram against ``ref.oversketch_gram``; with no
+    survivor the ``max(n_avail, 1)`` guard gives exact zeros."""
     key = jax.random.PRNGKey(k + b + d)
-    a_t = jax.random.normal(key, (k, b, d))
-    surv = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.8, (k,))
-    surv = surv.at[0].set(True)   # at least one survivor
-    out = ops.oversketch_gram(a_t, surv)
-    expect = ref.oversketch_gram(a_t, surv)
+    a_t = (jax.random.normal(key, (k, b, d)) / 8.0).astype(dtype)
+    surv = _mask(kind, k, jax.random.fold_in(key, 1))
+    out = sketched_gram(a_t, surv)
     assert out.shape == (d, d)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=1e-4, atol=1e-4)
+    if kind == "none":
+        assert not np.asarray(out).any()
+        return
+    expect = ref.oversketch_gram(a_t.astype(jnp.float32), surv)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect), rtol=TOL[dtype],
+                               atol=TOL[dtype])
 
 
-def test_oversketch_gram_all_masked_is_safe():
-    a_t = jnp.ones((3, 64, 16))
-    out = ops.oversketch_gram(a_t, jnp.zeros((3,), bool))
-    assert np.isfinite(np.asarray(out)).all()
-
-
-# ------------------------------------------------------------- coded matvec
-@pytest.mark.parametrize("w,b,s", [
-    (4, 64, 128),
-    (9, 32, 333),    # ragged s
-    (25, 64, 512),
-])
-def test_coded_matvec_shapes(w, b, s):
-    key = jax.random.PRNGKey(w + s)
-    enc = jax.random.normal(key, (w, b, s))
-    x = jax.random.normal(jax.random.fold_in(key, 1), (s,))
-    erased = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.2, (w,))
-    out = ops.coded_block_matvec(enc, x, erased)
-    expect = ref.coded_block_matvec(enc, x, erased)
-    assert out.shape == (w, b)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=1e-4, atol=1e-4)
-
-
-# --------------------------------------------------- fused sketch->gram
-def _sketch_inputs(seed, k, n, d, b, n_pad_srht=None):
-    key = jax.random.PRNGKey(seed)
-    kh, ks, ka, kr, km = jax.random.split(key, 5)
-    h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
-    sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
-    # 1/sqrt(n) row scale keeps Gram entries O(1) so the <= 1e-4 max-abs
-    # acceptance bound is an absolute float32 figure, not a moving target.
-    a = jax.random.normal(ka, (n, d)) / jnp.sqrt(jnp.asarray(n, jnp.float32))
-    n_pad = n_pad_srht or (1 << max(0, (n - 1).bit_length()))
-    rows = jax.random.randint(kr, (k, b), 0, n_pad, dtype=jnp.int32)
-    surv = jax.random.bernoulli(km, 0.6, (k,)).at[0].set(True)
-    return h, sigma, a, rows, surv
-
-
-@pytest.mark.parametrize("k,n,d,b", [
-    (2, 128, 32, 64),
-    (4, 700, 37, 64),      # non-power-of-two n, ragged d
-    (3, 1000, 130, 128),   # d % 128 != 0 on both sides of a tile
-    (5, 520, 64, 256),     # n % tile_n != 0
-])
-def test_sketch_gram_count_fused_matches_unfused(k, n, d, b):
-    h, sigma, a, _, surv = _sketch_inputs(k * 7 + n, k, n, d, b)
-    out = ops.sketch_gram_count(h, sigma, a, b, surv)
-    expect = ref.sketch_gram_count(h, sigma, a, b, surv)
-    assert out.shape == (d, d)
-    assert float(jnp.abs(out - expect).max()) <= 1e-4
-
-
-@pytest.mark.parametrize("k,n,d,b", [
-    (2, 64, 20, 32),
-    (3, 700, 37, 64),      # non-power-of-two n (pads to 1024 internally)
-    (2, 1024, 130, 128),   # ragged d
-])
-def test_sketch_gram_srht_fused_matches_unfused(k, n, d, b):
-    _, sigma, a, rows, surv = _sketch_inputs(k * 11 + n, k, n, d, b)
-    out = ops.sketch_gram_srht(rows, sigma, a, surv)
-    expect = ref.sketch_gram_srht(rows, sigma, a, surv)
-    assert out.shape == (d, d)
-    assert float(jnp.abs(out - expect).max()) <= 1e-4
-
-
-def test_sketch_gram_single_survivor():
-    k, n, d, b = 4, 300, 24, 64
-    h, sigma, a, rows, _ = _sketch_inputs(0, k, n, d, b)
-    surv = jnp.zeros((k,), bool).at[2].set(True)
-    for out, expect in [
-        (ops.sketch_gram_count(h, sigma, a, b, surv),
-         ref.sketch_gram_count(h, sigma, a, b, surv)),
-        (ops.sketch_gram_srht(rows, sigma, a, surv),
-         ref.sketch_gram_srht(rows, sigma, a, surv)),
-    ]:
-        assert float(jnp.abs(out - expect).max()) <= 1e-4
-
-
-def test_sketch_gram_all_masked_is_safe():
-    k, n, d, b = 3, 200, 16, 64
-    h, sigma, a, rows, _ = _sketch_inputs(1, k, n, d, b)
-    surv = jnp.zeros((k,), bool)
-    assert np.isfinite(
-        np.asarray(ops.sketch_gram_count(h, sigma, a, b, surv))).all()
-    assert np.isfinite(
-        np.asarray(ops.sketch_gram_srht(rows, sigma, a, surv))).all()
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sketch_gram_dtypes(dtype):
-    k, n, d, b = 2, 256, 40, 64
-    h, sigma, a, rows, surv = _sketch_inputs(2, k, n, d, b)
-    a = a.astype(dtype)
-    # Both kernels accumulate in float32, so after the (exact) bf16->f32
-    # cast they must match the f32 oracle on the same cast values.
+# ----------------------------------- each family's Gram, SketchFamily.gram
+def _family_case(family, k, n, d, b, s, dtype, seed):
+    """(family, its state, A, the oracle's Gram for a mask) with the
+    state drawn here, so the oracle sees the same h, sigma and rows."""
+    kh, ks, ka, kr = jax.random.split(jax.random.PRNGKey(seed), 4)
+    # 1/sqrt(n) row scale keeps Gram entries O(1), so the tolerance is an
+    # absolute figure.
+    a = (jax.random.normal(ka, (n, d))
+         / jnp.sqrt(jnp.asarray(n, jnp.float32))).astype(dtype)
     a32 = a.astype(jnp.float32)
-    out_c = ops.sketch_gram_count(h, sigma, a, b, surv)
-    np.testing.assert_allclose(
-        np.asarray(out_c), np.asarray(ref.sketch_gram_count(h, sigma, a32,
-                                                            b, surv)),
-        rtol=1e-4, atol=1e-4)
-    out_s = ops.sketch_gram_srht(rows, sigma, a, surv)
-    np.testing.assert_allclose(
-        np.asarray(out_s), np.asarray(ref.sketch_gram_srht(rows, sigma,
-                                                           a32, surv)),
-        rtol=1e-4, atol=1e-4)
+    cfg = OverSketchConfig(b, b, 0.0)
+    if family == "oversketch":
+        h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
+        sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
+        return (sketching.get(family, cfg),
+                core_sketch.CountSketch(h=h, sigma=sigma, block_size=b), a,
+                lambda m: ref.sketch_gram_count(h, sigma, a32, b, m))
+    if family == "srht":
+        sigma = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
+        n_pad = 1 << max(0, (n - 1).bit_length())
+        rows = jax.random.randint(kr, (k, b), 0, n_pad, dtype=jnp.int32)
+        return (sketching.get(family, cfg), {"sigma": sigma, "rows": rows},
+                a, lambda m: ref.sketch_gram_srht(rows, sigma, a32, m))
+    h = jax.random.randint(kh, (k, s, n), 0, b, dtype=jnp.int32)
+    sigma = jax.random.rademacher(ks, (k, s, n), dtype=jnp.float32)
+    fam = sketching.SJLTFamily(cfg, nnz_per_row=s)
+    return (fam, {"h": h, "sigma": sigma}, a,
+            lambda m: ref.sketch_gram_sjlt(h, sigma, a32, b, m))
 
 
-# ------------------------------- fused-vs-unfused differential sweep (tiled)
-# d = 64 fits one resident output tile; 1536 and 4096 are past the old
-# single-tile VMEM budget, where pre-tiling code silently fell back to the
-# unfused pair — the path/pick assertions pin that the d-tiled fused grid
-# actually runs there now.
+def _gram(fam, dtype):
+    """``fam.gram``, jitted as the solve runs it.  A bfloat16 A goes
+    through the OverSketch family unjitted: under jit ``apply_sketch``'s
+    platform switch refuses it, as its kernel branch gives float32 and its
+    segment-sum branch bfloat16 (a named debt in ROADMAP.md)."""
+    if fam.name == "oversketch" and dtype == jnp.bfloat16:
+        return fam.gram
+    return jax.jit(fam.gram)
+
+
+@pytest.mark.parametrize("family,k,n,d,b,s,kind,dtype", [
+    ("oversketch", 2, 128, 32, 64, 1, "random", jnp.float32),
+    ("oversketch", 4, 700, 37, 64, 1, "random", jnp.float32),
+    ("oversketch", 3, 1000, 130, 128, 1, "random", jnp.float32),
+    ("oversketch", 5, 520, 64, 256, 1, "random", jnp.float32),
+    ("oversketch", 4, 300, 24, 64, 1, "single", jnp.float32),
+    ("oversketch", 3, 200, 16, 64, 1, "none", jnp.float32),
+    ("oversketch", 2, 256, 40, 64, 1, "one_dropped", jnp.float32),
+    ("oversketch", 2, 256, 40, 64, 1, "one_dropped", jnp.bfloat16),
+    ("oversketch", 3, 520, 200, 64, 1, "random", jnp.float32),
+    ("srht", 2, 64, 20, 32, 1, "random", jnp.float32),
+    ("srht", 3, 700, 37, 64, 1, "random", jnp.float32),
+    ("srht", 2, 1024, 130, 128, 1, "random", jnp.float32),
+    ("srht", 4, 300, 24, 64, 1, "single", jnp.float32),
+    ("srht", 3, 200, 16, 64, 1, "none", jnp.float32),
+    ("srht", 2, 256, 40, 64, 1, "one_dropped", jnp.float32),
+    ("srht", 2, 256, 40, 64, 1, "one_dropped", jnp.bfloat16),
+    ("srht", 3, 520, 200, 64, 1, "random", jnp.float32),
+    ("sjlt", 2, 128, 32, 64, 1, "random", jnp.float32),
+    ("sjlt", 3, 700, 37, 64, 4, "random", jnp.float32),
+    ("sjlt", 2, 300, 130, 128, 8, "random", jnp.float32),
+])
+def test_family_gram_matches_the_oracle(family, k, n, d, b, s, kind, dtype):
+    """``SketchFamily.gram`` (apply, then ``sketched_gram``) against the
+    ``ref.sketch_gram_*`` oracle on the same draws: non-power-of-two n,
+    ragged d, one survivor, none (the ``max(n_avail, 1)`` guard: zeros)."""
+    fam, state, a, oracle = _family_case(family, k, n, d, b, s, dtype,
+                                         seed=k * 7 + n + s)
+    surv = _mask(kind, k, jax.random.PRNGKey(n + d))
+    out = _gram(fam, dtype)(state, a, surv)
+    assert out.shape == (d, d)
+    if kind == "none":
+        assert not np.asarray(out).any()
+        return
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(jax.jit(oracle)(surv)),
+                               rtol=TOL[dtype], atol=TOL[dtype])
+
+
+# d = 64 to 4096 at b = 64: the widths the Hessian's Gram meets, the last
+# past the kernel's 512-wide column tiles.
 _SWEEP_N = {64: 300, 1536: 192, 4096: 128}
 
 
 @pytest.mark.parametrize("d", [64, 1536, 4096])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("family", ["oversketch", "srht", "sjlt"])
-def test_fused_differential_sweep(family, dtype, d):
-    from repro import sketching
-    from repro.core.sketch import OverSketchConfig, sketched_gram
-
-    b = 64
-    n = _SWEEP_N[d]
-    fam = sketching.get(family, OverSketchConfig(128, b, 0.25))
-    d_pad = d + ((-d) % 128)
-    nnz = getattr(fam, "nnz_per_row", 1)
-    expect_path = "fused" if d <= 1024 else "fused_tiled"
-    assert fam.fused_path(d) == expect_path
-    assert ops.fused_path(b, d, nnz=nnz) == expect_path
-    if expect_path == "fused_tiled":
-        assert ops.pick_d_tile(b, d, nnz=nnz) < d_pad
-
-    key = jax.random.PRNGKey(d + 13 * (dtype == jnp.bfloat16))
-    state = fam.sample(key, n)
-    a = jax.random.normal(jax.random.fold_in(key, 1), (n, d))
-    a = (a / jnp.sqrt(jnp.asarray(n, jnp.float32))).astype(dtype)
-    surv = jnp.ones((fam.cfg.total_blocks,), bool).at[0].set(False)
-    fused = fam.gram_fused(state, a, surv)
-    assert fused is not None           # the decline path is gone for any d
-    # The kernel casts to f32 up front; the unfused oracle runs on the
-    # exactly-cast values so <= 1e-4 is an absolute f32 agreement bound.
-    a32 = a.astype(jnp.float32)
-    expect = sketched_gram(fam.apply(state, a32), surv)
-    assert fused.shape == (d, d)
-    assert float(jnp.abs(fused - expect).max()) <= 1e-4
-
-
-def test_fused_runs_to_d8192():
-    """Acceptance bound: power-of-two-padded d up to 8192 takes the tiled
-    fused grid (never None, never the unfused pair) and agrees."""
-    k, n, d, b = 1, 64, 8192, 64
-    h, sigma, a, _, _ = _sketch_inputs(3, k, n, d, b)
-    surv = jnp.ones((k,), bool)
-    assert ops.fused_path(b, d) == "fused_tiled"
-    out = ops.sketch_gram_count(h, sigma, a, b, surv)
-    expect = ref.sketch_gram_count(h, sigma, a, b, surv)
-    assert float(jnp.abs(out - expect).max()) <= 1e-4
-
-
-def test_sketch_gram_forced_tiny_tile_matches():
-    """Forcing d_tile below d exercises the multi-tile grid on shapes the
-    default pick would run single-tile — diag/off-diag fold coverage."""
-    k, n, d, b = 3, 520, 200, 64
-    h, sigma, a, rows, surv = _sketch_inputs(4, k, n, d, b)
-    out = ops.sketch_gram_count(h, sigma, a, b, surv, d_tile=128)
-    assert float(jnp.abs(out - ref.sketch_gram_count(h, sigma, a, b,
-                                                     surv)).max()) <= 1e-4
-    out_s = ops.sketch_gram_srht(rows, sigma, a, surv, d_tile=128)
-    assert float(jnp.abs(out_s - ref.sketch_gram_srht(rows, sigma, a,
-                                                      surv)).max()) <= 1e-4
-
-
-# --------------------------------------------------- fused sjlt entry point
-@pytest.mark.parametrize("k,s,n,d,b", [
-    (2, 1, 128, 32, 64),    # s=1 degenerates to count-sketch
-    (3, 4, 700, 37, 64),    # non-power-of-two n, ragged d
-    (2, 8, 300, 130, 128),  # deep slot axis, d crossing a lane tile
-])
-def test_sketch_gram_sjlt_fused_matches_unfused(k, s, n, d, b):
-    key = jax.random.PRNGKey(k * 3 + s + n)
-    kh, ks, ka, km = jax.random.split(key, 4)
-    h = jax.random.randint(kh, (k, s, n), 0, b, dtype=jnp.int32)
-    sigma = jax.random.rademacher(ks, (k, s, n), dtype=jnp.float32)
-    a = jax.random.normal(ka, (n, d)) / jnp.sqrt(jnp.asarray(n, jnp.float32))
-    surv = jax.random.bernoulli(km, 0.6, (k,)).at[0].set(True)
-    out = ops.sketch_gram_sjlt(h, sigma, a, b, surv)
-    expect = ref.sketch_gram_sjlt(h, sigma, a, b, surv)
-    assert out.shape == (d, d)
-    assert float(jnp.abs(out - expect).max()) <= 1e-4
+def test_gram_differential_sweep(family, dtype, d):
+    """Each family's Gram against its oracle, every block but the first
+    surviving; for the OverSketch family also the TPU's form, the MXU
+    kernel (interpreted) with the live mask, then ``sketched_gram``."""
+    b, n = 64, _SWEEP_N[d]
+    fam, state, a, oracle = _family_case(family, 3, n, d, b, 4, dtype,
+                                         seed=d + 13 * (dtype == jnp.bfloat16))
+    surv = jnp.ones((3,), bool).at[0].set(False)
+    expect = np.asarray(jax.jit(oracle)(surv))
+    grams = [_gram(fam, dtype)(state, a, surv)]
+    if family == "oversketch":
+        grams.append(sketched_gram(count_sketch.count_sketch_apply(
+            state.h, state.sigma, a, b, live=surv, interpret=True), surv))
+    for out in grams:
+        assert out.shape == (d, d)
+        np.testing.assert_allclose(np.asarray(out, np.float32), expect,
+                                   rtol=TOL[dtype], atol=TOL[dtype])
 
 
 def test_sjlt_s1_equals_count_sketch():
-    """SJLT with one slot IS count-sketch: both fused entry points agree."""
+    """SJLT with one slot IS count-sketch: the two families' Grams agree on
+    the same h, sigma and mask."""
     k, n, d, b = 2, 256, 40, 64
-    h, sigma, a, _, surv = _sketch_inputs(5, k, n, d, b)
-    out_c = ops.sketch_gram_count(h, sigma, a, b, surv)
-    out_j = ops.sketch_gram_sjlt(h[:, None, :], sigma[:, None, :], a, b, surv)
+    fam_c, cs, a, _ = _family_case("oversketch", k, n, d, b, 1,
+                                   jnp.float32, seed=5)
+    fam_j = sketching.SJLTFamily(fam_c.cfg, nnz_per_row=1)
+    surv = jnp.array([False, True])
+    out_c = jax.jit(fam_c.gram)(cs, a, surv)
+    out_j = jax.jit(fam_j.gram)(
+        {"h": cs.h[:, None, :], "sigma": cs.sigma[:, None, :]}, a, surv)
     np.testing.assert_allclose(np.asarray(out_j), np.asarray(out_c),
                                rtol=1e-5, atol=1e-6)
 
 
-# ------------------------------------------------------------ two-pass fwht
-@pytest.mark.parametrize("k,n,d", [
-    (2, 64, 20),       # tiny d (pads to one 128 lane tile)
-    (1, 1024, 130),    # d % tile_d != 0
-    (2, 2048, 17),
-    (1, 4096, 256),
-])
-def test_fwht_two_pass_matches_butterfly_oracle(k, n, d):
-    x = jax.random.normal(jax.random.PRNGKey(n + d), (k, n, d))
-    np.testing.assert_allclose(np.asarray(ops.fwht_two_pass(x)),
-                               np.asarray(ref.fwht(x)),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_fwht_two_pass_rejects_non_pow2():
-    with pytest.raises(ValueError, match="power of two"):
-        ops.fwht_two_pass(jnp.zeros((1, 100, 4)))
-
-
-def test_fwht_dispatches_two_pass_beyond_panel_budget():
-    """An n whose monolithic (n, td) panel exceeds the documented VMEM
-    budget must still go through ops.fwht (via the two-pass kernel) and
-    match the oracle."""
-    from repro.kernels.srht import MAX_PANEL_BYTES, panel_vmem_bytes
-    n = 32768
-    assert panel_vmem_bytes(n, d=8) > MAX_PANEL_BYTES
-    x = jax.random.normal(jax.random.PRNGKey(5), (1, n, 8))
-    np.testing.assert_allclose(np.asarray(ops.fwht(x)),
-                               np.asarray(ref.fwht(x)),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_fwht_two_pass_is_involution():
-    x = jax.random.normal(jax.random.PRNGKey(6), (1, 512, 64))
-    y = ops.fwht_two_pass(ops.fwht_two_pass(x))
-    np.testing.assert_allclose(np.asarray(y), np.asarray(x),
-                               rtol=1e-4, atol=1e-4)
-
-
-# --------------------------------------- dtype sweep, remaining entry points
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fwht_dtypes_both_paths(dtype):
-    x = jax.random.normal(jax.random.PRNGKey(8), (2, 256, 40)).astype(dtype)
-    expect = ref.fwht(x.astype(jnp.float32))
-    for out in (ops.fwht(x), ops.fwht_two_pass(x)):
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                                   rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_oversketch_gram_dtypes(dtype):
-    key = jax.random.PRNGKey(9)
-    a_t = (jax.random.normal(key, (3, 64, 40)) / 8.0).astype(dtype)
-    surv = jnp.ones((3,), bool).at[1].set(False)
-    out = ops.oversketch_gram(a_t, surv)
-    expect = ref.oversketch_gram(a_t.astype(jnp.float32), surv)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_coded_matvec_dtypes(dtype):
-    key = jax.random.PRNGKey(10)
-    enc = (jax.random.normal(key, (4, 32, 200)) / 14.0).astype(dtype)
-    x = jax.random.normal(jax.random.fold_in(key, 1), (200,)).astype(dtype)
-    erased = jnp.zeros((4,), bool).at[2].set(True)
-    out = ops.coded_block_matvec(enc, x, erased)
-    expect = ref.coded_block_matvec(enc.astype(jnp.float32),
-                                    x.astype(jnp.float32), erased)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=1e-4, atol=1e-4)
-
-
-# ------------------------------------------- end-to-end kernels inside newton
-def test_newton_with_kernels_matches_reference_path():
+# ------------------------------------------------- the default-path solve
+def test_newton_default_path_matches_exact_newton():
+    """The default path (count sketch, masked Gram, coded matvecs) ends
+    within 1% of plain exact Newton after as many iterations."""
+    from benchmarks.common import best_f
     from repro.core import (Dataset, LogisticRegression, NewtonConfig,
-                            OverSketchConfig, oversketched_newton)
+                            oversketched_newton)
     key = jax.random.PRNGKey(11)
     n, d = 600, 20
     kx, kw, ky = jax.random.split(key, 3)
@@ -513,13 +393,12 @@ def test_newton_with_kernels_matches_reference_path():
                   jax.nn.sigmoid(x @ wstar), 1.0, -1.0)
     data = Dataset(x=x, y=y)
     obj = LogisticRegression(lam=1e-4)
-    base = dict(iters=4, sketch=OverSketchConfig(256, 64, 0.25),
-                coded_block_rows=64)
-    r_ref = oversketched_newton(obj, data, jnp.zeros(d),
-                                NewtonConfig(**base), model=None)
-    r_ker = oversketched_newton(obj, data, jnp.zeros(d),
-                                NewtonConfig(use_kernels=True, **base),
-                                model=None)
-    # Same sketch seed => identical Hessians => identical trajectories.
-    np.testing.assert_allclose(np.asarray(r_ref.w), np.asarray(r_ker.w),
-                               rtol=1e-4, atol=1e-5)
+    r_osn = oversketched_newton(
+        obj, data, jnp.zeros(d),
+        NewtonConfig(iters=4, sketch=OverSketchConfig(256, 64, 0.25),
+                     coded_block_rows=64), model=None)
+    r_ref = oversketched_newton(
+        obj, data, jnp.zeros(d),
+        NewtonConfig(iters=4, hessian_policy="exact"), model=None)
+    assert r_osn.history["fval"][-1] <= best_f(r_ref.history, r_osn.history,
+                                               rel=0.01)

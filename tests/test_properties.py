@@ -14,23 +14,24 @@ it is absent, and CI runs them in a dedicated job with it installed):
    dropping blocks + rescaling is EXACT — the masked estimator equals
    the plain average over the surviving subset, for any mask including
    the single-survivor edge.
-3. **Fused-kernel agreement across padding edges**: the d-tiled fused
-   sketch->Gram kernel matches the unfused oracle to 1e-4 with n not a
-   multiple of tile_n, d not a multiple of d_tile, and forced-small
-   tiles so the multi-tile (d_i, d_j) grid runs on CPU-sized shapes.
+3. **Gram agreement across padding edges**: each encode family's
+   ``gram`` matches its ``kernels/ref.py`` oracle to 1e-4 with n and d
+   drawn across the 128-row and 128-lane edges and one survivor or many.
 
 Families are looped inside the test bodies (not pytest.parametrize): the
 hypothesis-compat shim replaces @given tests with zero-arg skippers, so
 externally injected params would break hypothesis-less collection.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from _hypothesis_compat import given, settings, st
 
 from repro import sketching
-from repro.core.sketch import OverSketchConfig
-from repro.kernels import ops, ref
+from repro.core.sketch import CountSketch, OverSketchConfig, sketch_impl
+from repro.kernels import ref
 
 FAMILIES = ["oversketch", "srht", "sjlt", "gaussian", "nystrom", "leverage"]
 _CFG = OverSketchConfig(sketch_dim=64, block_size=16,
@@ -96,20 +97,18 @@ def test_survivor_mask_invariance(seed, n, d, single, idx):
                                    err_msg=f"family={family}")
 
 
-# --------------------------------- fused kernel across padding edges
+# ------------------------------------------- Gram across padding edges
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 2**20),
-       n=st.integers(50, 300),               # crosses tile_n boundaries
+       n=st.integers(50, 300),               # crosses 128-row panels
        d=st.integers(3, 150),                # crosses the 128-lane tile
-       tile_n=st.sampled_from([64, 128]),
-       d_tile=st.sampled_from([128, 256]),
        single=st.booleans())
-def test_fused_kernel_padding_edges(seed, n, d, tile_n, d_tile, single):
-    """ops.sketch_gram_{count,srht,sjlt} vs the unfused jnp oracle, with
-    shapes straddling every padding edge and forced-small d_tile so the
-    multi-tile (d_i, d_j) grid (diagonal + off-diagonal folds) executes
-    on CPU-sized shapes."""
+def test_gram_padding_edges(seed, n, d, single):
+    """``family.gram`` of the oversketch, srht and sjlt families vs the
+    ``ref.sketch_gram_*`` oracle on the same draws, with shapes
+    straddling every padding edge."""
     k, b, s = 2, 32, 3
+    cfg = OverSketchConfig(b, b, 0.0)
     key = jax.random.PRNGKey(seed)
     kh, ks, ka, kr, km = jax.random.split(key, 5)
     a = jax.random.normal(ka, (n, d)) / jnp.sqrt(jnp.asarray(n, jnp.float32))
@@ -117,7 +116,6 @@ def test_fused_kernel_padding_edges(seed, n, d, tile_n, d_tile, single):
         surv = jnp.zeros((k,), bool).at[1].set(True)
     else:
         surv = jax.random.bernoulli(km, 0.6, (k,)).at[0].set(True)
-    kw = dict(tile_n=tile_n, d_tile=d_tile)
     h = jax.random.randint(kh, (k, n), 0, b, dtype=jnp.int32)
     sg = jax.random.rademacher(ks, (k, n), dtype=jnp.float32)
     n_pad = 1 << max(0, (n - 1).bit_length())
@@ -125,15 +123,21 @@ def test_fused_kernel_padding_edges(seed, n, d, tile_n, d_tile, single):
     hj = jax.random.randint(kh, (k, s, n), 0, b, dtype=jnp.int32)
     sj = jax.random.rademacher(jax.random.fold_in(ks, 1), (k, s, n),
                                dtype=jnp.float32)
+    oracle = functools.partial(jax.jit, static_argnames="block_size")
     cells = [
-        ("count", ops.sketch_gram_count(h, sg, a, b, surv, **kw),
-         ref.sketch_gram_count(h, sg, a, b, surv)),
-        ("srht", ops.sketch_gram_srht(rows, sg, a, surv, **kw),
-         ref.sketch_gram_srht(rows, sg, a, surv)),
-        ("sjlt", ops.sketch_gram_sjlt(hj, sj, a, b, surv, **kw),
-         ref.sketch_gram_sjlt(hj, sj, a, b, surv)),
+        ("count", sketching.get("oversketch", cfg),
+         CountSketch(h=h, sigma=sg, block_size=b),
+         oracle(ref.sketch_gram_count)(h, sg, a, block_size=b,
+                                       survivors=surv)),
+        ("srht", sketching.get("srht", cfg), {"sigma": sg, "rows": rows},
+         jax.jit(ref.sketch_gram_srht)(rows, sg, a, surv)),
+        ("sjlt", sketching.SJLTFamily(cfg, nnz_per_row=s),
+         {"h": hj, "sigma": sj},
+         oracle(ref.sketch_gram_sjlt)(hj, sj, a, block_size=b,
+                                      survivors=surv)),
     ]
-    for mode, out, expect in cells:
+    for mode, fam, state, expect in cells:
+        out = jax.jit(fam.gram)(state, a, surv)
         assert out.shape == (d, d)
         err = float(jnp.abs(out - expect).max())
         assert err <= 1e-4, f"mode={mode}: max_err={err:.2e}"
@@ -145,12 +149,17 @@ def test_all_six_families_registered():
     assert sorted(FAMILIES) == sketching.available()
 
 
-def test_fused_path_reporting_consistent():
-    """fused_path agrees with has_fused_gram across the registry."""
+def test_apply_path_reporting_consistent():
+    """apply_path across the registry: the OverSketch family names the
+    implementation ``apply_sketch`` picks for the platform and block
+    size, every other family its one jnp form."""
     for name in sketching.available():
         fam = sketching.get(name, _CFG)
-        path = fam.fused_path(512)
-        if fam.has_fused_gram:
-            assert path in ("fused", "fused_tiled")
-        else:
-            assert path == "unfused"
+        for platform in ("tpu", "cpu"):
+            path = fam.apply_path(platform)
+            if name == "oversketch":
+                assert path == sketch_impl(platform, _CFG.block_size)
+            else:
+                assert path == "unfused"
+    assert sketching.get("oversketch", _CFG).apply_path("tpu") \
+        == "mxu_count_sketch"

@@ -1,7 +1,8 @@
 """Pluggable sketching subsystem: registry round-trips, per-family
-unbiasedness of the sketched Gram, survivor-subset rescaling, the SRHT
-Pallas kernel vs its butterfly oracle, Marchenko-Pastur debiasing, and
-end-to-end Newton convergence for every family (incl. distributed-avg)."""
+unbiasedness of the sketched Gram, survivor-subset rescaling, each
+family's apply and Gram against its oracle and its dense formula, the FWHT
+butterfly, Marchenko-Pastur debiasing, and end-to-end Newton convergence
+for every family (incl. distributed-avg)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +12,7 @@ from repro import sketching
 from repro.core import (Dataset, LogisticRegression, NewtonConfig,
                         OverSketchConfig, oversketched_newton)
 from repro.core.sketch import sketched_gram
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 FAMILIES = ("oversketch", "srht", "sjlt", "gaussian", "nystrom", "leverage")
 
@@ -87,114 +88,127 @@ def test_survivor_subset_rescaling(name):
 
 
 @pytest.mark.parametrize("name", ("oversketch", "srht", "sjlt"))
-def test_kernel_path_matches_reference(name):
+def test_apply_matches_the_oracle(name):
+    """Each family's apply against its ``kernels/ref.py`` oracle on the
+    same draws (the OverSketch family's is ``apply_sketch``'s CPU branch,
+    the ``lax.map`` of segment sums)."""
     key = jax.random.PRNGKey(5)
     a = jax.random.normal(key, (200, 20))
     fam = sketching.get(name, _cfg(256, 64, 0.25))
     state = fam.sample(jax.random.fold_in(key, 2), 200)
-    plain = fam.apply(state, a, use_kernels=False)
-    kern = fam.apply(state, a, use_kernels=True)
-    np.testing.assert_allclose(np.asarray(plain), np.asarray(kern),
-                               rtol=1e-5, atol=1e-5)
+    b = fam.cfg.block_size
+    expect = {
+        "oversketch": lambda: ref.count_sketch_apply(state.h, state.sigma,
+                                                     a, b),
+        "srht": lambda: ref.srht_apply(state["rows"], state["sigma"], a),
+        "sjlt": lambda: ref.sjlt_apply(state["h"], state["sigma"], a, b),
+    }[name]()
+    np.testing.assert_allclose(np.asarray(jax.jit(fam.apply)(state, a)),
+                               np.asarray(expect), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("name", ("oversketch", "srht", "sjlt"))
-def test_gram_fused_matches_gram(name):
-    """Families with a fused streaming kernel: gram(use_kernels=True)
-    (which prefers gram_fused) == the plain apply+gram path, under a
-    partial survivor mask."""
-    key = jax.random.PRNGKey(6)
-    n = 300
-    a = jax.random.normal(key, (n, 20)) / np.sqrt(n)
-    fam = sketching.get(name, _cfg(256, 64, 0.25))
-    state = fam.sample(jax.random.fold_in(key, 2), n)
-    surv = jnp.arange(fam.cfg.total_blocks) % 2 == 0
-    fused = fam.gram_fused(state, a, surv)
-    assert fused is not None
-    plain = fam.gram(state, a, surv, use_kernels=False)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(plain),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(
-        np.asarray(fam.gram(state, a, surv, use_kernels=True)),
-        np.asarray(fused), rtol=1e-6, atol=1e-6)
+def _dense_sketch(fam, state, a):
+    """The (K, n, b) sketch matrices S_k the family's draw stands for,
+    written out entry by entry (S_k^T A is what ``apply`` returns)."""
+    n = a.shape[0]
+    k, b = fam.cfg.total_blocks, fam.cfg.block_size
+    if fam.name in ("oversketch", "sjlt"):
+        h, sigma = ((state.h[:, None], state.sigma[:, None])
+                    if fam.name == "oversketch"
+                    else (state["h"], state["sigma"]))
+        one_hot = jax.nn.one_hot(h, b) * sigma[..., None]   # (K, s, n, b)
+        return one_hot.sum(axis=1) / np.sqrt(h.shape[1])
+    if fam.name == "srht":
+        n_pad = 1 << max(0, (n - 1).bit_length())
+        had = ref.fwht(jnp.eye(n_pad)[None])[0][:n]          # (n, n_pad)
+        cols = had[:, state["rows"]].transpose(1, 0, 2)      # (K, n, b)
+        return (cols * state["sigma"][..., None]
+                * np.sqrt(n_pad / b))
+    if fam.name == "gaussian":
+        return jax.vmap(lambda kk: jax.random.normal(kk, (n, b))
+                        / np.sqrt(b))(state["keys"])
+    if fam.name == "nystrom":
+        return (jax.nn.one_hot(state["rows"], n).transpose(0, 2, 1)
+                * np.sqrt(n / b))
+    # leverage: rows drawn by A's leverage scores, each scaled by
+    # 1/sqrt(b p_row).
+    q, _ = jnp.linalg.qr(a)
+    lev = jnp.sum(q * q, axis=1)
+    p = lev / jnp.sum(lev)
+    rows = jax.random.choice(state["key"], n, (k, b), replace=True, p=p)
+    return (jax.nn.one_hot(rows, n).transpose(0, 2, 1)
+            / jnp.sqrt(b * p[rows])[:, None, :])
 
 
-@pytest.mark.parametrize("name", ("oversketch", "srht", "sjlt"))
-def test_gram_fused_tiles_past_single_tile_budget(name):
-    """Beyond the single-tile VMEM budget (the resident (d,d) output) the
-    fused kernel d-tiles its output grid instead of declining: gram_fused
-    never returns None and still matches the reference path.  (The old
-    behavior — None past MAX_FUSED_VMEM_BYTES, silent unfused fallback —
-    is exactly what the tiled grid deleted.)"""
-    from repro.kernels.sketch_gram import fits_fused_vmem, pick_d_tile
+@pytest.mark.parametrize("d", [640, 2048])
+@pytest.mark.parametrize("name", FAMILIES)
+def test_gram_matches_the_dense_formula(name, d):
+    """Each family's masked Gram against (1/N_avail) sum_k m_k
+    (S_k^T A)^T (S_k^T A) from its written-out sketch matrices, at d past
+    512."""
     key = jax.random.PRNGKey(9)
-    n, d = 64, 2048
-    fam = sketching.get(name, _cfg(128, 64, 0.25))
-    assert not fits_fused_vmem(fam.cfg.block_size, d)
-    assert fits_fused_vmem(fam.cfg.block_size, 512)
-    assert fam.fused_path(d) == "fused_tiled"
-    assert pick_d_tile(fam.cfg.block_size, d) < d
+    n = 64
     a = jax.random.normal(key, (n, d)) / np.sqrt(n)
-    state = fam.sample(jax.random.fold_in(key, 1), n)
-    surv = jnp.ones((fam.cfg.total_blocks,), bool)
-    fused = fam.gram_fused(state, a, surv)
-    assert fused is not None
-    np.testing.assert_allclose(
-        np.asarray(fused),
-        np.asarray(fam.gram(state, a, surv, use_kernels=False)),
-        rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("name", ("gaussian", "nystrom", "leverage"))
-def test_gram_kernel_fallback_without_fused(name):
-    """Families without a fused kernel return None from gram_fused and the
-    kernel path falls back to apply + masked-Gram kernel."""
-    key = jax.random.PRNGKey(7)
-    n = 200
-    a = jax.random.normal(key, (n, 12))
     fam = sketching.get(name, _cfg(256, 64, 0.25))
-    state = fam.sample(jax.random.fold_in(key, 3), n)
-    surv = jnp.ones((fam.cfg.total_blocks,), bool).at[0].set(False)
-    assert fam.gram_fused(state, a, surv) is None
-    np.testing.assert_allclose(
-        np.asarray(fam.gram(state, a, surv, use_kernels=True)),
-        np.asarray(fam.gram(state, a, surv, use_kernels=False)),
-        rtol=1e-4, atol=1e-4)
+    state = fam.sample(jax.random.fold_in(key, 1), n)
+    surv = jnp.arange(fam.cfg.total_blocks) % 2 == 0
+    a_t = jnp.einsum("knb,nd->kbd", _dense_sketch(fam, state, a), a,
+                     precision="highest")
+    keep = np.asarray(a_t, np.float64)[np.asarray(surv)]
+    want = np.einsum("kbd,kbe->de", keep, keep) / keep.shape[0]
+    np.testing.assert_allclose(np.asarray(jax.jit(fam.gram)(state, a, surv)),
+                               want, rtol=1e-4, atol=1e-4)
 
 
-def test_core_oversketched_gram_fused_routing():
-    """core.sketch.oversketched_gram(use_kernels=True) takes the fused
-    kernel end-to-end and agrees with the reference composition."""
+@pytest.mark.parametrize("masked", [False, True])
+def test_core_oversketched_gram_is_the_sketched_gram_of_apply_sketch(masked):
+    """core.sketch.oversketched_gram draws its sketch from the key and is
+    sketched_gram(apply_sketch(...)) on that draw, bit for bit."""
     from repro.core import sketch as core_sketch
     key = jax.random.PRNGKey(8)
     n = 400
     a = jax.random.normal(key, (n, 16)) / np.sqrt(n)
     cfg = _cfg(256, 64, 0.25)
     kf = jax.random.fold_in(key, 1)
-    surv = jnp.ones((cfg.total_blocks,), bool).at[1].set(False)
-    fused = core_sketch.oversketched_gram(kf, a, cfg, surv, use_kernels=True)
-    plain = core_sketch.oversketched_gram(kf, a, cfg, surv)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(plain),
-                               rtol=1e-4, atol=1e-4)
+    surv = (jnp.ones((cfg.total_blocks,), bool).at[1].set(False)
+            if masked else None)
+    cs = core_sketch.sample_countsketch(kf, n, cfg)
+    np.testing.assert_array_equal(
+        np.asarray(core_sketch.oversketched_gram(kf, a, cfg, surv)),
+        np.asarray(sketched_gram(core_sketch.apply_sketch(cs, a), surv)))
 
 
-# -------------------------------------------------------------- FWHT kernel
-@pytest.mark.parametrize("k,n,d", [(2, 8, 5), (3, 256, 17), (1, 512, 130)])
-def test_fwht_kernel_vs_butterfly_oracle(k, n, d):
-    x = jax.random.normal(jax.random.PRNGKey(n), (k, n, d))
-    np.testing.assert_allclose(np.asarray(ops.fwht(x)),
-                               np.asarray(ref.fwht(x)),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_fwht_is_orthonormal_involution():
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 9))
-    y = ref.fwht(x)
-    # orthonormal: norms preserved; Sylvester H is symmetric: H^2 = I
-    np.testing.assert_allclose(float(jnp.linalg.norm(y)),
-                               float(jnp.linalg.norm(x)), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(ref.fwht(y)), np.asarray(x),
-                               rtol=1e-5, atol=1e-5)
+# --------------------------------------------------------------- FWHT
+@pytest.mark.parametrize("k,n,d,dtype", [
+    (2, 128, 9, jnp.float32),
+    (2, 8, 5, jnp.float32),
+    (3, 256, 17, jnp.float32),
+    (1, 512, 130, jnp.float32),
+    (2, 64, 20, jnp.float32),
+    (1, 1024, 130, jnp.float32),
+    (2, 2048, 17, jnp.float32),
+    (1, 4096, 256, jnp.float32),
+    (1, 32768, 8, jnp.float32),       # past the old kernel's panel budget
+    (1, 512, 64, jnp.float32),
+    (2, 256, 40, jnp.float32),
+    (2, 256, 40, jnp.bfloat16),
+    (1, 512, 64, jnp.bfloat16),
+])
+def test_fwht_is_orthonormal_involution(k, n, d, dtype):
+    """The butterfly SRHT runs: norms kept, and H_norm^2 = I (Sylvester H
+    is symmetric).  bfloat16 rounds at each of the log2(n) stages, so its
+    bound is a few roundings (2^-8 each) at the largest magnitude."""
+    x = jax.random.normal(jax.random.PRNGKey(n + d), (k, n, d)).astype(dtype)
+    x32 = np.asarray(x, np.float32)
+    rtol = atol = 1e-5
+    if dtype == jnp.bfloat16:
+        rtol, atol = 2.0 ** -6, 2.0 ** -5 * np.abs(x32).max()
+    y = jax.jit(ref.fwht)(x)
+    assert y.dtype == dtype
+    np.testing.assert_allclose(float(jnp.linalg.norm(y.astype(jnp.float32))),
+                               float(np.linalg.norm(x32)), rtol=rtol)
+    np.testing.assert_allclose(np.asarray(jax.jit(ref.fwht)(y), np.float32),
+                               x32, rtol=rtol, atol=atol)
 
 
 def test_fwht_rejects_non_pow2():

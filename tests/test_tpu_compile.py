@@ -1,14 +1,15 @@
-"""Compile the main path's kernels for a TPU v5e chip that is described,
-not attached, at real widths.
+"""Compile the solve's device programs for a TPU v5e chip that is
+described, not attached, at real widths.
 
-Nothing runs here: a pass means the chip's compiler accepts each Pallas
-kernel compiled with ``interpret=False`` (its block tiling, its scoped-VMEM
-request) and that the program fits one chip's 16 GiB of HBM.  The widths
-are epsilon's published d = 2000 with n cut to 200,000 rows (the fig7
-sketch: K = 148 blocks of b = 256) and a9a at its full 32,000 x 123 (the
-fig8 sketch: K = 13 blocks of b = 128).  The paper's synthetic problem
-is compiled at its full 300,000 x 3000 (its sketch: K = 148 blocks of
-b = 256), with the product codes held as parity only.
+Nothing runs here: a pass means the chip's compiler accepts each program,
+the count-sketch Pallas kernel compiled with ``interpret=False`` among
+them (its block tiling, its scoped-VMEM request), and that the program
+fits one chip's 16 GiB of HBM.  The widths are epsilon's published
+d = 2000 with n cut to 200,000 rows (the fig7 sketch: K = 148 blocks of
+b = 256), a9a at its full 32,000 x 123 (the fig8 sketch: K = 13 blocks of
+b = 128) and the paper's synthetic problem at its full 300,000 x 3000
+(its sketch: K = 148 blocks of b = 256), with the product codes held as
+parity only.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker that runs this file
@@ -29,7 +30,7 @@ from jax.sharding import SingleDeviceSharding
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)            # chip_smoke.py lives at the repo root
 
-from chip_smoke import PHASES  # noqa: E402
+from chip_smoke import PHASES, Phase  # noqa: E402
 from repro import sketching  # noqa: E402
 from repro.core import coded  # noqa: E402
 from repro.core.coded import make_code  # noqa: E402
@@ -38,16 +39,22 @@ from repro.core.newton import (_jitted_distavg_direction,  # noqa: E402
 from repro.core.objectives import Dataset, LogisticRegression  # noqa: E402
 from repro.core.sketch import (MXU_MAX_BLOCK_SIZE, CountSketch,  # noqa: E402
                                OverSketchConfig, apply_sketch)
-from repro.kernels import ops  # noqa: E402
 from repro.kernels.count_sketch import (VMEM_BUDGET_BYTES,  # noqa: E402
-                                        VMEM_HEADROOM_BYTES, pick_tiles,
+                                        VMEM_HEADROOM_BYTES,
+                                        count_sketch_apply, pick_tiles,
                                         vmem_bytes)
-from repro.sketching.base import next_pow2  # noqa: E402
 
 HBM_BYTES = 16 * 2 ** 30
-# The phases chip_smoke.py runs on the chip: n, d, sketch, coded rows.
+# The paper's synthetic logistic problem (arXiv:1903.08857, Sec. 5) at its
+# published size, as bench/configs/synthetic.json runs it.
+SYNTHETIC = dict(n=300_000, d=3000, coded_block_rows=256,
+                 sketch=OverSketchConfig(30208, 256, 0.25))
+# The phases chip_smoke.py runs on the chip, and the synthetic problem:
+# n, d, sketch, coded rows.
 WIDTHS = {ph.name: ph for ph in PHASES}
-SJLT_NNZ = 4
+WIDTHS["synthetic"] = Phase("synthetic", SYNTHETIC["n"], SYNTHETIC["d"],
+                            100_000, SYNTHETIC["sketch"],
+                            SYNTHETIC["coded_block_rows"])
 
 
 @pytest.fixture(scope="module")
@@ -81,52 +88,48 @@ def _hbm_bytes(compiled) -> int:
             + m.temp_size_in_bytes)
 
 
-def _kernel_cases(width):
+def _programs(width):
+    """The solve's device programs at a width: (program, its argument
+    shapes, whether the MXU count-sketch kernel is in it)."""
     ph = WIDTHS[width]
     n, d, br = ph.n, ph.d, ph.coded_block_rows
     k, b = ph.sketch.total_blocks, ph.sketch.block_size
     f32, i32, b1 = jnp.float32, jnp.int32, jnp.bool_
     code_x, code_xt = make_code(n, min(br, n)), make_code(d, min(br, d))
+    hessian = _jitted_sketched_hessian(
+        LogisticRegression(lam=1e-5), sketching.get("oversketch", ph.sketch))
     return {
-        "sketch_gram_count": (
-            lambda h, s, a, m: ops.sketch_gram_count(h, s, a, b, m,
-                                                     interpret=False),
-            ((k, n), i32), ((k, n), f32), ((n, d), f32), ((k,), b1)),
-        "sketch_gram_sjlt": (
-            lambda h, s, a, m: ops.sketch_gram_sjlt(h, s, a, b, m,
-                                                    interpret=False),
-            ((k, SJLT_NNZ, n), i32), ((k, SJLT_NNZ, n), f32), ((n, d), f32),
-            ((k,), b1)),
-        "sketch_gram_srht": (
-            lambda r, s, a, m: ops.sketch_gram_srht(r, s, a, m,
-                                                    interpret=False),
-            ((k, b), i32), ((k, n), f32), ((n, d), f32), ((k,), b1)),
         "count_sketch_apply": (
-            lambda h, s, a: ops.count_sketch_apply(h, s, a, b,
-                                                   interpret=False),
-            ((k, n), i32), ((k, n), f32), ((n, d), f32)),
-        "oversketch_gram": (
-            lambda at, m: ops.oversketch_gram(at, m, interpret=False),
-            ((k, b, d), f32), ((k,), b1)),
-        # One streamed SRHT block, as sketching/srht.py applies it.
-        "fwht": (
-            lambda x: ops.fwht(x, interpret=False),
-            ((1, next_pow2(n), d), f32)),
-        # The product codes of X and X^T the gradient's matvecs read.
-        "coded_block_matvec_x": (
-            lambda e, x, er: ops.coded_block_matvec(e, x, er,
-                                                    interpret=False),
-            ((code_x.num_workers, code_x.block_rows, d), f32), ((d,), f32),
-            ((code_x.num_workers,), b1)),
-        "coded_block_matvec_xt": (
-            lambda e, x, er: ops.coded_block_matvec(e, x, er,
-                                                    interpret=False),
-            ((code_xt.num_workers, code_xt.block_rows, n), f32),
-            ((n,), f32), ((code_xt.num_workers,), b1)),
+            lambda h, s, a: count_sketch_apply(h, s, a, b, interpret=False),
+            True, ((k, n), i32), ((k, n), f32), ((n, d), f32)),
+        # With the straggler survivors as its live mask, as the Hessian
+        # calls it (an SMEM operand).
+        "count_sketch_apply_live": (
+            lambda h, s, a, m: count_sketch_apply(h, s, a, b, live=m,
+                                                  interpret=False),
+            True, ((k, n), i32), ((k, n), f32), ((n, d), f32), ((k,), b1)),
+        "hessian": (
+            lambda w, x, y, h, s, m: hessian(
+                w, Dataset(x, y), CountSketch(h=h, sigma=s, block_size=b),
+                m),
+            True, ((d,), f32), ((n, d), f32), ((n,), f32), ((k, n), i32),
+            ((k, n), f32), ((k,), b1)),
+        # The gradient's coded matvecs: X v and X^T v from X and the
+        # parity of its code.
+        "coded_matvec_x": (
+            lambda x, p, v, e: coded.coded_matvec(x, p, v, code_x, e),
+            False, ((n, d), f32),
+            ((2 * code_x.grid + 1, code_x.block_rows, d), f32),
+            ((d,), f32), ((code_x.grid + 1,) * 2, b1)),
+        "coded_matvec_xt": (
+            lambda x, p, v, e: coded.coded_matvec(x, p, v, code_xt, e, True),
+            False, ((n, d), f32),
+            ((2 * code_xt.grid + 1, n, code_xt.block_rows), f32),
+            ((n,), f32), ((code_xt.grid + 1,) * 2, b1)),
     }
 
 
-KERNELS = list(_kernel_cases("a9a"))
+PROGRAMS = list(_programs("a9a"))
 
 
 # Highest precision splits f32 matmul operands into bf16 parts in VMEM,
@@ -134,14 +137,14 @@ KERNELS = list(_kernel_cases("a9a"))
 # the Hessian at highest precision).
 @pytest.mark.parametrize("precision", [None, "highest"])
 @pytest.mark.parametrize("width", list(WIDTHS))
-@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("kernel", PROGRAMS)
 def test_kernel_compiles_for_v5e(one_chip, kernel, width, precision):
-    fn, *shapes = _kernel_cases(width)[kernel]
+    fn, mxu, *shapes = _programs(width)[kernel]
     with jax.default_matmul_precision(precision):
         compiled = _compile(fn, one_chip, *shapes)
     # interpret=False lowers the kernel through Mosaic; an interpreted
     # kernel would compile too, but as plain HLO with no custom call.
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == mxu
     assert _hbm_bytes(compiled) <= HBM_BYTES
 
 
@@ -197,7 +200,7 @@ def test_apply_sketch_lowers_to_the_mxu_kernel(one_chip, width):
     vmem = vmem_bytes(group, b, tn, td)
     k_pad = k + (-k) % group
     hessian = _jitted_sketched_hessian(
-        LogisticRegression(), sketching.get("oversketch", ph.sketch), False)
+        LogisticRegression(), sketching.get("oversketch", ph.sketch))
     arg = functools.partial(_arg, one_chip)
     text = hessian.lower(
         arg((d,)), Dataset(arg((n, d)), arg((n,))),
@@ -213,8 +216,7 @@ def test_apply_sketch_lowers_to_the_mxu_kernel(one_chip, width):
 
 @pytest.mark.parametrize("width", list(WIDTHS))
 def test_streamed_apply_sketch_fits_one_chip(one_chip, width):
-    """The default (use_kernels=False) sketch path on the chip: the MXU
-    kernel streams panels of A through VMEM, so besides its (K, b, d)
+    """The sketch on the chip: the MXU kernel streams panels of A through VMEM, so besides its (K, b, d)
     output it holds no more than the (K, n) bucket and sign rows rounded
     up to the kernel's tiles: never an (n, d) panel, never (K, n, d)."""
     ph = WIDTHS[width]
@@ -246,7 +248,7 @@ def test_distavg_direction_compiles_at_epsilon_width(one_chip):
     assert b > MXU_MAX_BLOCK_SIZE
     fn = _jitted_distavg_direction(LogisticRegression(),
                                    sketching.get("oversketch", cfg), True,
-                                   False, "chol", 64)
+                                   "chol", 64)
     arg = functools.partial(_arg, one_chip)
     compiled = fn.lower(
         arg((d,)), Dataset(arg((n, d)), arg((n,))), arg((d,)),
@@ -258,25 +260,18 @@ def test_distavg_direction_compiles_at_epsilon_width(one_chip):
 
 
 def test_count_sketch_apply_compiles_at_distavg_width(one_chip):
-    """What use_kernels runs in that direction: the kernel at b = 2048,
-    whose tiles pick_tiles shrinks until its working set fits the VMEM
-    budget, compiles for the chip."""
+    """The kernel at b = 2048, whose tiles pick_tiles shrinks until its
+    working set fits the VMEM budget, compiles for the chip."""
     ph, cfg = WIDTHS["epsilon"], DISTAVG_SKETCH
     n, d, k, b = ph.n, ph.d, cfg.total_blocks, cfg.block_size
     group, tn, td = pick_tiles(k, b, n, d)
     assert vmem_bytes(group, b, tn, td) <= VMEM_BUDGET_BYTES
     compiled = _compile(
-        lambda h, s, a: ops.count_sketch_apply(h, s, a, b, interpret=False),
+        lambda h, s, a: count_sketch_apply(h, s, a, b, interpret=False),
         one_chip, ((k, n), jnp.int32), ((k, n), jnp.float32),
         ((n, d), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
     assert _hbm_bytes(compiled) <= HBM_BYTES
-
-
-# The paper's synthetic logistic problem (arXiv:1903.08857, Sec. 5) at its
-# published size, as bench/configs/synthetic.json runs it.
-SYNTHETIC = dict(n=300_000, d=3000, coded_block_rows=256,
-                 sketch=OverSketchConfig(30208, 256, 0.25))
 
 
 def _row_major(sharding, ndim):
@@ -310,8 +305,7 @@ def test_synthetic_solve_programs_fit_one_chip_beside_x_and_parity(one_chip):
     assert par_x.shape == (2 * code_x.grid + 1, br, d)
     assert par_xt.shape == (2 * code_xt.grid + 1, n, br)
     fam = sketching.get("oversketch", cfg)
-    hessian = _jitted_sketched_hessian(LogisticRegression(lam=1e-5), fam,
-                                       False)
+    hessian = _jitted_sketched_hessian(LogisticRegression(lam=1e-5), fam)
     compiled = {
         "encode_x": program(lambda a: coded.encode_2d(a, code_x), 3
                             ).lower(x),

@@ -134,18 +134,23 @@ def test_tracing_leaves_the_iterate_unchanged(traced):
     assert jnp.array_equal(plain, traced_w)
 
 
-def _hessian_text(use_kernels):
+def _hessian_program(platforms=None):
+    """The default Hessian program's StableHLO with its locations: lowered
+    for this process's platform, or exported for ``platforms`` (no chip
+    is needed to lower for one)."""
     data, obj = _data(), LogisticRegression(lam=1e-5)
     fam = sketching.get("oversketch", CFG.sketch)
     state = fam.sample(jax.random.PRNGKey(3), data.x.shape[0])
-    fn = newton._jitted_sketched_hessian(obj, fam, use_kernels)
-    surv = jnp.ones((CFG.sketch.total_blocks,), bool)
-    return fn.lower(jnp.zeros(D), data, state, surv).as_text(
-        debug_info=True)
+    fn = newton._jitted_sketched_hessian(obj, fam)
+    args = (jnp.zeros(D), data, state,
+            jnp.ones((CFG.sketch.total_blocks,), bool))
+    if platforms is None:
+        return fn.lower(*args).as_text(debug_info=True)
+    return jax.export.export(fn, platforms=platforms)(*args).mlir_module()
 
 
 def test_lowered_hessian_carries_the_three_device_scopes():
-    text = _hessian_text(False)
+    text = _hessian_program()
     for scope in wall.SCOPES:
         assert f"jit(fn)/{scope}/" in text, scope
     # The count sketch's streamed blocks run inside the sketch scope, in
@@ -154,11 +159,23 @@ def test_lowered_hessian_carries_the_three_device_scopes():
         rf"jit\(fn\)/{wall.SKETCH}/cond/branch_\d+_fun/while/body/", text)
 
 
-def test_fused_kernel_falls_under_the_sketch_scope_alone():
-    text = _hessian_text(True)
-    assert f"jit(fn)/{wall.SKETCH}/" in text
-    assert f"jit(fn)/{wall.HESS_SQRT}/" in text
-    assert f"/{wall.GRAM}/" not in text
+def test_mxu_kernel_falls_under_the_sketch_scope_alone():
+    """Lowered for a TPU, the Hessian calls the MXU count-sketch kernel
+    from inside ``osn_sketch`` alone, and its Gram's matmuls run under
+    ``osn_gram``."""
+    text = _hessian_program(("tpu",))
+    locs = dict(re.findall(r"^(#loc\d+) = loc\(\"([^\"]*)\"", text, re.M))
+    call, = re.findall(r"call @(_count_sketch_apply)\(.*loc\((#loc\d+)\)$",
+                       text, re.M)
+    scope = locs[call[1]]
+    assert scope.startswith(f"jit(fn)/{wall.SKETCH}/"), scope
+    assert f"/{wall.GRAM}/" not in scope and f"/{wall.HESS_SQRT}/" not in scope
+    body = text[text.index(f"func.func private @{call[0]}("):]
+    body = body[:body.index("\n  }")]
+    assert "stablehlo.custom_call @tpu_custom_call" in body
+    gram_ops = [name for name in locs.values()
+                if name.startswith(f"jit(fn)/{wall.GRAM}/")]
+    assert any(name.endswith("dot_general") for name in gram_ops)
 
 
 def test_distributed_average_direction_carries_the_device_scopes():
@@ -166,7 +183,7 @@ def test_distributed_average_direction_carries_the_device_scopes():
     cfg = OverSketchConfig(512, 64, 0.25)
     fam = sketching.get("oversketch", cfg)
     state = fam.sample(jax.random.PRNGKey(3), data.x.shape[0])
-    fn = newton._jitted_distavg_direction(obj, fam, False, False)
+    fn = newton._jitted_distavg_direction(obj, fam, False)
     text = fn.lower(jnp.zeros(D), data, jnp.ones(D), state,
                     jnp.ones((cfg.total_blocks,), bool)).as_text(
                         debug_info=True)
