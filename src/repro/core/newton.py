@@ -347,7 +347,8 @@ class CodedMatvecEngine:
             prods = coded.block_products(self.x, self.parity[tag], v, code,
                                          tag == "XT")
             noise = (jnp.sqrt(jnp.mean(prods ** 2)) + 1e-30) * \
-                jax.random.normal(jax.random.fold_in(key, 777), prods.shape)
+                jax.random.normal(straggler.on_device_of(
+                    jax.random.fold_in(key, 777), prods), prods.shape)
             cgrid = jnp.asarray(corrupt.reshape(g1, g1))
             prods = jnp.where(cgrid[..., None], prods + noise, prods)
             known = jnp.asarray(arrived.reshape(g1, g1))
@@ -609,7 +610,9 @@ def _hessian_phase(objective, data: Dataset, w: jax.Array, cfg: NewtonConfig,
                         if int(surv2.sum()) < floor:
                             return None, None
                         survivors = jnp.asarray(surv2)
-        state = fam.sample(jax.random.fold_in(key, 7), n_rows)
+        state = fam.sample(
+            straggler.on_device_of(jax.random.fold_in(key, 7), data.x),
+            n_rows)
         tel = _telemetry(clock)
         if tel.enabled:
             # Audit trail for the sketch's routing: the apply that the
@@ -743,7 +746,8 @@ def _distavg_direction_phase(objective, data: Dataset, w: jax.Array,
                 "newton.fault_fallbacks").inc()
             if e.mask.shape == (scfg.total_blocks,):
                 survivors = jnp.asarray(e.mask)
-    state = fam.sample(jax.random.fold_in(key, 7), n_rows)
+    state = fam.sample(
+        straggler.on_device_of(jax.random.fold_in(key, 7), data.x), n_rows)
     fn = _jitted_distavg_direction(objective, fam, cfg.debias,
                                    cfg.distavg_solver, cfg.cg_iters)
     return fn(w, data, g, state, survivors)
@@ -803,7 +807,9 @@ def _oversketched_newton(objective, data: Dataset, w0: jax.Array,
                 f"per-worker solves to be well-posed: block_size="
                 f"{cfg.sketch.block_size} <= d={d_hess}")
     sketching.get(cfg.sketch_family, cfg.sketch)   # fail fast on bad family
-    key = jax.random.PRNGKey(cfg.seed)
+    # The key chain lives on the host: the fleet's folds and draws run
+    # there, and the sketch draw takes one copy of its key to the data.
+    key = straggler.host_key(cfg.seed)
     if isinstance(model, straggler.SimClock):
         clock, model = model, model.model
     else:

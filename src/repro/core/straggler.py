@@ -18,13 +18,52 @@ deterministic simulated seconds and simulated dollars.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.obs import wall
+
+
+@functools.cache
+def host_device() -> jax.Device:
+    """The device the clock draws on: the CPU backend JAX starts beside
+    any accelerator, or the default device where there is no CPU backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return jax.devices()[0]
+
+
+def on_host(key: jax.Array) -> jax.Array:
+    """``key`` committed to ``host_device()``: every split, fold and draw
+    derived from it then runs there as well, with the same bits (the key
+    derivation is integer arithmetic) and no read from an accelerator."""
+    dev = host_device()
+    if getattr(key, "committed", False) and key.devices() == {dev}:
+        return key
+    return jax.device_put(key, dev)
+
+
+def host_key(seed: int) -> jax.Array:
+    """``jax.random.PRNGKey(seed)``, made and committed on the host."""
+    with jax.default_device(host_device()):
+        return on_host(jax.random.PRNGKey(seed))
+
+
+def on_device_of(key: jax.Array, x) -> jax.Array:
+    """A host key copied, with no read, to the device that holds ``x``: a
+    draw that feeds a device program (a sketch, noise, a row sample) runs
+    beside its data.  Data on several devices, or on none, takes the key
+    uncommitted on the default device, where its programs place it."""
+    devices = x.devices() if isinstance(x, jax.Array) else ()
+    if len(devices) == 1:
+        return jax.device_put(key, next(iter(devices)))
+    return jax.device_put(np.asarray(key))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +88,12 @@ class StragglerModel:
         model's simulated throughput — phases with genuinely different
         per-worker compute (a matvec block vs a local Newton solve) then get
         proportionally different durations, which is what makes the
-        scheme-vs-scheme comparisons honest."""
+        scheme-vs-scheme comparisons honest.
+
+        The draws run as eager ops on the key's device; the fleet engine
+        passes host keys (``on_host``).  They stay eager: a jitted draw
+        fuses the float ops differently and moves the last bits of the
+        times, hence the simulated seconds and dollars."""
         if flops_per_worker is not None:
             work_per_worker = flops_per_worker / self.flops_per_second
         k1, k2, k3 = jax.random.split(key, 3)
@@ -97,7 +141,6 @@ def speculative_time(times: jax.Array, key: jax.Array,
     fast, flattering every speculative baseline.
     """
     from repro.runtime import policies as rt_policies   # lazy: imports us
-    import numpy as np
     n = times.shape[0]
 
     def sample_relaunch():

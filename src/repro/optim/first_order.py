@@ -79,7 +79,7 @@ def first_order(objective, data: Dataset, w0: jax.Array,
                 cfg: FirstOrderConfig,
                 model: Optional[straggler.StragglerModel] = straggler.StragglerModel()
                 ) -> Dict[str, List[float]]:
-    key = jax.random.PRNGKey(cfg.seed)
+    key = straggler.host_key(cfg.seed)   # the fleet draws on the host
     if isinstance(model, straggler.SimClock):
         clock, model = model, model.model
     else:
@@ -106,7 +106,8 @@ def first_order(objective, data: Dataset, w0: jax.Array,
 
         if cfg.method == "sgd":
             nb = max(1, int(cfg.batch_fraction * n))
-            idx = jax.random.choice(kb, n, (nb,), replace=False)
+            idx = jax.random.choice(straggler.on_device_of(kb, data.x), n,
+                                    (nb,), replace=False)
             sub = Dataset(x=data.x[idx], y=data.y[idx])
             g = objective.gradient(w_eval, sub)
             if clock is not None:

@@ -59,7 +59,7 @@ def giant(objective, data: Dataset, w0: jax.Array, cfg: GiantConfig,
     cost / trace config, see ``repro.runtime``)."""
     if cfg.schedule not in ("dag", "sequential"):
         raise ValueError(f"unknown schedule {cfg.schedule!r}")
-    key = jax.random.PRNGKey(cfg.seed)
+    key = straggler.host_key(cfg.seed)   # only the fleet's phases draw on it
     if isinstance(model, straggler.SimClock):
         clock = model
     else:

@@ -37,6 +37,13 @@ folded from the phase key, and all lifecycle coin flips come from a numpy
 ``Generator`` seeded from the same key — identical seeds give bit-identical
 ``(seconds, dollars)``, which is what makes trace replay exact.  Pool state
 mutates in phase-dispatch order, which the scheduler canonicalizes.
+
+Placement: ``run_phase`` commits its key to the host CPU device
+(``core.straggler.on_host``; a no-op for the solver's keys, which are made
+there), so the folds, the draws and the generator seeds run on the host
+and are read there (each read still an ``osn.sync.straggler`` span: it
+waits for the host's own eager draws, not for an accelerator).  Each draw
+round counts as the telemetry counter ``fleet.draws.<platform>``.
 """
 from __future__ import annotations
 
@@ -50,6 +57,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro import obs
+from repro.core.straggler import on_host
 from repro.obs import wall
 from repro.runtime import policies as _policies
 from repro.runtime import trace as _trace_mod
@@ -198,13 +206,9 @@ class FleetEngine:
             # One jax sample round per retry wave, lazily — the common
             # failure-free case costs exactly one sample_times call.
             if attempt not in round_times:
-                k = jax.random.fold_in(key, attempt)
-                times = self.model.sample_times(k, num_workers,
-                                                work_per_worker,
-                                                flops_per_worker)
-                with TraceAnnotation(wall.SYNC_STRAGGLER):
-                    round_times[attempt] = np.asarray(times,
-                                                      dtype=np.float64)
+                round_times[attempt] = self._draw(
+                    jax.random.fold_in(key, attempt), num_workers,
+                    work_per_worker, flops_per_worker)
             return float(round_times[attempt][worker])
 
         done = np.full(num_workers, np.inf)
@@ -348,6 +352,19 @@ class FleetEngine:
                     fstats["peak_concurrency"] = max(
                         fstats["peak_concurrency"], len(running))
         return done, attempts, successes, stats
+
+    def _draw(self, key: jax.Array, num_workers: int,
+              work_per_worker: float, flops_per_worker: Optional[float]
+              ) -> np.ndarray:
+        """One round of per-worker times (``model.sample_times``), counted
+        as ``fleet.draws.<platform>`` by the platform the draw ran on."""
+        times = self.model.sample_times(key, num_workers, work_per_worker,
+                                        flops_per_worker)
+        if self.telemetry.enabled:
+            platform = next(iter(times.devices())).platform
+            self.telemetry.metrics.counter(f"fleet.draws.{platform}").inc()
+        with TraceAnnotation(wall.SYNC_STRAGGLER):
+            return np.asarray(times, dtype=np.float64)
 
     # ---------------------------------------------------------- telemetry
     def _phase_telemetry(self, name: str, deps: Tuple[str, ...], start: float,
@@ -536,6 +553,7 @@ class FleetEngine:
                     mask, elapsed)
             return elapsed, mask
 
+        key = on_host(key)
         rng = _np_rng(key)
         fp = self.faults
         frng = None
@@ -573,12 +591,8 @@ class FleetEngine:
             # copy then wins; min() in the policy handles it).
             if "r" not in relaunch_cache:
                 fl = self.fleet
-                kr = jax.random.fold_in(key, 7777)
-                run = self.model.sample_times(kr, num_workers,
-                                              work_per_worker,
-                                              flops_per_worker)
-                with TraceAnnotation(wall.SYNC_STRAGGLER):
-                    run = np.asarray(run, dtype=np.float64)
+                run = self._draw(jax.random.fold_in(key, 7777), num_workers,
+                                 work_per_worker, flops_per_worker)
                 if fl.cold_start_prob > 0.0:
                     cold = rng.random(num_workers) < fl.cold_start_prob
                     run = run + cold * rng.uniform(

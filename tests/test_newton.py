@@ -132,3 +132,40 @@ def test_history_schema():
     for k in ("iter", "fval", "gnorm", "step", "time"):
         assert len(res.history[k]) == 3
     assert res.history["time"] == sorted(res.history["time"])
+
+
+def test_sketch_draw_takes_the_seed_keys_bits_on_the_datas_device(
+        monkeypatch):
+    """The key chain runs on the host, yet every iteration's sketch
+    (its hashes and signs) is the one that PRNGKey(seed), split four ways
+    per iteration and folded with 7, draws; it is drawn on the data's
+    device."""
+    from repro import sketching
+    from repro.core import newton
+    data, _ = _logistic(jax.random.PRNGKey(8), n=600, d=12)
+    obj = LogisticRegression(lam=1e-4)
+    cfg = NewtonConfig(iters=3, sketch=OverSketchConfig(256, 64, 0.25),
+                       coded_block_rows=64, seed=1600000013)
+    seen = []
+    jitted = newton._jitted_sketched_hessian
+
+    def spy(objective, family):
+        fn = jitted(objective, family)
+
+        def call(w, data, state, survivors):
+            seen.append((state, survivors))
+            return fn(w, data, state, survivors)
+        return call
+    monkeypatch.setattr(newton, "_jitted_sketched_hessian", spy)
+    oversketched_newton(obj, data, jnp.zeros(12), cfg)
+    assert len(seen) == cfg.iters
+    fam = sketching.get(cfg.sketch_family, cfg.sketch)
+    key = jax.random.PRNGKey(cfg.seed)
+    device, = data.x.devices()
+    for state, survivors in seen:
+        key, _, kh, _ = jax.random.split(key, 4)
+        want = fam.sample(jax.random.fold_in(kh, 7), data.x.shape[0])
+        for got, ref in zip(jax.tree.leaves(state), jax.tree.leaves(want)):
+            assert got.devices() == {device}
+            assert np.array_equal(np.asarray(got), np.asarray(ref))
+        assert survivors.shape == (cfg.sketch.total_blocks,)
