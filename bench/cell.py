@@ -8,7 +8,9 @@ files, each found by its name:
   problem's sizes, the objective by name, the precision, and under
   ``newton`` the ``NewtonConfig`` fields the solve takes;
 - ``bench/objectives/<objective>.py``: the objective's data generator, the
-  program class that solves it, and its plain reference;
+  program class that solves it, the iterate a solve starts from
+  (``start``), the work of one iteration that the roofline metrics divide
+  by (``work``), and its plain reference;
 - ``bench/traffic/<traffic>.json``: how solves arrive and how many
   iterations each runs;
 - ``bench/workloads/<cell>.json``: the limits that decide ``correct``,
@@ -160,7 +162,8 @@ def newton_config(core, config: dict, iters: int, seed: int):
 
 
 def solve(cell: Cell, x, y, seed: int, iters: int, newton=None) -> Answer:
-    """One solve through the program's normal path, from w0 = 0.
+    """One solve through the program's normal path, from the objective's
+    ``start``.
 
     ``newton`` stands in for ``repro.core.oversketched_newton`` (the tests
     plant faults through it)."""
@@ -171,7 +174,7 @@ def solve(cell: Cell, x, y, seed: int, iters: int, newton=None) -> Answer:
     run = newton or core.oversketched_newton
     t0 = time.perf_counter()
     res = run(objective, core.Dataset(x=x, y=y),
-              jnp.zeros((x.shape[1],), jnp.float32), cfg)
+              cell.objective.start(x, y, cell.config), cfg)
     jax.block_until_ready(res.w)
     return Answer(w=res.w, f=float(res.history["fval"][-1]),
                   seconds=time.perf_counter() - t0)

@@ -4,9 +4,10 @@ percentage of the device time its coded programs took.
 Layer: coded gradient (``core/coded.py``).  The programs are those of
 ``gradient_ms``: the coded matvecs (``jit_coded_matvec``) and the parity
 encodes each solve makes once (``jit_encode_2d``), per iteration.  The
-work is ``bench.work.gradient`` at the cell's shapes, X w and X^T r
-uncoded: two reads of X, so a code that re-reads X, or writes more than
-its parity, reads a lower share.  None where none of the programs ran.
+work is the cell's objective's ``work(config)["gradient"]`` (for
+logistic, ``bench.work.gradient``: X w and X^T r uncoded, two reads of
+X), so a code that re-reads X, or writes more than its parity, reads a
+lower share.  None where none of the programs ran.
 """
 from bench import work
 
@@ -17,6 +18,6 @@ def read(ctx):
     secs = ctx.trace.device_s(PROGRAMS)
     if not secs or not ctx.iterations:
         return None
-    cfg = ctx.config
-    least, _ = work.least_time(work.gradient(cfg["n"], cfg["d"]), ctx.peak)
+    least, _ = work.least_time(ctx.objective.work(ctx.config)["gradient"],
+                               ctx.peak)
     return least / (secs / ctx.iterations) * 100.0

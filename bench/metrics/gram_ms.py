@@ -2,10 +2,8 @@
 ``osn_gram``.
 
 Layer: Hessian.  The scope wraps the survivors' Gram of the sketched
-blocks (``core.sketch.sketched_gram``) inside the Hessian program.  On the
-fused kernel path the Gram runs inside the sketch kernel, under
-``osn_sketch`` (``sketch_ms``), and this reads nothing.  None where the
-trace carries no device scopes.
+blocks (``core.sketch.sketched_gram``) inside the Hessian program.  None
+where the trace carries no device scopes.
 """
 from bench import span_reduce
 

@@ -1,8 +1,9 @@
 """hessian_ms: device milliseconds per iteration of the Hessian's programs.
 
-Layer: Hessian (``objectives.hess_sqrt`` and the sketch->Gram of
-``core/sketch.py`` or ``kernels/sketch_gram.py``).  The programs, as the
-trace names them: the jitted closure of ``newton._jitted_sketched_hessian``
+Layer: Hessian (``objectives.hess_sqrt``, the count sketch of
+``core/sketch.py::apply_sketch``, on a TPU the MXU kernel of
+``kernels/count_sketch.py``, and the survivors' Gram).  The programs, as
+the trace names them: the jitted closure of ``newton._jitted_sketched_hessian``
 (``jit_fn``) and the count-sketch draw (``jit__randint``,
 ``jit__rademacher``).  None where none of them ran.
 """

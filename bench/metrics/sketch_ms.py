@@ -2,12 +2,12 @@
 ``osn_sketch``.
 
 Layer: Hessian.  The scope wraps the sketch apply inside the Hessian
-program (``SketchFamily.gram``): at the cells' default path the count
-sketch's ``lax.map`` of segment sums over the blocks.  On the fused
-kernel path the one sketch->Gram kernel falls under this scope, so
-``gram_ms`` then reads nothing.  Read from the union of the intervals of
-the operations under the scope (``bench/span_reduce.py``).  None where
-the trace carries no device scopes.
+program (``SketchFamily.gram``): ``core/sketch.py::apply_sketch``, which
+on a TPU for blocks of up to 1024 rows (every cell's) runs the MXU
+kernel of ``kernels/count_sketch.py`` (``mxu_count_sketch``) over the
+blocks the straggler mask keeps.  Read from the union of the intervals
+of the operations under the scope (``bench/span_reduce.py``).  None
+where the trace carries no device scopes.
 """
 from bench import span_reduce
 

@@ -8,6 +8,10 @@ harness takes from it:
 
 - ``PROGRAM`` and ``program_args``: the ``repro.core`` class that the timed
   path solves with, and its arguments from the configuration;
+- ``start``: the iterate every solve starts from, w0 = 0 of length d;
+- ``work``: the least work of one Newton iteration on this objective, the
+  yardstick of the roofline metrics (logistic's counts live in
+  ``bench/work.py``);
 - ``make_data``: the benchmark's own jitted copy of the repository's
   synthetic generator (``repro.data.synthetic.make_logistic_dataset``, no
   test set): features uniform on [-1, 1]^d with columns scaled by a
@@ -36,6 +40,8 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
+from bench import work as counts
+
 PROGRAM = "LogisticRegression"
 
 # Armijo sufficient-decrease constant and the step candidates 4^0..4^-5.
@@ -51,6 +57,22 @@ MAX_ITERS = 40
 
 def program_args(config: dict) -> dict:
     return {"lam": float(config["lam"])}
+
+
+def start(x, y, config: dict) -> jax.Array:
+    """w0 = 0: float32 zeros, one weight per feature."""
+    return jnp.zeros((x.shape[1],), jnp.float32)
+
+
+def work(config: dict) -> dict:
+    """{"hessian", "gradient", "iteration": ``bench.work.Work``}: the d x d
+    sketched Hessian over n rows, the gradient's two reads of X, and the
+    whole iteration, at the configuration's shapes and sketch."""
+    n, d = config["n"], config["d"]
+    blocks = counts.sketch_blocks(config["newton"]["sketch"])
+    return {"hessian": counts.hessian(n, d, *blocks),
+            "gradient": counts.gradient(n, d),
+            "iteration": counts.iteration(n, d, *blocks)}
 
 
 @functools.partial(jax.jit,

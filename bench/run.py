@@ -17,9 +17,11 @@ reference.
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result carries
 the per-layer metrics read from the trace (``bench/metrics/``), the
-device's busy and window seconds, and a breakdown.  The last lines of
-standard error give each number compared beside its limit; the last line
-of standard output is the result, one JSON object.
+device's busy and window seconds, and a breakdown.  The readers get the
+trace with the program's own spans and device scopes attached
+(``bench/span_reduce.py``).  The last lines of standard error give each
+number compared beside its limit; the last line of standard output is the
+result, one JSON object.
 
 Exits 1 without a TPU or with fewer chips than the cell asks for, and 2
 where the checkout holds no program (``src/repro``); neither prints a
@@ -84,12 +86,25 @@ def _top(pairs, n=TOP):
     return [[k, v] for k, v in sorted(pairs, key=lambda kv: -kv[1])[:n]]
 
 
+def read_trace(path: str):
+    """The window's trace as the readers get it: reduced
+    (``bench/trace_reduce.py``), with the program's spans and device scopes
+    attached (``bench/span_reduce.py``)."""
+    import jax
+    from bench import span_reduce, trace_reduce
+    profile = jax.profiler.ProfileData.from_file(path)
+    return span_reduce.attach(trace_reduce.reduce(path, profile), path,
+                              profile)
+
+
 class Context:
     """What a per-layer metric's ``read(ctx)`` may use."""
 
     def __init__(self, trace, cell, iterations, peak):
-        self.trace = trace              # bench.trace_reduce.Trace
+        self.trace = trace              # bench.trace_reduce.Trace, with
+        #                                 span_reduce's spans and scopes
         self.config = cell.config
+        self.objective = cell.objective  # bench/objectives/<name>.py
         self.iterations = iterations    # Newton iterations in the window
         self.peak = peak                # this device's row of peaks.json
 
@@ -179,7 +194,7 @@ def main(argv=None, root: str = ROOT, newton=None) -> int:
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
     else:
-        trace = trace_reduce.reduce(trace_path)
+        trace = read_trace(trace_path)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = Context(trace, cell, len(answers) * iters,
                       work.peaks(device["kind"]))

@@ -9,11 +9,14 @@ device-to-host read ``osn.sync.<site>``) and device scopes named ``osn_*``
 program).  The names are the program's; they are matched here by prefix
 only, so a program without them yields empty results, never an error.
 
-``attach(trace, path)`` adds two fields to a ``bench.trace_reduce.Trace``
-read from the same file:
+``attach(trace, path)`` adds these fields to a ``bench.trace_reduce.Trace``
+read from the same file; ``bench/run.py`` calls it on every traced run
+before its readers run:
 
 - ``spans``: the ``osn.*`` events of the host thread that holds the
   benchmark's ``window`` span, as (start, end, name) in nanoseconds;
+- ``solves``: the (start, end) of the benchmark's ``solve`` annotations
+  on that thread;
 - ``scopes``: for each ``osn_*`` scope, per device plane, the union of the
   intervals of the operations whose framework op name has that scope as a
   path component, clipped to the window.  The op-to-scope map is xprof's
@@ -28,8 +31,8 @@ intervals ``gaps`` names) to the innermost ``osn.*`` span open over it
 
 Run as a script, it runs one traced cell through ``bench/run.py`` and then
 prints one more JSON line: the span metrics of ``bench/metrics/`` read on
-the same trace, the idle partition, span counts, per-iteration wall times
-and, for each solve, its longest idle stretch and the span over it:
+the run's own trace, the idle partition, span counts, per-iteration wall
+times and, for each solve, its longest idle stretch and the span over it:
 
     python3 bench/span_reduce.py --workload <cell> --seed <n> --seconds <s>
 """
@@ -74,11 +77,6 @@ def _window_thread(pd) -> List[Span]:
             if any(name == tr.WINDOW for _, _, name in events):
                 return events
     return []
-
-
-def host_spans(pd) -> List[Span]:
-    """The ``osn.*`` events of the host thread holding ``window``."""
-    return [e for e in _window_thread(pd) if e[2].startswith(SPAN_PREFIX)]
 
 
 def _scope_table(path: str) -> Optional[Dict[Tuple[str, str], Tuple[str]]]:
@@ -143,10 +141,14 @@ def device_scopes(path: str, pd, window: tr.Interval
 
 
 def attach(trace: "tr.Trace", path: str, pd=None) -> "tr.Trace":
-    """Add ``spans`` and ``scopes`` (module docstring) to ``trace``."""
+    """Add ``spans``, ``solves`` and ``scopes`` (module docstring) to
+    ``trace``."""
     pd = pd or jax.profiler.ProfileData.from_file(path)
     lo, hi = trace.window
-    trace.spans = [s for s in host_spans(pd) if lo <= s[0] < hi]
+    thread = _window_thread(pd)
+    trace.spans = [e for e in thread
+                   if e[2].startswith(SPAN_PREFIX) and lo <= e[0] < hi]
+    trace.solves = sorted((a, b) for a, b, n in thread if n == SOLVE)
     trace.scopes = device_scopes(path, pd, trace.window)
     return trace
 
@@ -190,17 +192,16 @@ def idle_by_span(trace) -> Optional[Dict[Optional[str], float]]:
 
 
 # ---------------------------------------------------------------- the script
-def summary(trace, pd) -> dict:
+def summary(trace) -> dict:
     """What the script prints after the run's own result line."""
     spans = trace.spans
-    solves = sorted((a, b) for a, b, n in _window_thread(pd) if n == SOLVE)
     iters = sorted((a, b) for a, b, n in spans if n == ITER)
     counts: Dict[str, int] = {}
     for _, _, name in spans:
         counts[name] = counts.get(name, 0) + 1
     pieces = idle_pieces(trace, spans) if spans else []
     per_solve = []
-    for lo, hi in solves:
+    for lo, hi in trace.solves:
         inside = [(b - a, a, name) for (a, b), name in pieces
                   if lo <= a < hi]
         its = [a for a, _ in iters if lo <= a < hi]
@@ -229,38 +230,30 @@ def summary(trace, pd) -> dict:
 
 
 def main(argv=None) -> int:
-    """Run ``bench/run.py`` traced, keep its trace for the span reduction,
-    then print the span metrics and the summary as one JSON line."""
+    """Run ``bench/run.py`` traced, keep the context it hands its readers
+    (the trace with its spans attached), then print the span metrics and
+    the summary as one JSON line."""
     from bench import run
     argv = list(sys.argv[1:] if argv is None else argv)
     kept: dict = {}
-    # bench/run.py deletes its trace before its readers run and has no
-    # hook for more fields, so for the length of one run the script wraps
-    # the reduction it calls and the context it hands the readers.
-    reduce, context = tr.reduce, run.Context
-
-    def reduce_and_attach(path):
-        pd = jax.profiler.ProfileData.from_file(path)
-        trace = attach(reduce(path), path, pd)
-        kept["summary"] = summary(trace, pd)
-        return trace
+    context = run.Context
 
     class Context(context):
         def __init__(self, *args, **kw):
             super().__init__(*args, **kw)
             kept["ctx"] = self
 
-    tr.reduce, run.Context = reduce_and_attach, Context
+    run.Context = Context
     try:
         rc = run.main(argv + ["--trace", "1"])
     finally:
-        tr.reduce, run.Context = reduce, context
+        run.Context = context
     if rc != 0 or "ctx" not in kept:
         return rc
     ctx = kept["ctx"]
     metrics = {name: _reader(name).read(ctx) for name in METRICS}
     print(json.dumps({"span_metrics": metrics, "iterations": ctx.iterations,
-                      **kept["summary"]}), flush=True)
+                      **summary(ctx.trace)}), flush=True)
     return 0
 
 

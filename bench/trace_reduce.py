@@ -140,10 +140,11 @@ def _events(line):
         yield e.start_ns, e.start_ns + e.duration_ns, e.name
 
 
-def reduce(path: str) -> Trace:
-    """Reduce one trace file.  The host thread that holds the benchmark's
-    ``window`` span names the gaps."""
-    pd = jax.profiler.ProfileData.from_file(path)
+def reduce(path: str, pd=None) -> Trace:
+    """Reduce one trace file (``pd``: the file already read, if it has
+    been).  The host thread that holds the benchmark's ``window`` span
+    names the gaps."""
+    pd = pd or jax.profiler.ProfileData.from_file(path)
     window, host = None, []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):

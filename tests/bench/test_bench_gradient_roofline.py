@@ -9,13 +9,16 @@ import pytest
 
 from bench import trace_reduce as tr
 from bench import work
+from bench.objectives import logistic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 SPANS_FIXTURE = os.path.join(HERE, "fixtures", "trace_spans.xplane.pb")
 OLD_FIXTURE = os.path.join(HERE, "fixtures", "trace.xplane.pb")
 # record_trace.py's solve: 2 iterations at n = 2048, d = 64.
-RECORDED = {"n": 2048, "d": 64}
+RECORDED = {"n": 2048, "d": 64,
+            "newton": {"sketch": {"sketch_dim": 1024, "block_size": 128,
+                                  "straggler_tolerance": 0.25}}}
 ITERATIONS = 2
 PROGRAMS = ("jit_coded_matvec", "jit_encode_2d")
 
@@ -33,6 +36,7 @@ class Ctx:
     def __init__(self, trace):
         self.trace, self.iterations, self.config = (trace, ITERATIONS,
                                                     RECORDED)
+        self.objective = logistic
         self.peak = work.peaks("TPU v5 lite")
 
 

@@ -7,6 +7,7 @@ pinned on the older fixture."""
 import importlib.util
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import pytest
@@ -14,6 +15,7 @@ import pytest
 from bench import span_reduce as sr
 from bench import trace_reduce as tr
 from bench import work
+from bench.objectives import logistic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
@@ -39,6 +41,7 @@ def reader(name):
 class Ctx:
     def __init__(self, trace, iterations=2, config=RECORDED):
         self.trace, self.iterations, self.config = trace, iterations, config
+        self.objective = logistic
         self.peak = work.peaks("TPU v5 lite")
 
 
@@ -201,6 +204,86 @@ PINNED = {"hessian_ms": 0.18604500000000002,
 def test_older_readers_read_the_older_fixture_as_before(name):
     ctx = Ctx(tr.reduce(OLD_FIXTURE))
     assert reader(name).read(ctx) == pytest.approx(PINNED[name], rel=1e-12)
+
+
+# Values of the readers the benchmark had before it attached the program's
+# spans and asked the objective for its work, as they read before those
+# changes: the spans fixture, and the older one's reader not in PINNED.
+# Read here through ``bench/run.py``'s own trace reduction and context.
+PINNED_BOTH = {
+    ("older", "gradient_roofline"): 6.558810359942504,
+    ("spans", "hessian_ms"): 0.186227,
+    ("spans", "hessian_roofline"): 0.35449272777909335,
+    ("spans", "gradient_ms"): 0.01939,
+    ("spans", "iter_roofline"): 0.0015405034086393315,
+    ("spans", "device_idle_share"): 99.80907663722655,
+    ("spans", "gradient_roofline"): 6.602952946428964,
+}
+FIXTURES = {"older": OLD_FIXTURE, "spans": SPANS_FIXTURE}
+
+
+def _run_ctx(fixture):
+    from bench import run
+    cell = SimpleNamespace(config=RECORDED, objective=logistic)
+    return run.Context(run.read_trace(FIXTURES[fixture]), cell, 2,
+                       work.peaks("TPU v5 lite"))
+
+
+@pytest.mark.parametrize("fixture, name", sorted(PINNED_BOTH))
+def test_readers_read_the_fixtures_as_before_through_the_run(fixture, name):
+    assert reader(name).read(_run_ctx(fixture)) == PINNED_BOTH[
+        (fixture, name)]
+
+
+def _bench():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("cell", ["epsilon-logistic", "a9a-logistic",
+                                  "synthetic-logistic"])
+def test_every_listed_metric_reads_a_number_on_the_chip_trace(cell):
+    """The run's own reduction of the recorded chip trace gives every
+    per-layer metric BENCHMARK.json lists for the cell a number; the
+    device scopes only where xprof is there to map ops to scopes."""
+    try:
+        import xprof  # noqa: F401
+        scoped = True
+    except ImportError:
+        scoped = False
+    ctx = _run_ctx("spans")
+    names = [m["name"] for m in _bench()["per_layer"]
+             if cell in m["workloads"]]
+    # A ``<quantity>.<variant>`` is read by the quantity's reader.
+    values = {n: reader(n.split(".")[0]).read(ctx) for n in names}
+    for n, v in values.items():
+        if n.split(".")[0] in ("sketch_ms", "gram_ms") and not scoped:
+            assert v is None, n
+        else:
+            assert isinstance(v, float) and v > 0, (n, v)
+
+
+def test_a_traced_run_reports_the_span_metrics(checkout, harness):
+    """On the CPU: the run hands its readers the spans, so the span metrics
+    read numbers; the CPU trace has no device plane, so the scope metrics
+    read nothing and are left out of the line."""
+    from conftest import add_cell
+    name = add_cell(checkout)
+    path = os.path.join(checkout, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    for m in bench["per_layer"]:
+        if m["name"] in sr.METRICS:
+            m["workloads"].append(name)
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    rc, res, err = harness(checkout, name, trace=1)
+    assert rc == 0 and res["correct"] is True, err
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert "sketch_ms" not in m and "gram_ms" not in m
+    assert m["sync_idle_ms"] > 0 and m["fleet_idle_ms"] > 0
+    # 17 reads in each of tiny-logistic's 3 iterations, 4 for the encodes.
+    assert m["syncs_per_iter"] == pytest.approx((17 * 3 + 4) / 3)
 
 
 # ---------------------------------------------------------------- the script
